@@ -13,7 +13,7 @@ loudly with a per-field diff until a human re-blesses it with
 Baselines record the jax version that produced them: a version mismatch
 downgrades drift to a warning (XLA's CPU pipeline legitimately changes
 between releases; the gate is only authoritative on the env it was
-blessed on — this container's jax, per tests/known_env_failures.json).
+blessed on — this container's jax).
 """
 
 from __future__ import annotations

@@ -254,11 +254,7 @@ class CausalSelfAttention(nn.Module):
             else:
                 cache_write(ck, k.reshape(b, t, hd).astype(kv_dt))
                 cache_write(cv, v.reshape(b, t, hd).astype(kv_dt))
-            if (
-                cfg.decode_attention in ("fused", "fused_layers")
-                and t == 1
-                and fused.supports(cfg.max_seq_len)
-            ):
+            if fused.use_fused(cfg, t):
                 # The serving fast path: one Pallas launch reads the whole
                 # packed cache, masked to the frontier (int8 caches ride
                 # their scales in; dequant is in-register). Multi-token

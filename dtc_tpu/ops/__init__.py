@@ -1,9 +1,3 @@
-# NOTE: overlap_collectives is deliberately NOT imported here — its
-# transitive flash_attention -> utils.compat -> utils (-> metrics ->
-# models.gpt) chain closes an import cycle when the package is loaded
-# from models.gpt's own `from dtc_tpu.ops.attention import ...`. Import
-# it directly (`from dtc_tpu.ops import overlap_collectives` works as a
-# submodule import without package-level re-export).
 from dtc_tpu.ops import decode_attention, decode_fused, moe_dispatch
 from dtc_tpu.ops.attention import causal_attention
 

@@ -19,17 +19,20 @@ three implementations behind one interface:
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e9  # matches the reference's additive mask value
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # No try/except: a backend that fails to come up must fail the run,
+    # not quietly route `attention: auto` to the dense path.
+    return jax.devices()[0].platform == "tpu"
 
 
 def dense_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
@@ -78,6 +81,67 @@ def decode_attention(
     return out.astype(q.dtype)
 
 
+def resolve_impl(
+    impl: str, t: int, d: int, block_q: int = 512, block_kv: int = 512
+) -> str:
+    """The implementation :func:`causal_attention` runs for ``impl`` at
+    sequence length ``t`` and head_dim ``d``: ``auto`` is flash on a TPU
+    when the tiling divides, else dense; anything else is itself."""
+    if impl != "auto":
+        return impl
+    from dtc_tpu.ops import flash_attention
+
+    # head_dim is zero-padded to the lane width inside the kernel, so the
+    # flagship shape (head_dim=32, T=512) qualifies; only the sequence
+    # tiling has to divide.
+    if _on_tpu() and t >= 256 and flash_attention.supports(t, d, block_q, block_kv):
+        return "flash"
+    return "dense"
+
+
+def _flash_per_shard(q, k, v, spec: P | None, **blocks) -> jax.Array:
+    """The flash kernel on each device's own (batch, heads) shard.
+
+    XLA cannot partition a Mosaic kernel — on a mesh of more than one
+    device the TPU lowering refuses it outright ("Mosaic kernels cannot
+    be automatically partitioned. Please wrap the call in a shard_map";
+    interpret mode on the CPU mesh never shows this). Attention is
+    independent across batch rows and heads, so the kernel run per shard
+    inside a region that is manual over every mesh axis IS the sharded
+    op. A sharded sequence axis is not — that is ring / Ulysses — and is
+    refused here. A dimension its mesh axis does not divide (the
+    batch-1 ``model.init`` trace) is computed whole on every device of
+    that axis instead."""
+    from flax import linen as nn
+    from jax._src.core import trace_state_clean
+
+    from dtc_tpu.ops.flash_attention import flash_causal_attention
+    from dtc_tpu.parallel.sharding import ambient_mesh
+
+    flash = functools.partial(flash_causal_attention, **blocks)
+    mesh = None if trace_state_clean() else ambient_mesh(allow_empty=True)
+    free = set() if mesh is None else set(mesh.axis_names) - set(mesh.manual_axes)
+    if mesh is None or mesh.size == 1 or not free:
+        return flash(q, k, v)  # one device, or already fully manual
+    if spec is None:
+        rules = dict(nn.get_logical_axis_rules())
+        spec = P(*(rules.get(ax) for ax in ("batch", "seq", "heads", "head_dim")))
+    sizes = dict(mesh.shape)
+    spec = P(*(
+        ax if ax in free and dim % sizes[ax] == 0 else None
+        for ax, dim in zip(tuple(spec) + (None,) * (4 - len(spec)), q.shape)
+    ))
+    if spec[1] is not None:
+        raise ValueError(
+            f"flash attention needs the whole sequence on each device, but "
+            f"seq is sharded over {spec[1]!r}; use attention: ring or ulysses"
+        )
+    return shard_map(
+        flash, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+        axis_names=free, check_vma=False,
+    )(q, k, v)
+
+
 def causal_attention(
     q: jax.Array,
     k: jax.Array,
@@ -88,26 +152,18 @@ def causal_attention(
     block_kv: int = 512,
     block_q_bwd: int = 0,
     block_kv_bwd: int = 0,
+    spec: P | None = None,
 ) -> jax.Array:
-    """Dispatch causal self-attention over ``(B, T, H, D)`` tensors."""
-    if impl == "auto":
-        from dtc_tpu.ops import flash_attention
-
-        t, d = q.shape[1], q.shape[3]
-        # head_dim is zero-padded to the lane width inside the kernel, so the
-        # flagship shape (head_dim=32, T=512) qualifies; only the sequence
-        # tiling has to divide.
-        if _on_tpu() and t >= 256 and flash_attention.supports(t, d, block_q, block_kv):
-            impl = "flash"
-        else:
-            impl = "dense"
+    """Dispatch causal self-attention over ``(B, T, H, D)`` tensors.
+    ``spec`` says how the caller laid q/k/v out over the ambient mesh
+    (None: the active logical rules' batch/seq/heads/head_dim mapping);
+    only the flash kernel needs to be told — see :func:`_flash_per_shard`."""
+    impl = resolve_impl(impl, q.shape[1], q.shape[3], block_q, block_kv)
     if impl == "dense":
         return dense_causal_attention(q, k, v)
     if impl == "flash":
-        from dtc_tpu.ops.flash_attention import flash_causal_attention
-
-        return flash_causal_attention(
-            q, k, v, block_q=block_q, block_kv=block_kv,
+        return _flash_per_shard(
+            q, k, v, spec, block_q=block_q, block_kv=block_kv,
             block_q_bwd=block_q_bwd, block_kv_bwd=block_kv_bwd,
         )
     if impl == "ring":

@@ -40,8 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Shared with the training kernels; importing flash_attention also installs
-# the jax-0.4.x pltpu.CompilerParams alias every pallas_call below relies on.
+# Shared with the training kernels.
 from dtc_tpu.ops.flash_attention import _interpret, _packed_group
 
 NEG_INF = -1e9  # matches ops/attention.py
@@ -126,6 +125,18 @@ def supports(s: int) -> bool:
     if s <= _DECODE_MAX_SINGLE_S and vmem.decode_single_tile_fits(s):
         return True
     return s % _DECODE_BLOCK_S == 0
+
+
+def use_fused(cfg, t_new: int) -> bool:
+    """The per-layer kernel's routing predicate (models/gpt.py): knob on
+    ``fused`` — or ``fused_layers`` for a call the megakernel declined —
+    a single-token call, and a supported cache length. Prefill
+    (multi-token) and unsupported lengths take the einsum oracle."""
+    return (
+        cfg.decode_attention in ("fused", "fused_layers")
+        and t_new == 1
+        and supports(cfg.max_seq_len)
+    )
 
 
 def _head_kv(kt, vt, ks, vs, gg, d, out_dtype):

@@ -70,9 +70,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Shared helpers; importing decode_attention also installs the jax-0.4.x
-# pltpu.CompilerParams alias (via flash_attention) every pallas_call
-# below relies on.
 from dtc_tpu.ops import vmem
 from dtc_tpu.ops.decode_attention import KV_SCALE_FLOOR, NEG_INF, _interpret
 
@@ -94,11 +91,9 @@ _SPEC_MAX_K = vmem.SPEC_MAX_K
 #: Per-grid-step VMEM working-set budget — the ONE shared constant in
 #: ops/vmem.py (ISSUE 20 unified this module's copy with
 #: overlap_collectives'). The flagship (12.6 MB fp32 weights + 1.05 MB
-#: bf16 row) fits single-buffered; the planner's
-#: ``fits_double_buffered`` answers the cross-layer double-buffering
-#: question statically (it does NOT fit at 14 MiB — PERF.md "Kernel
-#: audit"), so the per-layer kernel remains the fallback if Mosaic
-#: insists on prefetching.
+#: bf16 row) passes it single-buffered. Mosaic double-buffers the
+#: streamed blocks, which the chip's default scoped limit refuses, so
+#: the call states the planner's ``vmem_limit_bytes``.
 _VMEM_BUDGET_BYTES = vmem.VMEM_BUDGET_BYTES
 
 #: LoRA site order the kernel threads factors in (a subset, filtered by
@@ -141,6 +136,22 @@ def use_fused_layers(cfg, t_new: int, verify: bool = False) -> bool:
         and ok_t
         and supports_fused_layers(cfg, t=t_new)
     )
+
+
+def decode_backend(cfg, t_new: int = 1, verify: bool = False) -> str:
+    """Which backend one decode call of ``t_new`` tokens takes — the
+    ladder fused_layers -> fused -> xla read off the SAME predicates the
+    program routes by (:func:`use_fused_layers` in
+    ``generate.decode_step``, ``decode_attention.use_fused`` in the
+    attention module). For callers that must know what ran rather than
+    what was configured (``chip_smoke.py``)."""
+    from dtc_tpu.ops import decode_attention
+
+    if use_fused_layers(cfg, t_new, verify=verify):
+        return "fused_layers"
+    if decode_attention.use_fused(cfg, t_new):
+        return "fused"
+    return "xla"
 
 
 # ---------------------------------------------------------------------------
@@ -202,16 +213,23 @@ def _fused_layers_kernel(
         var = jnp.maximum(
             0.0, jnp.mean(xf * xf, axis=-1, keepdims=True) - mean * mean
         )
-        mul = jax.lax.rsqrt(var + _LN_EPS) * s_ref[:]
-        return (xf - mean) * mul + b_ref[:]
+        mul = jax.lax.rsqrt(var + _LN_EPS) * s_ref[0]
+        return (xf - mean) * mul + b_ref[0]
+
+    def _dot(a, w):
+        # Mosaic's matmul accumulates in 32 bits and refuses any other
+        # result type; XLA's compute-dtype dot accumulates in fp32 and
+        # rounds once too, so fp32-then-cast is the same arithmetic.
+        return jax.lax.dot_general(
+            a, w, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(cdtype)
 
     def dense(xx, w_ref, bias_ref):
         # nn.Dense: inputs/kernel/bias promoted to compute dtype, plain
         # dot_general (output dtype = compute dtype), bias added after.
-        return jax.lax.dot_general(
-            xx.astype(cdtype), w_ref[0].astype(cdtype),
-            (((1,), (0,)), ((), ())),
-        ) + bias_ref[:].astype(cdtype)
+        y = _dot(xx.astype(cdtype), w_ref[0].astype(cdtype))
+        return y + bias_ref[0].astype(cdtype)
 
     def lora(site, xx, y):
         # adapters/lora.apply_lora: y + scale * ((x @ A) @ B), factors
@@ -221,10 +239,7 @@ def _fused_layers_kernel(
         a_ref, b_ref = lora_refs[site]
         av = (a_ref[0, 0] if lora_per_row else a_ref[0]).astype(cdtype)
         bv = (b_ref[0, 0] if lora_per_row else b_ref[0]).astype(cdtype)
-        z = jax.lax.dot_general(
-            xx.astype(cdtype), av, (((1,), (0,)), ((), ())),
-        )
-        delta = jax.lax.dot_general(z, bv, (((1,), (0,)), ((), ())))
+        delta = _dot(_dot(xx.astype(cdtype), av), bv)
         return y + (lora_scale * delta).astype(y.dtype)
 
     # ---- attention leg ----
@@ -384,6 +399,9 @@ def _fused_layers_call(x, blocks_p, blocks_c, idx, lora_tree, cfg):
         mlp_p["fc1"]["kernel"], mlp_p["fc1"]["bias"],
         mlp_p["fc2"]["kernel"], mlp_p["fc2"]["bias"],
     ]
+    # Per-layer vectors go in as (L, 1, feat): see the block-shape note
+    # in ops/vmem.fused_layers_grid_plan.
+    weights = [w[:, None] if w.ndim == 2 else w for w in weights]
     lora_sites, lora_arrays, lora_per_row = _lora_inputs(lora_tree, cfg)
 
     # Block shapes and index maps come from the shared static planner —
@@ -432,6 +450,7 @@ def _fused_layers_call(x, blocks_p, blocks_c, idx, lora_tree, cfg):
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=plan["vmem_limit_bytes"],
         ),
         interpret=_interpret(),
     )(*args)

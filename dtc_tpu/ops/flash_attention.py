@@ -39,9 +39,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # pragma: no cover — jax 0.4.x name
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -1e9  # matches the reference's additive mask value (ops/attention.py)
 _LANES = 128  # TPU lane width (kept for stat-scratch shapes)
 

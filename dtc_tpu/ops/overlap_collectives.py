@@ -69,7 +69,7 @@ from jax.sharding import PartitionSpec as P
 
 from dtc_tpu.ops import vmem
 from dtc_tpu.ops.flash_attention import _interpret  # noqa: F401  (shared gate)
-from dtc_tpu.utils.compat import shard_map
+from jax import shard_map
 
 #: VMEM budget for the fused kernels — the ONE shared constant in
 #: ops/vmem.py (ISSUE 20 unified this module's copy with
@@ -145,6 +145,27 @@ def resolve_backend(
     ):
         return "pallas"
     return "decomposed"
+
+
+def _manual_region(
+    shape: dict[str, int], manual: set[str], backend: str
+) -> tuple[set[str], str]:
+    """The shard_map's manual axes and the transport that can run there.
+
+    Mosaic refuses a kernel inside a PARTIALLY manual region ("Mosaic
+    kernels cannot be automatically partitioned" — every mesh axis must
+    be manual; found by compiling for a described v5e, no CPU run can
+    hit it). Size-1 axes of the trainer's 3-axis mesh therefore join the
+    manual set for the pallas transport (a no-op for placement); a
+    non-trivial axis left Auto sends it to the decomposed ring. Interpret
+    mode has neither the refusal nor support for remote copies under
+    more than one manual axis, so the CPU tests keep the narrow region."""
+    if backend != "pallas" or _interpret():
+        return manual, backend
+    rest = set(shape) - manual
+    if any(shape[name] > 1 for name in rest):
+        return manual, "decomposed"
+    return manual | rest, backend
 
 
 # ---------------------------------------------------------------------------
@@ -434,9 +455,8 @@ def _decomposed_ag_matmul(
     ``idx`` is the device's ring position, threaded in as a sharded-iota
     operand rather than ``lax.axis_index``: under a PARTIAL-manual region
     (the DP×FSDP×TP mesh, where "model" stays auto) this jax's SPMD
-    partitioner rejects axis_index's PartitionId lowering — the same env
-    limitation tests/known_env_failures.json records for PP and
-    fsdp+ring; the iota operand sidesteps it on every backend."""
+    partitioner rejected axis_index's PartitionId lowering when this was
+    written; the iota operand sidesteps that on every backend."""
     perm = _right_perm(ring)
     m = xl.shape[0]
     blk_in = wl.shape[1] if w_t else wl.shape[0]
@@ -660,6 +680,8 @@ def overlap_dense_matmul(
         or (tp_axis is not None and _interpret())
     ):
         backend = "decomposed"
+    manual = {axis_name} | ({tp_axis} if tp_axis is not None else set())
+    manual, backend = _manual_region(shape, manual, backend)
 
     out_dtype = jnp.result_type(x.dtype, w.dtype)
     mm = _make_local_matmul(
@@ -687,7 +709,6 @@ def overlap_dense_matmul(
         x_spec = P(axis_name, *mids, tp_axis)
         w_spec = P(tp_axis, axis_name)
         out_spec = P(axis_name, *mids, None)
-    manual = {axis_name} | ({tp_axis} if tp_axis is not None else set())
     return shard_map(
         local,
         mesh=mesh,
@@ -757,6 +778,7 @@ def reduce_scatter_matmul(
         ) <= _VMEM_BUDGET_BYTES
         if (not _interpret() and blk % _LANE != 0) or not fits:
             backend = "decomposed"
+    manual, backend = _manual_region(shape, {axis_name}, backend)
 
     def local(al, bl, il):
         al = al.reshape(-1, al.shape[-1])
@@ -780,6 +802,6 @@ def reduce_scatter_matmul(
         mesh=mesh,
         in_specs=(row_spec, row_spec, P(axis_name)),
         out_specs=out_spec,
-        axis_names={axis_name},
+        axis_names=manual,
         check_vma=False,
     )(a, b, jnp.arange(ring, dtype=jnp.int32))

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from dtc_tpu.utils.compat import shard_map
+from jax import shard_map
 
 NEG_INF = -1e9
 

@@ -72,7 +72,7 @@ def ulysses_causal_attention(
     q, k, v = (jax.lax.with_sharding_constraint(x, head_spec) for x in (q, k, v))
     out = causal_attention(
         q, k, v, impl="auto", block_q=block_q, block_kv=block_kv,
-        block_q_bwd=block_q_bwd, block_kv_bwd=block_kv_bwd,
+        block_q_bwd=block_q_bwd, block_kv_bwd=block_kv_bwd, spec=head_spec,
     )
     # head-sharded -> seq-sharded: the inverse all-to-all.
     return jax.lax.with_sharding_constraint(out, P(None, axis_name, None, None))

@@ -170,7 +170,12 @@ def build_mesh(
                 shape, devices=devices, allow_split_physical_axes=True
             )
         except (ValueError, NotImplementedError):
-            # Topology-unaware fallback (e.g. virtual CPU devices).
+            if getattr(devices[0], "platform", None) == "tpu":
+                # Same rule as the hybrid branch: on a real TPU a mesh
+                # the topology cannot carry is an error, never a plain
+                # reshape that ignores which chips are neighbours.
+                raise
+            # Topology-unaware fallback (virtual CPU devices).
             device_array = np.asarray(devices).reshape(shape)
     return Mesh(device_array, axis_names=AXIS_NAMES)
 

@@ -51,7 +51,7 @@ from dtc_tpu.parallel.sharding import (
     logical_to_spec,
 )
 
-from dtc_tpu.utils.compat import shard_map
+from jax import shard_map
 
 PyTree = Any
 

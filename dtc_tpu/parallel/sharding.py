@@ -106,15 +106,10 @@ def ambient_mesh(allow_empty: bool = False):
     trainer's ``with mesh:`` context. One definition shared by ring
     attention and the overlapped-collectives ops (ISSUE 12), so every
     nested-manual op resolves its mesh identically."""
-    try:
-        from jax.sharding import get_abstract_mesh
-    except ImportError:  # jax 0.4.x keeps it private
-        from jax._src.mesh import get_abstract_mesh
+    from jax.sharding import get_abstract_mesh
 
     amesh = get_abstract_mesh()
-    # jax 0.4.x returns a bare tuple outside any trace context — only a
-    # real (non-empty) AbstractMesh is usable here.
-    if amesh is not None and getattr(amesh, "empty", True) is False:
+    if not amesh.empty:
         return amesh
     from jax._src.mesh import thread_resources
 

@@ -267,10 +267,7 @@ class SnapshotStore:
         copies = []
         for _, leaf in flat:
             c = jnp.copy(leaf)
-            try:
-                c.copy_to_host_async()
-            except AttributeError:  # older jax.Array without the method
-                pass
+            c.copy_to_host_async()
             copies.append(c)
         # Alive set frozen NOW, on the hot loop's thread: a dead host can
         # store nothing, and the commit thread must judge by the roster as

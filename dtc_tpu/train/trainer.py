@@ -1175,8 +1175,7 @@ def _train(
                 state, loss = train_step(state, Batch(x=x, y=y), jax.random.fold_in(warm_key, i))
             delivered += warmup_steps
             if warmup_steps:
-                # Sync via value fetch — reliable even on remote-execution
-                # platforms where block_until_ready returns early.
+                # Sync via value fetch.
                 jax.device_get(loss)
 
             if start_step > 0:
@@ -1365,8 +1364,7 @@ def _train(
                             budget_s=res_cfg.watchdog.hard_timeout_s
                             * max(train_cfg.log_every, 1),
                         )
-                    # One stacked transfer, not len(window) scalar fetches — a
-                    # per-array fetch costs a full RTT on tunneled platforms.
+                    # One stacked transfer, not len(window) scalar fetches.
                     losses = [float(v) for v in jax.device_get(jnp.stack(device_losses))]
                     now = time.perf_counter()  # after the device sync
                     # Anomaly guard rides the losses ALREADY fetched for

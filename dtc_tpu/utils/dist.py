@@ -20,6 +20,29 @@ _initialized = False
 TIMEOUT_ENV = "DTC_COORDINATOR_TIMEOUT_S"
 
 
+#: Where compiled programs persist when the environment names no place:
+#: one fixed directory inside the checkout. The path is part of the
+#: cache key's surroundings — a directory that moves (a temp name, a
+#: pid, a timestamp) never hits.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def configure_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache; call before the first
+    compile (``main.py``, ``bench.py``, ``chip_smoke.py`` do, first
+    thing). Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
+    itself and nothing is configured here, so the cache can be placed
+    from outside; otherwise it lives at :data:`COMPILE_CACHE_DIR`."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+
+
 def _resolve_timeout(timeout_s: int | None) -> int | None:
     """Effective coordinator timeout: env knob > config > jax default.
     ``0`` means "jax's default" in BOTH the env knob and the config (so an

@@ -38,6 +38,11 @@ def main(
     dataset: str | None,
     obs: bool | None,
 ):
+    from dtc_tpu.utils.dist import (
+        configure_compile_cache, maybe_initialize_distributed,
+    )
+
+    configure_compile_cache()
     train_cfg, model_cfg, opt_cfg = load_config(
         train_config_path, model_config_path, optim_config_path
     )
@@ -50,8 +55,6 @@ def main(
 
     # Multi-host init FIRST: jax.distributed.initialize() must run before
     # any backend-touching JAX API (including jax.device_count below).
-    from dtc_tpu.utils.dist import maybe_initialize_distributed
-
     maybe_initialize_distributed(
         train_cfg.multihost, train_cfg.coordinator_timeout_s
     )

@@ -14,8 +14,7 @@ Also asserts the two adapters actually diverged (different lrs) and that
 no steady-state recompile happened across the mixed-tenant admissions.
 ~1-2 min on the 1-core CI host.
 
-    XLA_FLAGS="--xla_force_host_platform_device_count=8 \
-      --xla_cpu_use_thunk_runtime=false" JAX_PLATFORMS=cpu \
+    XLA_FLAGS="--xla_force_host_platform_device_count=8" JAX_PLATFORMS=cpu \
       python scripts/adapter_smoke.py
 """
 
@@ -29,7 +28,6 @@ if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"
-        + " --xla_cpu_use_thunk_runtime=false"
     )
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 

@@ -9,12 +9,10 @@ dot-class op attributed (the structural gates the bench row carries), and
 the merged host+device Perfetto export must hold both span kinds on
 aligned wall-clock timestamps with the required Chrome-trace keys.
 
-NOTE: runs with the DEFAULT CPU thunk runtime — the per-op trace events
-the parser consumes only exist there (the test suite's
-``--xla_cpu_use_thunk_runtime=false`` harness flag suppresses them, which
-is why tests/test_devprof.py's capture smoke only asserts the
-warn-not-fail contract). A capability probe guards environments whose
-profiler emits no op events at all: warn-and-skip, never a false red.
+NOTE: tests/test_devprof.py's capture tests assert only the mechanics
+and the warn-not-fail contract; this script is the live capture ->
+attribute run. A capability probe guards environments whose profiler
+emits no op events at all: warn-and-skip, never a false red.
 
     JAX_PLATFORMS=cpu python scripts/devprof_smoke.py
 """
@@ -33,7 +31,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 def _op_events_available() -> bool:
     """Capability probe: does this environment's profiler emit per-op
-    trace events? (Needs the CPU thunk runtime or a real device.)"""
+    trace events?"""
     import jax
     import jax.numpy as jnp
 
@@ -72,8 +70,8 @@ def main() -> int:
     if not _op_events_available():
         print(
             "# devprof smoke SKIPPED: this environment's profiler emits no "
-            "per-op trace events (thunk runtime disabled / unsupported "
-            "backend) — warn, not fail, per the capture contract"
+            "per-op trace events (unsupported backend) — warn, not fail, "
+            "per the capture contract"
         )
         return 0
 

@@ -20,8 +20,7 @@ the token budget. Asserts, in order:
 
 ~1-2 min on the 1-core CI host.
 
-    XLA_FLAGS="--xla_force_host_platform_device_count=8 \
-      --xla_cpu_use_thunk_runtime=false" JAX_PLATFORMS=cpu \
+    XLA_FLAGS="--xla_force_host_platform_device_count=8" JAX_PLATFORMS=cpu \
       python scripts/elastic_smoke.py
 """
 
@@ -38,7 +37,6 @@ if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"
-        + " --xla_cpu_use_thunk_runtime=false"
     )
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 

@@ -30,7 +30,6 @@ if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"
-        + " --xla_cpu_use_thunk_runtime=false"
     )
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 

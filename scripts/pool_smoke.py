@@ -37,8 +37,7 @@ events. ``--json`` appends a machine-readable ``# pool-smoke:`` line
 
 ~2-4 min on the 1-core CI host.
 
-    XLA_FLAGS="--xla_force_host_platform_device_count=8 \
-      --xla_cpu_use_thunk_runtime=false" JAX_PLATFORMS=cpu \
+    XLA_FLAGS="--xla_force_host_platform_device_count=8" JAX_PLATFORMS=cpu \
       python scripts/pool_smoke.py
 """
 
@@ -57,7 +56,6 @@ if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"
-        + " --xla_cpu_use_thunk_runtime=false"
     )
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 

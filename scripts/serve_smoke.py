@@ -11,8 +11,7 @@ asserts the prefix store built exactly once with one hit, and that at
 least one admission happened mid-flight (continuous batching, not
 batch-at-once). ~30 s on the 1-core CI host.
 
-    XLA_FLAGS="--xla_force_host_platform_device_count=8 \
-      --xla_cpu_use_thunk_runtime=false" JAX_PLATFORMS=cpu \
+    XLA_FLAGS="--xla_force_host_platform_device_count=8" JAX_PLATFORMS=cpu \
       python scripts/serve_smoke.py [--serve_config_path configs/serve_config.yaml]
 """
 
@@ -26,7 +25,6 @@ if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"
-        + " --xla_cpu_use_thunk_runtime=false"
     )
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 

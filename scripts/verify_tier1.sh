@@ -71,9 +71,7 @@ timeout -k 10 240 env JAX_PLATFORMS=cpu python scripts/serve_smoke.py || {
 timeout -k 10 300 env JAX_PLATFORMS=cpu python scripts/trace_smoke.py || {
     echo "tier-1 pre-gate: tracing smoke failed" >&2; exit 1; }
 # Pre-gate 5 (ISSUE 8): device-time observatory smoke — capture a 2-step
-# devprof window around the b8 audit train step (DEFAULT CPU thunk
-# runtime: the per-op trace events only exist there, which is why this
-# is a standalone script and not a pytest), then the offline leg: the
+# devprof window around the b8 audit train step, then the offline leg: the
 # shared parser + attribution must cover >= 90% of measured device time
 # with every dot-class op attributed, and the merged host+device
 # Perfetto export must hold both timelines on aligned wall clocks.

@@ -111,3 +111,50 @@ def test_grad_clip_zero_disables_clipping():
     # Adam normalizes: update magnitude ~lr regardless, but with clip(0.0)
     # the update would be exactly zero.
     assert float(jnp.abs(updates["w"]).sum()) > 0
+
+
+# ---- compile cache placement (utils/dist.configure_compile_cache) ---------
+
+
+@pytest.fixture
+def _restore_cache_dir():
+    import jax
+
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_left_to_the_environment(monkeypatch, tmp_path, _restore_cache_dir):
+    """With JAX_COMPILATION_CACHE_DIR set the helper configures nothing:
+    JAX reads the variable itself, and the cache stays placeable from
+    outside."""
+    import jax
+
+    from dtc_tpu.utils.dist import configure_compile_cache
+
+    jax.config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "elsewhere"))
+    configure_compile_cache()
+    assert jax.config.jax_compilation_cache_dir is None
+
+
+def test_compile_cache_fixed_path_inside_checkout(monkeypatch, tmp_path, _restore_cache_dir):
+    """Unset, the helper names ONE directory inside the checkout — the same
+    on every call and from every working directory (a path that moves
+    never hits)."""
+    import os
+
+    import jax
+
+    from dtc_tpu.utils.dist import configure_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    seen = []
+    for cwd in (repo, str(tmp_path)):
+        monkeypatch.chdir(cwd)
+        jax.config.update("jax_compilation_cache_dir", None)
+        configure_compile_cache()
+        seen.append(jax.config.jax_compilation_cache_dir)
+    assert seen == [os.path.join(repo, ".jax_cache")] * 2

@@ -3,13 +3,11 @@
 The parser/attribution tests run against the COMMITTED fixture capture
 (``tests/fixtures/devprof_capture/`` — a hand-built trace.json.gz + meta
 sidecar with hand-computed durations), never against live profiler
-output: this environment's test harness disables the CPU thunk runtime
-(``--xla_cpu_use_thunk_runtime=false``, see conftest), under which the
-profiler emits no per-op events at all. The capture-window tests
-therefore assert the MECHANICS (window lifecycle, meta sidecar, trigger
-wiring, warn-not-fail on empty captures); the full capture->attribute
-pipeline is exercised by ``scripts/devprof_smoke.py`` (tier-1 pre-gate),
-which runs with the default thunk runtime where op events exist.
+output, so they do not depend on what this backend's profiler emits.
+The capture-window tests assert the MECHANICS (window lifecycle, meta
+sidecar, trigger wiring, warn-not-fail on empty captures); the full
+capture->attribute pipeline is exercised by ``scripts/devprof_smoke.py``
+(tier-1 pre-gate).
 """
 
 import glob
@@ -287,8 +285,7 @@ class TestProfileStepParity:
 
 
 # ---------------------------------------------------------------------------
-# capture windows (mechanics only — op events don't exist under the test
-# harness's thunk-runtime flag; the devprof smoke covers the full path)
+# capture windows (mechanics only; the devprof smoke covers the full path)
 
 
 class TestCaptureWindows:

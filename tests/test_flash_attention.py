@@ -308,3 +308,52 @@ def test_whole_t_tiles_past_packed_max_t_raise(monkeypatch):
     # Multi-tile tilings still route to the split backward and train.
     out = flash_causal_attention(q, k, v, block_q=128, block_kv=128)
     assert out.shape == q.shape
+
+
+# ---- the kernel per shard on a multi-device mesh (ISSUE 23) ---------------
+
+
+@pytest.mark.parametrize("shape", [(1, 4, 2), (1, 8, 1), (2, 2, 2)],
+                         ids=["data4_model2", "data8", "pipe2_data2_model2"])
+def test_flash_per_shard_matches_dense_on_a_mesh(shape):
+    """On a mesh of several devices ``causal_attention(impl="flash")`` runs
+    the kernel per (batch, heads) shard inside a fully manual shard_map
+    (the TPU lowering refuses a bare Mosaic kernel under GSPMD —
+    tests/test_chip_compile.py holds that line); values and gradients must
+    still equal the dense reference, whichever axes carry batch and heads."""
+    from flax import linen as nn
+
+    from dtc_tpu.parallel.mesh import build_mesh
+    from dtc_tpu.parallel.sharding import DEFAULT_RULES
+
+    mesh = build_mesh(shape)
+    q, k, v = _qkv(jax.random.PRNGKey(3), 8, 256, 8, 32)
+
+    def loss(impl):
+        def f(q, k, v):
+            return jnp.sum(causal_attention(q, k, v, impl=impl, block_q=128, block_kv=128) ** 2)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))
+
+    with mesh, nn.logical_axis_rules(DEFAULT_RULES):
+        got, got_grads = loss("flash")(q, k, v)
+        want, want_grads = loss("dense")(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    for a, b in zip(got_grads, want_grads):
+        assert jnp.max(jnp.abs(a - b)) < 2e-4
+
+
+def test_flash_per_shard_refuses_a_sharded_sequence():
+    """A sequence axis split over devices is ring / Ulysses territory: the
+    per-shard kernel would silently attend within each chunk only."""
+    from flax import linen as nn
+    from jax.sharding import PartitionSpec as P
+
+    from dtc_tpu.parallel.mesh import build_mesh
+    from dtc_tpu.parallel.sharding import DEFAULT_RULES
+
+    q, k, v = _qkv(jax.random.PRNGKey(4), 2, 256, 4, 32)
+    with build_mesh((1, 4, 2)), nn.logical_axis_rules(DEFAULT_RULES):
+        with pytest.raises(ValueError, match="whole sequence"):
+            jax.jit(lambda q, k, v: causal_attention(
+                q, k, v, impl="flash", spec=P("data", "model", None, None)
+            ))(q, k, v)

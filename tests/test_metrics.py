@@ -138,6 +138,24 @@ def test_mfu_none_when_peak_unknown():
     assert mfu(_cfg(), 8, T, 0.1, 8) is None
 
 
+def test_unknown_tpu_kind_is_an_error_not_a_default():
+    """A TPU whose kind is not in the peaks table must raise — an MFU or a
+    roofline against another chip's peak is worse than none — while a
+    known kind reads its own row (the v5e reports "TPU v5 lite")."""
+    from types import SimpleNamespace
+
+    from dtc_tpu.utils.metrics import peak_hbm_gbps
+
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert peak_flops_per_chip(v5e) == 197e12
+    assert peak_hbm_gbps(v5e) == 819.0
+    unknown = SimpleNamespace(platform="tpu", device_kind="TPU v99x")
+    with pytest.raises(ValueError, match="no published peaks"):
+        peak_flops_per_chip(unknown)
+    with pytest.raises(ValueError, match="no published peaks"):
+        peak_hbm_gbps(unknown)
+
+
 def test_mfu_none_on_zero_step_time():
     assert mfu(_cfg(), 8, T, 0.0, 8) is None
 

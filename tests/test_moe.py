@@ -238,7 +238,25 @@ def test_sort_dispatch_trains_loss_parity_with_einsum(
     np.testing.assert_allclose(e_s.losses, e_e.losses, rtol=5e-5, atol=5e-5)
 
 
-@pytest.mark.parametrize("dispatch", ["einsum", "sort"])
+#: `moe_dispatch: sort` under a `pipe > 1` mesh ABORTS the interpreter
+#: inside XLA's SPMD partitioner on the installed jax (run=False: an abort
+#: is a dead xdist worker that is handed the same test again, not a failed
+#: test — it starved every test queued behind it). The check is in the
+#: partitioner the TPU compile shares, so this is filed in ROADMAP.md as
+#: a defect of sort dispatch under the pipeline, not as a CPU quirk.
+_SORT_UNDER_PIPELINE = pytest.param(
+    "sort",
+    marks=pytest.mark.xfail(
+        run=False,
+        reason="aborts in XLA's SPMD partitioner: spmd_partitioner_util.cc:495 "
+        "Check failed: partition_group_list.num_replica_groups() * ... == "
+        "device_groups.num_devices_per_group() "
+        "(PartitionGatherExplicitBatchDimensions)",
+    ),
+)
+
+
+@pytest.mark.parametrize("dispatch", ["einsum", _SORT_UNDER_PIPELINE])
 def test_moe_under_pipeline_matches_dp_at_m1(tiny_model_cfg, opt_cfg,
                                              train_cfg_factory, dispatch):
     """PP x EP: with one microbatch the pipeline's per-stage aux sum equals
@@ -258,7 +276,7 @@ def test_moe_under_pipeline_matches_dp_at_m1(tiny_model_cfg, opt_cfg,
     np.testing.assert_allclose(pp.losses, dp.losses, rtol=5e-4, atol=5e-4)
 
 
-@pytest.mark.parametrize("dispatch", ["einsum", "sort"])
+@pytest.mark.parametrize("dispatch", ["einsum", _SORT_UNDER_PIPELINE])
 def test_moe_under_pipeline_1f1b_matches_gpipe(tiny_model_cfg, opt_cfg,
                                                train_cfg_factory, dispatch):
     """Both pipeline schedules thread the MoE aux loss (GPipe: through the

@@ -86,7 +86,6 @@ def _launch(tmp_path, variant: str):
             env.get("XLA_FLAGS", "")
             .replace("--xla_force_host_platform_device_count=8", "")
             + " --xla_force_host_platform_device_count=4"
-            + " --xla_cpu_use_thunk_runtime=false"
         )
         env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")

@@ -114,7 +114,7 @@ def test_reduce_scatter_matmul_vs_psum_scatter(
             backend=backend,
         ))(a, b)
 
-        from dtc_tpu.utils.compat import shard_map
+        from jax import shard_map
 
         def oracle_local(al, bl):
             part = jnp.einsum(
