@@ -745,19 +745,31 @@ class OnlineGoodput:
         self.registry = registry
         self.counter_every = max(int(counter_every), 0)
         self._win: Any = deque(maxlen=max(int(window), 2))
+        # Running sums over the window: pct() is read every step inside the
+        # trainer's timed loop (train.obs), so it must not walk the window.
+        self._total = 0.0
+        self._prod = 0.0
         self._updates = 0
 
     def note(self, klass: str, seconds: float) -> None:
         """Attribute ``seconds`` of wall-clock to one taxonomy class."""
-        if seconds > 0.0:
-            self._win.append((klass, float(seconds)))
+        if seconds <= 0.0:
+            return
+        seconds = float(seconds)
+        if len(self._win) == self._win.maxlen:
+            k, s = self._win[0]  # the append below pushes it out
+            self._total -= s
+            if k in PRODUCTIVE:
+                self._prod -= s
+        self._win.append((klass, seconds))
+        self._total += seconds
+        if klass in PRODUCTIVE:
+            self._prod += seconds
 
     def pct(self) -> float | None:
-        total = sum(s for _, s in self._win)
-        if total <= 0.0:
+        if self._total <= 0.0:
             return None
-        prod = sum(s for k, s in self._win if k in PRODUCTIVE)
-        return 100.0 * prod / total
+        return 100.0 * max(self._prod, 0.0) / self._total  # the sums' rounding may dip under 0
 
     def update(self, **where: Any) -> float | None:
         """Refresh the gauge; every ``counter_every``-th call also emits

@@ -345,17 +345,19 @@ class MetricsRegistry:
         self._clock = clock
 
     # -- instruments ------------------------------------------------------
+    # Get-or-create without building a throwaway instrument on every call:
+    # the trainer asks for its histogram and gauges once a step.
     def counter(self, name: str) -> Counter:
-        return self._counters.setdefault(name, Counter(name))
+        return self._counters.get(name) or self._counters.setdefault(name, Counter(name))
 
     def gauge(self, name: str) -> Gauge:
-        return self._gauges.setdefault(name, Gauge(name))
+        return self._gauges.get(name) or self._gauges.setdefault(name, Gauge(name))
 
     def timer(self, name: str) -> Timer:
-        return self._timers.setdefault(name, Timer(name))
+        return self._timers.get(name) or self._timers.setdefault(name, Timer(name))
 
     def histogram(self, name: str) -> Histogram:
-        return self._hists.setdefault(name, Histogram(name))
+        return self._hists.get(name) or self._hists.setdefault(name, Histogram(name))
 
     def drop_histogram(self, name: str) -> None:
         """Forget one histogram (no-op when absent). For DYNAMICALLY named

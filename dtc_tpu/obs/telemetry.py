@@ -6,7 +6,7 @@ generate) talks to it through a small hook interface::
 
     tele.on_run_start(...)
     tele.on_step_start(step)
-    with tele.clock.phase("data_wait"): ...
+    with tele.clock.phase("data_wait"): ...      # and dispatch > rng, launch; block
     tele.on_step_end(step, synced=...)
     tele.on_eval(step, loss, duration_s)
     tele.on_run_end(...); tele.close()
@@ -21,8 +21,11 @@ Event stream schema (JSONL, one shard per process — see README
 - ``compile``      — first XLA backend-compile window (init + warmup),
                      labeled step 0;
 - ``recompile``    — any later compile: something changed shape mid-run;
-- ``step``         — per-step breakdown: ``data_wait_s``, ``dispatch_s``,
-                     ``block_s``, ``other_s``, ``step_time_s``,
+- ``step``         — per-step breakdown: ``data_wait_s``, ``dispatch_s``
+                     (of which ``rng_s`` the eager key fold and
+                     ``launch_s`` the step call), ``block_s``, ``other_s``,
+                     ``step_time_s``, ``between_s`` (the loop's time
+                     between the step before's end and this one's begin),
                      cumulative ``elapsed_s``;
 - ``train_row``    — the CSV-schema row (step, elapsed_time, loss), also
                      bridged to ``log.csv`` by the CSV sink;
@@ -139,6 +142,9 @@ class Telemetry:
             self.registry, enabled=self.cfg.enabled and self.cfg.trace,
             clock=time.time, tid="train",
         )
+        # The step clock stamps on perf_counter; spans live on the tracer's
+        # clock. One offset, taken once, carries stamps from one to the other.
+        self._clock_offset = self.tracer.clock() - time.perf_counter()
         self.recorder: FlightRecorder | None = None
         if self.cfg.enabled and self.cfg.flight_recorder > 0:
             self.recorder = self.registry.add_sink(
@@ -239,19 +245,31 @@ class Telemetry:
         self.registry.emit("run_start", **meta)
 
     def on_step_start(self, step: int) -> None:
-        self.profiler.step(step)
-        if self.devprof is not None:
-            # One jax profiler session per process: defer devprof windows
-            # while the legacy configured window is mid-capture.
-            self.devprof.on_step(step, busy=self.profiler._active)
+        # The pass before ends here, ahead of the profiler's own start /
+        # stop, so that a window holds its last iteration's spans whole.
+        self.clock.close()
+        with self.clock.phase("obs"):
+            self.profiler.step(step)
+            if self.devprof is not None:
+                # One jax profiler session per process: defer devprof windows
+                # while the legacy configured window is mid-capture.
+                self.devprof.on_step(step, busy=self.profiler._active)
         self.clock.begin(step)
 
     def on_step_end(self, step: int, *, elapsed_s: float, synced: bool) -> dict:
         """Close the step's clock, fold in any compile the step triggered,
-        emit the ``step`` event, and sample memory on cadence."""
+        emit the ``step`` event, and sample memory on cadence. Everything
+        after the clock has closed is the ``obs`` phase; the rest of the
+        loop body, up to the next ``on_step_start``, is ``tail``."""
         breakdown = self.clock.end()
+        with self.clock.phase("obs"):
+            self._after_step(step, breakdown, elapsed_s, synced)
+        self.clock.tail()
+        return breakdown
+
+    def _after_step(self, step: int, breakdown: dict, elapsed_s: float,
+                    synced: bool) -> None:
         self.registry.histogram("step_time_s").observe(breakdown["step_time_s"])
-        self.registry.histogram("data_wait_s").observe(breakdown["data_wait_s"])
         compile_s, n = self.compiles.drain()
         extra: dict[str, Any] = {}
         if n:
@@ -278,26 +296,24 @@ class Telemetry:
             **breakdown,
             **extra,
         )
-        # Step/phase spans, synthesized from the breakdown the clock
-        # ALREADY measured (no extra syncs, one wall-clock read). The
-        # phases run in loop order data_wait -> dispatch -> block, so
-        # laying them end to end from the step start is exact up to the
-        # interleaved host overhead other_s accounts for.
+        # Step/phase spans from the clock's own start stamps (no extra
+        # clock read, no sync), moved onto the tracer's clock by the one
+        # offset taken at construction.
         if self.tracer.enabled:
-            t1 = time.time()
-            t0 = t1 - breakdown["step_time_s"]
+            off = self._clock_offset
+            t0 = self.clock.t0 + off
+            t1 = t0 + breakdown["step_time_s"]
             self.tracer.emit_span(
                 "step", t0, t1, cat="train", tid="train", step=step
             )
-            cursor = t0
-            for ph in ("data_wait", "dispatch", "block"):
+            for ph in self.clock.PHASES:
                 d = breakdown[f"{ph}_s"]
                 if d > 0:
+                    p0 = self.clock.starts[ph] + off
                     self.tracer.emit_span(
-                        ph, cursor, cursor + d, cat="train",
+                        ph, p0, p0 + d, cat="train",
                         tid="train.phase", step=step,
                     )
-                    cursor += d
             # Only a STEADY-state recompile gets its span here; the
             # warmup-less first step's cold compile went through
             # _note_startup_compile above, which already emitted the
@@ -345,7 +361,6 @@ class Telemetry:
         every = self.cfg.memory_sample_every
         if self.cfg.enabled and every > 0 and step % every == 0:
             self.sample_memory(step)
-        return breakdown
 
     def record_aux_compile(self, step: int, what: str) -> None:
         """Drain compile seconds attributable to auxiliary host-side
@@ -610,6 +625,7 @@ class Telemetry:
         if self._closed:
             return
         self._closed = True
+        self.clock.close()  # loop exit: the last pass's tail and group
         self.profiler.close()
         if self.devprof is not None:
             self.devprof.close()  # finalize a window the run ended inside

@@ -1244,9 +1244,12 @@ def _train(
                 with tele.clock.phase("data_wait"):
                     x, y = next(data_it)
                 with tele.clock.phase("dispatch"):
-                    state, loss = train_step(
-                        state, Batch(x=x, y=y), jax.random.fold_in(key, step)
-                    )
+                    # Eager: two small device programs launched from Python
+                    # every step (PERF.md, `idle_rng_ms.train`).
+                    with tele.clock.phase("rng"):
+                        step_key = jax.random.fold_in(key, step)
+                    with tele.clock.phase("launch"):
+                        state, loss = train_step(state, Batch(x=x, y=y), step_key)
                 if chaos is not None:
                     poisoned, loss = chaos.maybe_poison(step, state, loss)
                     if poisoned is not state:
