@@ -64,8 +64,9 @@ Three families:
    :func:`lint_gate_coverage` AST-checks that every ops/ module
    launching a ``pallas_call`` gates it behind a ``supports*`` /
    ``_pallas_ok`` predicate that consults the shared planner, so gate
-   and kernel cannot drift (flash_attention carries a documented
-   waiver: its tile sizes are config-validated, not planner-gated).
+   and kernel cannot drift (no module is waived: flash attention's
+   tiles come from the planner's ``flash_plan``, and a user's tiling is
+   priced by ``flash_grid_tile_fits``).
 
 ``scripts/audit_graph.py --kernels`` is the CLI;
 ``scripts/verify_tier1.sh`` runs it as a pre-gate. Everything here is
@@ -99,14 +100,9 @@ LADDER_RUNGS = ("flagship", "ladder_350m", "ladder_1b")
 
 #: ops/ modules allowed to launch a pallas_call without consulting the
 #: shared VMEM planner, with the reason (emitted as an info finding so
-#: the waiver stays visible in every audit run).
-PALLAS_GATE_WAIVERS = {
-    "flash_attention.py": (
-        "tile sizes are user config (attention_block_*), validated by "
-        "ModelConfig and bounded by the flash gate's own shape checks — "
-        "a planner consult would duplicate the config validation"
-    ),
-}
+#: the waiver stays visible in every audit run). None since PR 27: the
+#: flash kernels' tiles come from :func:`vmem.flash_plan`.
+PALLAS_GATE_WAIVERS: dict[str, str] = {}
 
 
 # ---------------------------------------------------------------------------
@@ -728,6 +724,12 @@ def rung_fingerprint(name: str) -> dict[str, Any]:
         "decode_single": vmem.decode_single_plan(cfg),
         "decode_blocked": vmem.decode_blocked_plan(cfg),
     }
+    flash = vmem.flash_plan(
+        cfg.max_seq_len, cfg.head_dim, cfg.n_heads,
+        vmem._dtype_bytes(cfg.compute_dtype),
+    )
+    if flash is not None:  # the packed layout applies: the kernel chooses
+        kernels["flash_packed"] = flash
     for site, plan in _overlap_sites(cfg).items():
         kernels[f"overlap_{site}"] = plan
     hbm = train_memory_bytes(

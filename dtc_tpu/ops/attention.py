@@ -99,6 +99,29 @@ def resolve_impl(
     return "dense"
 
 
+def flash_plan_event(cfg) -> dict | None:
+    """Fields of the trainer's one ``flash_plan`` start-up event: what the
+    flash kernel will do with the score square under ``cfg`` (a
+    ModelConfig), or None where its attention does not resolve to flash
+    on this backend. See :func:`flash_attention.schedule`."""
+    from dtc_tpu.config.schema import DTYPE_BYTES
+    from dtc_tpu.ops import flash_attention
+
+    blocks = (
+        cfg.attention_block_q, cfg.attention_block_kv,
+        cfg.attention_block_q_bwd, cfg.attention_block_kv_bwd,
+    )
+    t, d = cfg.max_seq_len, cfg.head_dim
+    if resolve_impl(cfg.attention, t, d, *blocks[:2]) != "flash":
+        return None
+    plan = flash_attention.schedule(
+        t, cfg.n_heads, d, DTYPE_BYTES.get(cfg.compute_dtype, 4), *blocks
+    )
+    if plan is None:
+        return None
+    return {"seq_len": t, "head_dim": d, "heads": cfg.n_heads, **plan}
+
+
 def _flash_per_shard(q, k, v, spec: P | None, **blocks) -> jax.Array:
     """The flash kernel on each device's own (batch, heads) shard.
 

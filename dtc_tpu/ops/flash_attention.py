@@ -16,6 +16,11 @@ ever exist one ``(block_q, block_kv)`` VMEM tile at a time:
 - Causal structure is exploited twice: blocks strictly above the diagonal
   are predicated out entirely (``@pl.when``), and diagonal-straddling blocks
   apply an iota position mask.
+- On the packed layout, with the blocks left at their defaults, the tiles
+  come from the shape (``ops/vmem.py:flash_plan``) and the kernels follow
+  the causal triangle themselves: K and V of a lane group resident, large
+  unmasked updates below the diagonal, the diagonal in row strips that
+  stop at it. See "causal triangle followed inside the kernel" below.
 
 HBM-layout notes (what made this fast on a v5e):
 
@@ -38,6 +43,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from dtc_tpu.ops import vmem
 
 NEG_INF = -1e9  # matches the reference's additive mask value (ops/attention.py)
 _LANES = 128  # TPU lane width (kept for stat-scratch shapes)
@@ -406,7 +413,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 # ---------------------------------------------------------------------------
-# Packed (transpose-free) kernels for the single-KV-tile case.
+# Packed (transpose-free) kernels.
 #
 # The model's natural layout is (B, T, H*D) — the raw output of the qkv
 # projections. The original kernels wanted (B, H, T, D), and XLA realised
@@ -500,66 +507,316 @@ def _packed_tile_bwd(qt, kt, vt, dot_, ot, lse, mask, sl, scale, delta=None):
     return dq_c, dk_c, dv_c
 
 
-def _fwd_kernel_packed(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                       block_q, block_kv, g, d, scale):
-    """Single-KV-tile forward on packed (B, T, H*D) inputs; one grid slot
-    handles g heads living side-by-side in a 128-lane block."""
-    i = pl.program_id(2)
-    mask = _mask(i, 0, block_q, block_kv)
-    qt, kt, vt = q_ref[0], k_ref[0], v_ref[0]      # (bq, g*d), (bkv, g*d)
-    for gg in range(g):
-        sl = slice(gg * d, (gg + 1) * d)
-        s = _packed_scores(qt, kt, sl, scale, mask)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        acc = jax.lax.dot_general(
-            p.astype(vt.dtype), vt[:, sl], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        o_ref[0, :, sl] = (acc / l).astype(o_ref.dtype)
-        lse_ref[0, 0, :, gg : gg + 1] = m + jnp.log(l)
+# --- packed, causal triangle followed inside the kernel ---------------------
+#
+# Grid (rows, lane groups, q blocks). K and V of a lane group stay in VMEM
+# across its q blocks (same block index -> no second DMA). Below the q
+# block's first row the kernel loops over KV chunks unmasked, all of the
+# block's rows at once; the block's own square on the diagonal it walks in
+# row strips of ``diag`` rows, each against the columns it can see: what lies
+# left of the strip's diagonal unit unmasked, the unit itself masked, what
+# lies right of it never issued — a skipped unit costs nothing, where a
+# skipped grid step still costs its ~0.35 us of pipeline bookkeeping. A strip
+# is one softmax update however many units wide it is: the chip pays per
+# update (a chain of matmul, reduce, exp, reduce, matmul it cannot overlap
+# with the next), so the tiles are as large as the triangle allows and only
+# the unit of skipping is small. Where the q block is the whole sequence
+# every strip sees all its columns at once and nothing is carried.
+#
+# A head is selected by zeroing the other heads' lanes of the q-side operand
+# once per q block, so every matmul contracts or emits whole 128-lane tiles
+# (at head size 64 a half-filled MXU pass costs what a full one does) and
+# nothing in the loop slices or shifts lanes; m and l of a head are held
+# replicated across a lane tile, so their update is whole-register work.
 
 
-def _bwd_kernel_packed(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                       dq_ref, dk_ref, dv_ref, *, block_q, block_kv, g, d, scale):
-    """Fused single-tile backward on packed inputs: p recomputed once per
-    head group; delta computed in VMEM from do and o."""
-    i = pl.program_id(2)
-    mask = _mask(i, 0, block_q, block_kv)
-    qt, kt, vt = q_ref[0], k_ref[0], v_ref[0]
-    dot_, ot = do_ref[0], o_ref[0]
-    for gg in range(g):
-        sl = slice(gg * d, (gg + 1) * d)
-        lse = lse_ref[0, 0, :, gg : gg + 1]        # (block_q, 1) fp32
-        dq_c, dk_c, dv_c = _packed_tile_bwd(
-            qt, kt, vt, dot_, ot, lse, mask, sl, scale
-        )
-        dq_ref[0, :, sl] = dq_c.astype(dq_ref.dtype)
-        dk_ref[0, :, sl] = dk_c.astype(dk_ref.dtype)
-        dv_ref[0, :, sl] = dv_c.astype(dv_ref.dtype)
+def _head_lanes(g, d):
+    """Per head of the lane group, the (1, 128) mask of its own lanes."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    return [(lane >= gg * d) & (lane < (gg + 1) * d) for gg in range(g)]
 
 
-def _packed_specs(t, block_q):
-    """(q/o spec, kv spec) for the packed (B, T, H*D) layout. Only valid
-    for the single-tile case (t == block_q): the backward writes dk/dv
-    whole-tile per grid slot, which would race across q blocks otherwise."""
-    dspec = pl.BlockSpec((1, block_q, _LANES), lambda bi, gi, i: (bi, i, gi))
-    kvspec = pl.BlockSpec((1, t, _LANES), lambda bi, gi, i: (bi, 0, gi))
-    return dspec, kvspec
+def _per_head(x, lanes):
+    """``x`` with every other head's lanes zeroed, one copy per head."""
+    if len(lanes) == 1:
+        return [x]
+    return [jnp.where(m, x, jnp.zeros_like(x)) for m in lanes]
 
 
-# --- packed multi-tile: causal block skipping (25% less compute at 2x2) ---
+def _merge_heads(xs, lanes):
+    """Each head's own lanes of its (n, 128) array, side by side."""
+    out = xs[0]
+    for m, x in zip(lanes[1:], xs[1:]):
+        out = jnp.where(m, x, out)
+    return out
+
+
+def _walk_triangle(i, block_q, chunk, diag, below, strip):
+    """The schedule of q block i, shared by forward and backward:
+    ``below(cols)`` once per KV chunk wholly under the block's first row,
+    then per row strip r ``strip(rows, segs)`` with ``segs`` the column
+    ranges it sees inside the block's own square as ``(cols, mask)``:
+    the units left of the diagonal unmasked, the diagonal unit masked.
+    ``i`` None: the block is the whole sequence, every index is static."""
+    col0 = 0
+    if i is not None:
+        col0 = i * block_q
+
+        def body(j, carry):
+            below(pl.ds(pl.multiple_of(j * chunk, chunk), chunk))
+            return carry
+
+        jax.lax.fori_loop(0, i * (block_q // chunk), body, 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (diag, diag), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (diag, diag), 1)
+    mask = col <= row
+
+    def cols(start, size):
+        if i is None:
+            return pl.ds(start, size)
+        return pl.ds(pl.multiple_of(col0 + start, diag), size)
+
+    for r in range(block_q // diag):
+        segs = [(cols(0, r * diag), None)] if r else []
+        strip(slice(r * diag, (r + 1) * diag), segs + [(cols(r * diag, diag), mask)])
+
+
+def _fwd_kernel_tri(q_ref, k_ref, v_ref, o_ref, lse_ref, *stats,
+                    block_q, chunk, diag, g, d, scale):
+    whole = not stats  # the q block is the whole sequence: nothing carried
+    lanes = _head_lanes(g, d)
+    qh = _per_head(q_ref[0] * scale, lanes)     # scaled once per q block
+
+    def update(rows, segs, prev):
+        """One online-softmax update of ``rows`` against the column
+        segments, per head: (m, l, acc), m and l lane-dense (n, 128)."""
+        kv = [(k_ref[0, cols, :], v_ref[0, cols, :], mask) for cols, mask in segs]
+        new = []
+        for gg in range(g):
+            ss = []
+            for kt, _, mask in kv:
+                s = jax.lax.dot_general(
+                    qh[gg][rows], kt, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                ss.append(s if mask is None else jnp.where(mask, s, NEG_INF))
+            m_cur = functools.reduce(
+                jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for s in ss]
+            )
+            if prev is None:
+                m_new = jnp.broadcast_to(m_cur, (m_cur.shape[0], _LANES))
+            else:
+                m_prev, l_prev, acc_prev = prev[gg]
+                m_new = jnp.maximum(m_prev, m_cur)
+                alpha = jnp.exp(m_prev - m_new)
+            l_new = acc = None
+            for s, (_, vt, _) in zip(ss, kv):
+                rep = s.shape[1] // _LANES
+                p = jnp.exp(s - (pltpu.repeat(m_new, rep, 1) if rep > 1 else m_new))
+                l_seg = jnp.sum(p, axis=-1, keepdims=True)
+                # p . [v_0 | v_1 | ...]: this head's lanes of the product
+                # are its own p . v, the others are dropped at the end.
+                pv = jax.lax.dot_general(
+                    p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                l_new = l_seg if l_new is None else l_new + l_seg
+                acc = pv if acc is None else acc + pv
+            if prev is None:
+                l_new = jnp.broadcast_to(l_new, m_new.shape)
+            else:
+                l_new, acc = alpha * l_prev + l_new, alpha * acc_prev + acc
+            new.append((m_new, l_new, acc))
+        return new
+
+    if not whole:
+        m_scr, l_scr, acc_scr = stats
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    def carried(rows):
+        return [(m_scr[gg, rows], l_scr[gg, rows], acc_scr[gg, rows]) for gg in range(g)]
+
+    def below(cols):
+        rows = slice(None)
+        for gg, (m, l, acc) in enumerate(update(rows, [(cols, None)], carried(rows))):
+            m_scr[gg], l_scr[gg], acc_scr[gg] = m, l, acc
+
+    def strip(rows, segs):
+        done = update(rows, segs, None if whole else carried(rows))
+        out = _merge_heads([acc / l for _, l, acc in done], lanes)
+        o_ref[0, rows, :] = out.astype(o_ref.dtype)
+        for gg, (m, l, _) in enumerate(done):
+            lse_ref[0, 0, rows, gg : gg + 1] = (m + jnp.log(l))[:, :1]
+
+    i = None if whole else pl.program_id(2)
+    _walk_triangle(i, block_q, chunk, diag, below, strip)
+
+
+def _bwd_kernel_tri(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                    dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *dq_scr,
+                    block_q, chunk, diag, g, d, scale):
+    """The fused five-matmul backward on the same schedule: p recomputed
+    once per segment and head from the saved lse; dq of a q block
+    accumulates over its chunks, dk/dv rows over the q blocks that see
+    them, written whole after the last."""
+    whole = not dq_scr
+    i = None if whole else pl.program_id(2)
+
+    def first():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    if whole:
+        first()
+    else:
+        pl.when(i == 0)(first)
+
+    lanes = _head_lanes(g, d)
+    dot_ = do_ref[0]
+    qh = _per_head(q_ref[0] * scale, lanes)
+    doh = _per_head(dot_, lanes)
+    dod = dot_.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+    delta = [jnp.sum(x, axis=-1, keepdims=True) for x in _per_head(dod, lanes)]
+    lse = [lse_ref[0, 0, :, gg : gg + 1] for gg in range(g)]
+
+    def grads(rows, segs):
+        """dq of ``rows`` from the column segments (heads merged); their
+        dk and dv go into the accumulators."""
+        dq = [None] * g
+        for cols, mask in segs:
+            kt, vt = k_ref[0, cols, :], v_ref[0, cols, :]
+            dk_c = dv_c = None
+            for gg in range(g):
+                q_r, do_r = qh[gg][rows], doh[gg][rows]
+                s = jax.lax.dot_general(
+                    q_r, kt, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                p = jnp.exp(s - lse[gg][rows])
+                if mask is not None:
+                    p = jnp.where(mask, p, 0.0)
+                dp = jax.lax.dot_general(
+                    do_r, vt, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                ds = (p * (dp - delta[gg][rows])).astype(kt.dtype)
+                # ds . [k_0 | k_1 | ...]: only this head's lanes are its dq.
+                dq_h = jax.lax.dot_general(
+                    ds, kt, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                # q and do carry zeros in the other heads' lanes, so these
+                # land in this head's lanes alone and the heads simply add.
+                dk_h = jax.lax.dot_general(
+                    ds, q_r, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                dv_h = jax.lax.dot_general(
+                    p.astype(dot_.dtype), do_r, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                dq[gg] = dq_h if dq[gg] is None else dq[gg] + dq_h
+                dk_c = dk_h if dk_c is None else dk_c + dk_h
+                dv_c = dv_h if dv_c is None else dv_c + dv_h
+            dk_scr[cols, :] += dk_c
+            dv_scr[cols, :] += dv_c
+        return _merge_heads(dq, lanes)
+
+    if not whole:
+        dq_scr[0][:] = jnp.zeros_like(dq_scr[0])
+
+    def below(cols):
+        dq_scr[0][:] += grads(slice(None), [(cols, None)])
+
+    def strip(rows, segs):
+        dq = grads(rows, segs)
+        if not whole:
+            dq = dq + dq_scr[0][rows, :]
+        dq_ref[0, rows, :] = (dq * scale).astype(dq_ref.dtype)
+
+    _walk_triangle(i, block_q, chunk, diag, below, strip)
+
+    def last():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    if whole:
+        last()
+    else:
+        pl.when(i == pl.num_programs(2) - 1)(last)
+
+
+def _tri_call(kernel, args, outs, scratch, tri, g, d, scale):
+    """Launch a triangle kernel: ``args`` / ``outs`` are (array or
+    ShapeDtypeStruct, kind) with kind "q" (a q block), "kv" (resident,
+    whole T) or "lse"; ``scratch`` is what the pass needs however many q
+    blocks there are, the carried state is added where there are several."""
+    block_q, chunk, diag, limit = tri
+    b, t, hd = args[0][0].shape
+    specs = {
+        "q": pl.BlockSpec((1, block_q, _LANES), lambda bi, gi, i: (bi, i, gi)),
+        "kv": pl.BlockSpec((1, t, _LANES), lambda bi, gi, i: (bi, 0, gi)),
+        "lse": pl.BlockSpec((1, 1, block_q, g), lambda bi, gi, i: (bi, gi, i, 0)),
+    }
+    return pl.pallas_call(
+        functools.partial(
+            kernel, block_q=block_q, chunk=chunk, diag=diag, g=g, d=d, scale=scale
+        ),
+        grid=(b, hd // _LANES, t // block_q),
+        in_specs=[specs[kind] for _, kind in args],
+        out_specs=[specs[kind] for _, kind in outs],
+        out_shape=[x for x, _ in outs],
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=limit,
+        ),
+        interpret=_interpret(),
+    )(*(x for x, _ in args))
+
+
+def _tri_fwd_call(q, k, v, tri, g, d, scale):
+    b, t, hd = q.shape
+    block_q = tri[0]
+    stat = pltpu.VMEM((g, block_q, _LANES), jnp.float32)  # lane-dense, per head
+    return _tri_call(
+        _fwd_kernel_tri,
+        [(q, "q"), (k, "kv"), (v, "kv")],
+        [(jax.ShapeDtypeStruct((b, t, hd), q.dtype), "q"),
+         (jax.ShapeDtypeStruct((b, hd // _LANES, t, g), jnp.float32), "lse")],
+        [] if block_q == t else [stat, stat, stat],      # m, l, p . V
+        tri, g, d, scale,
+    )
+
+
+def _tri_bwd_call(q, k, v, do, out, lse, tri, g, d, scale):
+    b, t, hd = q.shape
+    block_q = tri[0]
+    acc = pltpu.VMEM((t, _LANES), jnp.float32)           # dk, dv accumulators
+    dq_acc = pltpu.VMEM((block_q, _LANES), jnp.float32)
+    sds = lambda x: jax.ShapeDtypeStruct((b, t, hd), x.dtype)  # noqa: E731
+    return _tri_call(
+        _bwd_kernel_tri,
+        [(q, "q"), (k, "kv"), (v, "kv"), (do, "q"), (out, "q"), (lse, "lse")],
+        [(sds(q), "q"), (sds(k), "kv"), (sds(v), "kv")],
+        [acc, acc] if block_q == t else [acc, acc, dq_acc],
+        tri, g, d, scale,
+    )
+
+
+# --- packed, grid-walking: one grid step a tile, for a user's tiling --------
 
 
 def _fwd_kernel_packed_multi(q_ref, k_ref, v_ref, o_ref, lse_ref,
                              m_scr, l_scr, acc_scr, *,
                              block_q, block_kv, g, d, scale):
     """Online-softmax forward on packed layout, KV blocks walked innermost.
-    Blocks strictly above the causal diagonal are predicated out entirely —
-    the single-tile kernel pays for the whole T² tile, this one only for
-    the lower-triangular blocks. Scratch columns gg hold head gg's running
-    stats; acc uses the same lane slot as the head's output slice."""
+    Blocks strictly above the causal diagonal are predicated out entirely.
+    Scratch columns gg hold head gg's running stats; acc uses the same
+    lane slot as the head's output slice. Runs a user's tiling; blocks
+    left at their defaults take the triangle kernels above."""
     i, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(j == 0)
@@ -821,37 +1078,22 @@ def _packed_split_bwd_call(q, k, v, do, out, lse, block_q, block_kv, g, d, scale
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_packed(q, k, v, block_q, block_kv, g, d, scale,
-                  block_q_bwd, block_kv_bwd):
-    out, _ = _packed_fwd_call(q, k, v, block_q, block_kv, g, d, scale)
+                  block_q_bwd, block_kv_bwd, tri_fwd=None, tri_bwd=None):
+    """``tri_fwd`` / ``tri_bwd``: (q block, KV chunk, diagonal unit,
+    vmem_limit_bytes) of the causal-triangle schedule for that pass, or
+    None to walk the grid with the ``block_*`` tiles."""
+    out, _ = _packed_fwd_call(q, k, v, block_q, block_kv, g, d, scale, tri_fwd)
     return out
 
 
-def _packed_fwd_call(q, k, v, block_q, block_kv, g, d, scale):
+def _packed_fwd_call(q, k, v, block_q, block_kv, g, d, scale, tri=None):
+    if tri is not None:
+        return _tri_fwd_call(q, k, v, tri, g, d, scale)
     b, t, hd = q.shape
     hg = hd // _LANES
     nq = t // block_q
-    if block_kv == t and nq == 1:
-        # Whole tile: one-pass kernel, no online-softmax scratch.
-        dspec, kvspec = _packed_specs(t, block_q)
-        lsespec = pl.BlockSpec((1, 1, block_q, g), lambda bi, gi, i: (bi, gi, i, 0))
-        return pl.pallas_call(
-            functools.partial(
-                _fwd_kernel_packed, block_q=block_q, block_kv=t, g=g, d=d, scale=scale
-            ),
-            grid=(b, hg, nq),
-            in_specs=[dspec, kvspec, kvspec],
-            out_specs=[dspec, lsespec],
-            out_shape=[
-                jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-                jax.ShapeDtypeStruct((b, hg, t, g), jnp.float32),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel"),
-            ),
-            interpret=_interpret(),
-        )(q, k, v)
     nkv = t // block_kv
     qspec = pl.BlockSpec((1, block_q, _LANES), lambda bi, gi, i, j: (bi, i, gi))
     kvspec = pl.BlockSpec((1, block_kv, _LANES), lambda bi, gi, i, j: (bi, j, gi))
@@ -881,8 +1123,8 @@ def _packed_fwd_call(q, k, v, block_q, block_kv, g, d, scale):
 
 
 def _packed_flash_fwd(q, k, v, block_q, block_kv, g, d, scale,
-                      block_q_bwd, block_kv_bwd):
-    out, lse = _packed_fwd_call(q, k, v, block_q, block_kv, g, d, scale)
+                      block_q_bwd, block_kv_bwd, tri_fwd=None, tri_bwd=None):
+    out, lse = _packed_fwd_call(q, k, v, block_q, block_kv, g, d, scale, tri_fwd)
     # Policy-saveable residuals — see _flash_fwd for the rationale.
     out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
@@ -893,28 +1135,27 @@ def _packed_flash_fwd(q, k, v, block_q, block_kv, g, d, scale,
 
 
 def _packed_flash_bwd(block_q, block_kv, g, d, scale,
-                      block_q_bwd, block_kv_bwd, res, do):
+                      block_q_bwd, block_kv_bwd, tri_fwd, tri_bwd, res, do):
     q, k, v, out, lse = res
+    if tri_bwd is not None:
+        return _tri_bwd_call(q, k, v, do, out, lse, tri_bwd, g, d, scale)
     b, t, hd = q.shape
     hg = hd // _LANES
     # The backward's best tiling differs from the forward's (the fused
     # kernel holds dk/dv scratches the forward doesn't; measured on v5e,
-    # PERF.md round 5): nonzero overrides retile it independently —
-    # including OUT of the single-tile fast path, so the knob is honored
-    # uniformly. The saved lse is blocked afresh by these specs, so any
-    # valid tiling of the same arrays works.
+    # PERF.md round 5): nonzero overrides retile it independently. The
+    # saved lse is blocked afresh by these specs, so any valid tiling of
+    # the same arrays works.
     if block_q_bwd:
         block_q = block_q_bwd
     if block_kv_bwd:
         block_kv = block_kv_bwd
     nq = t // block_q
-    # Guard ORDER matters (round-5 ADVICE): the T cap must be checked
-    # before the single-tile fast path, or a user tiling override that
-    # resolves to one whole-T tile at T > _PACKED_MAX_T reaches the fused
-    # kernel — whose full-T VMEM scratches then die as an opaque Mosaic
-    # compile OOM instead of this error. flash_causal_attention validates
-    # the same condition at the API surface; this is the defense for
-    # direct _flash_packed callers.
+    # A user tiling override that resolves to one whole-T tile at
+    # T > _PACKED_MAX_T must not reach the fused kernel — its full-T VMEM
+    # scratches would die as an opaque Mosaic compile OOM instead of this
+    # error. flash_causal_attention validates the same condition at the
+    # API surface; this is the defense for direct _flash_packed callers.
     if t > _PACKED_MAX_T:
         if block_kv == t and nq == 1:
             raise ValueError(
@@ -928,27 +1169,6 @@ def _packed_flash_bwd(block_q, block_kv, g, d, scale,
         return _packed_split_bwd_call(
             q, k, v, do, out, lse, block_q, block_kv, g, d, scale
         )
-    if block_kv == t and nq == 1:
-        dspec, kvspec = _packed_specs(t, block_q)
-        lsespec = pl.BlockSpec((1, 1, block_q, g), lambda bi, gi, i: (bi, gi, i, 0))
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(
-                _bwd_kernel_packed, block_q=block_q, block_kv=t, g=g, d=d, scale=scale
-            ),
-            grid=(b, hg, nq),
-            in_specs=[dspec, kvspec, kvspec, dspec, dspec, lsespec],
-            out_specs=[dspec, kvspec, kvspec],
-            out_shape=[
-                jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-                jax.ShapeDtypeStruct((b, t, hd), k.dtype),
-                jax.ShapeDtypeStruct((b, t, hd), v.dtype),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel"),
-            ),
-            interpret=_interpret(),
-        )(q, k, v, do, out, lse)
-        return dq, dk, dv
     nkv = t // block_kv
     qspec = pl.BlockSpec((1, block_q, _LANES), lambda bi, gi, i, j: (bi, i, gi))
     kvspec = pl.BlockSpec((1, block_kv, _LANES), lambda bi, gi, i, j: (bi, j, gi))
@@ -991,7 +1211,67 @@ def supports(t: int, d: int, block_q: int, block_kv: int) -> bool:
         t % bq == 0 and t % bkv == 0
         and bq % 8 == 0 and bkv % _LANES == 0
         and d <= 512  # per-tile head_dim must fit VMEM comfortably
+        and (_chosen(block_q, block_kv, 0, 0) or vmem.flash_grid_tile_fits(bq, bkv))
     )
+
+
+def _chosen(block_q, block_kv, block_q_bwd, block_kv_bwd) -> bool:
+    """The blocks were left at the config's defaults: the kernel chooses."""
+    return (block_q, block_kv, block_q_bwd, block_kv_bwd) == (
+        vmem.FLASH_DEFAULT_BLOCK, vmem.FLASH_DEFAULT_BLOCK, 0, 0
+    )
+
+
+def _triangle(t, d, h, itemsize):
+    """(tri_fwd, tri_bwd) for :func:`_flash_packed` from the planner: each
+    (q block, KV chunk, diagonal unit, vmem_limit_bytes), or None where K
+    and V of a lane group do not fit VMEM whole beside that pass's
+    accumulators (the backward never past ``_PACKED_MAX_T``: the split
+    kernels take over)."""
+    plan = vmem.flash_plan(t, d, h, itemsize)
+
+    def one(name):
+        leg = plan[name]
+        if not leg["fits"]:
+            return None
+        return leg["block_q"], leg["kv_chunk"], leg["unit"][0], leg["vmem_limit_bytes"]
+
+    if plan is None:
+        return None, None
+    return one("fwd"), one("bwd") if t <= _PACKED_MAX_T else None
+
+
+def schedule(
+    t: int, h: int, d: int, itemsize: int = 2,
+    block_q: int = vmem.FLASH_DEFAULT_BLOCK, block_kv: int = vmem.FLASH_DEFAULT_BLOCK,
+    block_q_bwd: int = 0, block_kv_bwd: int = 0,
+) -> dict | None:
+    """How :func:`flash_causal_attention` will walk the score square at
+    this shape, per pass: ``{"fwd": {...}, "bwd": {...}}``, each with
+    ``schedule`` ("triangle": the loop inside the kernel; "grid": one grid
+    step a tile), ``block_q``, ``kv_chunk``, the ``unit`` of skipping and
+    the units run / masked / skipped and the share of the square covered,
+    per (row, lane group).
+    Static, from the shape alone (no tracing): the trainer's start-up
+    event and the tests read the same function the dispatch does. None
+    off the packed layout (the transpose family tiles as configured)."""
+    if _packed_group(d, h) is None:
+        return None
+    tri = (None, None)
+    if _chosen(block_q, block_kv, block_q_bwd, block_kv_bwd):
+        tri = _triangle(t, d, h, itemsize)
+    grid = {
+        "fwd": (min(block_q, t), min(block_kv, t)),
+        "bwd": (min(block_q_bwd or block_q, t), min(block_kv_bwd or block_kv, t)),
+    }
+    out = {}
+    for name, leg in zip(("fwd", "bwd"), tri):
+        tiles = grid[name] if leg is None else leg[:3]
+        out[name] = {
+            "schedule": "grid" if leg is None else "triangle",
+            **vmem.flash_schedule(t, *tiles),
+        }
+    return out
 
 
 def flash_causal_attention(
@@ -1008,6 +1288,7 @@ def flash_causal_attention(
     wide KV blocks while the backward's scratches cap its tile budget.
     """
     b, t, h, d = q.shape
+    chosen = _chosen(block_q, block_kv, block_q_bwd, block_kv_bwd)
     block_q, block_kv = min(block_q, t), min(block_kv, t)
     block_q_bwd, block_kv_bwd = min(block_q_bwd, t), min(block_kv_bwd, t)
     if not supports(t, d, block_q, block_kv):
@@ -1022,9 +1303,9 @@ def flash_causal_attention(
             f"flash attention backward tiling unsupported for T={t}, "
             f"block_q_bwd={block_q_bwd}, block_kv_bwd={block_kv_bwd}"
         )
-    # Past _PACKED_MAX_T no kernel can hold a whole-T tile (the one-pass
-    # forward materializes (T, T) scores; fused AND split backwards hold
-    # (T, 128) accumulators) — reject single-tile tilings HERE with the
+    # Past _PACKED_MAX_T no grid-walking kernel can hold a whole-T tile
+    # (the forward materializes (T, T) scores; fused AND split backwards
+    # hold (T, 128) accumulators) — reject single-tile tilings HERE with the
     # cause named instead of letting pallas_call die in a Mosaic compile
     # OOM (round-5 ADVICE guard-order fix; the bwd-side check in
     # _packed_flash_bwd covers direct kernel callers).
@@ -1053,16 +1334,19 @@ def flash_causal_attention(
     if g is not None:
         # Packed transpose-free path: heads group into 128-lane blocks ->
         # operate on the model-native (B, T, H*D) layout directly. reshape
-        # is a bitcast; no HBM relayout anywhere. Single-tile shapes use
-        # the one-pass kernels; tiled shapes the online-softmax/causal-
-        # block-skipping ones. Beyond _PACKED_MAX_T the fused backward's
-        # full-T dk/dv scratches outgrow VMEM and the split dq/dkv
-        # kernels (all scratch O(block)) take over — packed at every T.
+        # is a bitcast; no HBM relayout anywhere. Blocks left at their
+        # defaults: the planner picks the tiles from the shape and the
+        # kernels follow the causal triangle themselves; a user's tiling
+        # walks the grid, one step a tile. Beyond _PACKED_MAX_T the fused
+        # backward's full-T dk/dv scratches outgrow VMEM and the split
+        # dq/dkv kernels (all scratch O(block)) take over — packed at
+        # every T.
         scale = float(d ** -0.5)
+        tri = _triangle(t, d, h, q.dtype.itemsize) if chosen else (None, None)
         out = _flash_packed(
             q.reshape(b, t, h * d), k.reshape(b, t, h * d),
             v.reshape(b, t, h * d), block_q, block_kv, g, d, scale,
-            block_q_bwd, block_kv_bwd,
+            block_q_bwd, block_kv_bwd, *tri,
         )
         return out.reshape(b, t, h, d)
 

@@ -369,6 +369,152 @@ def fused_layers_plan(cfg, t: int = 1, b: int = 1) -> dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
+# training flash attention, packed family (ops/flash_attention.py)
+# ---------------------------------------------------------------------------
+
+#: ``attention_block_q`` / ``attention_block_kv`` at this value (the
+#: schema's default, with both ``*_bwd`` at 0) mean "the kernel chooses":
+#: :func:`flash_plan` picks the tiles from the shape. Any other value is
+#: a user's tiling and runs as given.
+FLASH_DEFAULT_BLOCK = 512
+
+#: What a triangle flash kernel may plan for, blocks and scratch and the
+#: modeled transients together: a quarter of a v5e core's 128 MiB. The
+#: kernel states its own ``vmem_limit_bytes`` (the chip's default scoped
+#: limit is 16 MiB), so this is a choice, not the compiler's default:
+#: whole-sequence q blocks measured fastest up to T = 2048 (PERF.md, PR
+#: 27); this admits the forward's there and neither pass's at 4096,
+#: which was not timed.
+FLASH_VMEM_BUDGET_BYTES = 32 * 1024 * 1024
+
+
+def _flash_candidates(t: int) -> list[tuple[int, int, int]]:
+    """(q block, KV chunk, unit) from the largest q block down. The chip
+    pays per softmax update, not per element (PERF.md, PR 27: at T = 1024
+    one 1024-row block in strips took 0.97 ms a call forward + backward,
+    512-row blocks 1.19, 256-row blocks 1.48), so the q block is as large
+    as VMEM allows, the whole sequence first: then nothing is carried from
+    one update to the next. The unit of skipping is 256 (128 where the
+    block is under 512 rows): 128 and 512 both measured slower at T = 512
+    and 1024."""
+    out = []
+    for bq in (t, 1024, 512, 256, 128):
+        if t % bq or bq > t or (out and bq >= out[-1][0]):
+            continue
+        unit = next((u for u in (256, 128) if bq % u == 0 and bq >= 2 * u), bq)
+        out.append((bq, min(bq, 512) if bq % 512 == 0 else bq, unit))
+    return out
+
+
+def flash_schedule(t: int, block_q: int, chunk: int, unit: int = 0) -> dict[str, Any]:
+    """What a schedule does with the T x T score square of one (row, lane
+    group), counted in its units of skipping.
+
+    ``unit`` 0 — the grid-walking kernels: the unit is a ``block_q x
+    chunk`` tile, one softmax update each; tiles the diagonal crosses are
+    masked, tiles above it predicated out.
+
+    ``unit`` > 0 — the triangle inside the kernel: per q block one update
+    of all its rows per KV chunk below its first row, then one per row
+    strip of ``unit`` rows against the columns it sees in the block's own
+    square: left of its diagonal unit unmasked, that unit masked, the
+    rest never issued."""
+    nq, nkv = t // block_q, t // chunk
+    if unit == 0:
+        rows, cols = block_q, chunk
+        run = masked = 0
+        for i in range(nq):
+            for j in range(nkv):
+                if j * chunk > (i + 1) * block_q - 1:
+                    continue  # above the diagonal: not issued
+                run += 1
+                masked += (j + 1) * chunk - 1 > i * block_q
+        updates, total = run, nq * nkv
+    else:
+        rows = cols = unit
+        n = block_q // unit                       # strips per q block
+        below = sum(i * (block_q // chunk) for i in range(nq))
+        run = below * n * (chunk // unit) + nq * n * (n + 1) // 2
+        masked = nq * n
+        updates, total = below + nq * n, (t // unit) ** 2
+    return {
+        "block_q": block_q,
+        "kv_chunk": chunk,
+        "unit": [rows, cols],
+        "updates": updates,
+        "chunks_run": run,
+        "chunks_masked": masked,
+        "chunks_skipped": total - run,
+        "covered_share": run * rows * cols / float(t * t),
+    }
+
+
+def _flash_leg(name, t, g, itemsize, bq, ck, unit) -> dict[str, Any]:
+    """One pass's schedule and VMEM bytes at the given tiles. The
+    transient term is calibrated against what the v5e compiler accepts
+    (the least ``vmem_limit_bytes`` it took, searched for ten shapes, PR
+    27): it gives every update of a q block its own fp32 score tile per
+    head — the strips are unrolled and their stack slots are not shared —
+    and came out 0.4 to 1 times the model's, never above it by more than
+    the compiler allowance."""
+    kv = 2 * t * LANE * itemsize                  # K and V, one lane group
+    qblk = bq * LANE * itemsize
+    lse = bq * LANE * 4                           # (block_q, g) pads to a lane tile
+    carried = bq != t                             # several q blocks
+    n = bq // unit
+    scores = (unit * unit * n * (n + 1) // 2 + (bq * ck if carried else 0)) * 4
+    if name == "fwd":
+        blocks = kv + 2 * qblk + lse              # K, V, q, o, lse
+        scratch = 3 * g * bq * LANE * 4 if carried else 0   # m, l, p.V
+        transient = g * (scores + qblk)           # + q per head
+    else:
+        # dK/dV go out as (T, 128) blocks beside fp32 accumulators.
+        blocks = 2 * kv + 4 * qblk + lse
+        scratch = 2 * t * LANE * 4 + (bq * LANE * 4 if carried else 0)
+        # + q and do per head, do * o in fp32
+        transient = g * (scores + 2 * qblk) + bq * LANE * 4
+    total = 2 * blocks + scratch                  # blocks double-buffered
+    return {
+        **flash_schedule(t, bq, ck, unit),
+        "bytes": total,
+        "modeled_transient_bytes": transient,
+        "fits": total + transient <= FLASH_VMEM_BUDGET_BYTES,
+        "vmem_limit_bytes": total + transient + VMEM_COMPILER_ALLOWANCE_BYTES,
+    }
+
+
+def flash_plan(t: int, d: int, h: int, itemsize: int = 2) -> dict[str, Any] | None:
+    """The packed flash kernels' causal-triangle plan for ``(T, head_dim,
+    heads, dtype bytes)``: per pass the tiles — the largest q block whose
+    working set fits, K and V of a lane group held whole — the schedule's
+    counters, the VMEM bytes and the ``vmem_limit_bytes`` the kernel
+    states. None where the packed layout does not apply. ``fits`` False
+    on a pass: nothing fits, the grid-walking kernels run."""
+    g, lb = packed_group(d, h)
+    if lb != LANE or t % LANE:
+        return None
+    plan: dict[str, Any] = {
+        "kernel": "flash_packed", "t": t, "head_dim": d, "group": g,
+        "lane_groups": h // g, "budget_bytes": FLASH_VMEM_BUDGET_BYTES,
+    }
+    for name in ("fwd", "bwd"):
+        for tiles in _flash_candidates(t):
+            plan[name] = _flash_leg(name, t, g, itemsize, *tiles)
+            if plan[name]["fits"]:
+                break
+    return plan
+
+
+def flash_grid_tile_fits(block_q: int, block_kv: int, itemsize: int = 4) -> bool:
+    """The grid-walking flash kernels' working set for a user's tiling:
+    one head's fp32 score and probability tiles plus the double-buffered
+    q/k/v/o lane-group blocks and the statistics."""
+    blocks = 2 * (2 * block_q + 2 * block_kv) * LANE * itemsize
+    scratch = 3 * block_q * LANE * 4
+    return 2 * block_q * block_kv * 4 + blocks + scratch <= VMEM_BUDGET_BYTES
+
+
+# ---------------------------------------------------------------------------
 # per-layer decode kernels (ops/decode_attention.py)
 # ---------------------------------------------------------------------------
 

@@ -799,6 +799,13 @@ def _train(
             start_step=start_step,
             dataset=train_cfg.dataset,
         )
+        # The flash kernel's schedule is static: say once what it will do
+        # with the score square (tiles, chunks run / masked / skipped).
+        from dtc_tpu.ops.attention import flash_plan_event
+
+        flash_plan = flash_plan_event(model_cfg)
+        if flash_plan is not None:
+            tele.registry.emit("flash_plan", **flash_plan)
         # Auto timing semantics: when rows are being logged, sync each step
         # so elapsed_time is step time, not dispatch time (see schema.py).
         sync_every_step = train_cfg.sync_every_step

@@ -116,14 +116,31 @@ def _sds(shape, dtype, sharding):
 # training attention
 
 
-def test_flash_fwd_bwd_flagship(one_chip, flagship):
-    """Flash attention forward AND backward at the flagship train shape
-    (B8, T512, H16, D32, bf16), through the op ``attention: auto``
-    resolves to on a TPU."""
+#: Per-chip attention shapes (B, T, H, D) of the main training paths: the
+#: flagship, and the benchmark's two cells (gpt2-medium on one chip,
+#: gpt2-large under FSDP: 8 rows a chip in both).
+FLASH_SHAPES = {
+    "flagship": (8, 512, 16, 32),
+    "gpt2_medium_b8": (8, 1024, 16, 64),
+    "gpt2_large_fsdp4_b32": (8, 1024, 20, 64),
+}
+
+
+@pytest.mark.parametrize("name", list(FLASH_SHAPES))
+def test_flash_fwd_bwd(one_chip, flagship, name):
+    """Flash attention forward AND backward, bf16, through the op
+    ``attention: auto`` resolves to on a TPU, with the tiles the kernel
+    chooses from the shape: a tiling the chip's compiler refuses (scoped
+    VMEM, a block shape) fails here and not on the chip."""
+    from dtc_tpu.ops import flash_attention as fa
     from dtc_tpu.ops.attention import causal_attention, resolve_impl
 
-    b, t, h, d = 8, flagship.max_seq_len, flagship.n_heads, flagship.head_dim
+    b, t, h, d = FLASH_SHAPES[name]
+    if name == "flagship":
+        assert (t, h, d) == (flagship.max_seq_len, flagship.n_heads, flagship.head_dim)
     assert resolve_impl(flagship.attention, t, d) == "flash"
+    plan = fa.schedule(t, h, d)
+    assert plan["fwd"]["schedule"] == plan["bwd"]["schedule"] == "triangle"
     qkv = _sds((b, t, h, d), jnp.bfloat16, one_chip)
 
     def loss(q, k, v):
@@ -132,14 +149,17 @@ def test_flash_fwd_bwd_flagship(one_chip, flagship):
     _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
 
 
-@pytest.mark.parametrize("axis", ["data", "model"])
-def test_flash_on_a_four_chip_mesh(topo, flagship, axis):
+@pytest.mark.parametrize("axis,name", [
+    ("data", "flagship"), ("model", "flagship"), ("data", "gpt2_large_fsdp4_b32"),
+])
+def test_flash_on_a_four_chip_mesh(topo, axis, name):
     """XLA cannot partition a Mosaic kernel: on more than one device the
     TPU lowering refuses a bare pallas_call ("cannot be automatically
     partitioned") — which interpret mode on the CPU mesh never showed,
     and which stopped EVERY multi-chip training leg. The op must run the
     kernel per (batch, heads) shard in a fully manual region: batch over
-    data=4 (dp/fsdp) and heads over model=4 (tp)."""
+    data=4 (dp/fsdp; the four-chip cell's 32 rows) and heads over
+    model=4 (tp)."""
     from flax import linen as nn
 
     from dtc_tpu.ops.attention import causal_attention
@@ -148,7 +168,8 @@ def test_flash_on_a_four_chip_mesh(topo, flagship, axis):
 
     shape = (1, 4, 1) if axis == "data" else (1, 1, 4)
     mesh = build_mesh(shape, devices=list(topo.devices))
-    b, t, h, d = 8, flagship.max_seq_len, flagship.n_heads, flagship.head_dim
+    b, t, h, d = FLASH_SHAPES[name]
+    b = 4 * b if name != "flagship" else b  # the cell's rows over four chips
     qkv = _sds((b, t, h, d), jnp.bfloat16, NamedSharding(mesh, P("data", None, "model")))
 
     def loss(q, k, v):

@@ -39,30 +39,198 @@ SHAPES = [
 ]
 
 
-@pytest.mark.parametrize("t,d,bq,bkv", SHAPES)
-def test_forward_parity(t, d, bq, bkv):
-    q, k, v = _qkv(jax.random.PRNGKey(0), 2, t, 3, d)
+# The schedule the kernel chooses (blocks left at their defaults): the causal
+# triangle followed inside the kernel, at the benchmark cells' head layouts
+# (16 and 20 heads of 64: two a lane group) and the flagship's (16 of 32:
+# four a lane group). (T, D, H, dtype); one row so interpret mode stays fast.
+CHOSEN = [
+    (t, d, h, dtype)
+    for t in (256, 512, 1024)
+    for d, h in ((64, 16), (64, 20), (32, 16))
+    for dtype in (jnp.float32, jnp.bfloat16)
+]
+_chosen_id = lambda c: f"chosen-T{c[0]}-d{c[1]}-h{c[2]}-{c[3].__name__}"  # noqa: E731
+
+# A case is (T, D, H, blocks, dtype): explicit tilings on three heads (the
+# transpose family) or the chosen schedule.
+FWD_CASES = [
+    pytest.param((t, d, 3, dict(block_q=bq, block_kv=bkv), jnp.float32),
+                 id=f"{t}-{d}-{bq}-{bkv}")
+    for t, d, bq, bkv in SHAPES
+] + [pytest.param((t, d, h, {}, dtype), id=_chosen_id((t, d, h, dtype)))
+     for t, d, h, dtype in CHOSEN]
+
+
+def _tol(dtype, fp32):
+    # bf16 has ~3 decimal digits; compare in fp32 with a loose tolerance.
+    return fp32 if dtype == jnp.float32 else 0.05
+
+
+@pytest.mark.parametrize("case", FWD_CASES)
+def test_forward_parity(case):
+    t, d, h, blocks, dtype = case
+    q, k, v = _qkv(jax.random.PRNGKey(0), 1 if not blocks else 2, t, h, d, dtype)
     ref = dense_causal_attention(q, k, v)
-    got = flash_causal_attention(q, k, v, block_q=bq, block_kv=bkv)
+    got = flash_causal_attention(q, k, v, **blocks)
     assert got.shape == ref.shape and got.dtype == ref.dtype
-    assert jnp.max(jnp.abs(got - ref)) < 2e-5
+    err = jnp.max(jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32)))
+    assert err < _tol(dtype, 2e-5)
 
 
-@pytest.mark.parametrize("t,d,bq,bkv", SHAPES)
-def test_grad_parity(t, d, bq, bkv):
-    q, k, v = _qkv(jax.random.PRNGKey(1), 2, t, 2, d)
+@pytest.mark.parametrize("case", FWD_CASES)
+def test_grad_parity(case):
+    t, d, h, blocks, dtype = case
+    h = 2 if blocks else h
+    q, k, v = _qkv(jax.random.PRNGKey(1), 1 if not blocks else 2, t, h, d, dtype)
 
-    def loss_dense(q, k, v):
-        return jnp.sum(dense_causal_attention(q, k, v) ** 2)
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2)
 
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_causal_attention(q, k, v, block_q=bq, block_kv=bkv) ** 2)
-
-    g_ref = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    g_got = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(dense_causal_attention), argnums=(0, 1, 2))(q, k, v)
+    g_got = jax.grad(
+        loss(lambda q, k, v: flash_causal_attention(q, k, v, **blocks)),
+        argnums=(0, 1, 2),
+    )(q, k, v)
     for name, a, b in zip("qkv", g_ref, g_got):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
         err = jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(a)) + 1e-8)
-        assert err < 2e-4, f"d{name} relative error {err}"
+        assert err < _tol(dtype, 2e-4), f"d{name} relative error {err}"
+
+
+def test_chosen_plan_follows_the_causal_triangle():
+    """What the code chooses for the cells' shape (T 1024, head size 64):
+    at most 0.625 of the score square, a mask on the diagonal's units
+    only, and no unit above the diagonal — read from the same static
+    function the dispatch and the trainer's ``flash_plan`` event read."""
+    from dtc_tpu.ops import vmem
+    from dtc_tpu.ops.flash_attention import schedule
+
+    for h in (16, 20):
+        plan = schedule(1024, h, 64)
+        for leg in (plan["fwd"], plan["bwd"]):
+            assert leg["schedule"] == "triangle"
+            assert leg["covered_share"] <= 0.625
+            rows, cols = leg["unit"]
+            assert rows == cols <= 256
+            n = 1024 // rows
+            assert leg["chunks_masked"] == n                 # the diagonal's units, no other
+            assert leg["chunks_run"] == n * (n + 1) // 2     # every unit on or below it
+            assert leg["chunks_skipped"] == n * (n - 1) // 2 > 0  # none above it
+            assert leg["updates"] <= leg["chunks_run"]
+    # the parent's tiling, for scale: 2 x 2 tiles, three run, two masked
+    assert vmem.flash_schedule(1024, 512, 512)["covered_share"] == 0.75
+    # off the packed layout there is no plan: tiles are as configured
+    assert schedule(1024, 3, 64) is None
+
+
+@pytest.mark.parametrize("t,bq,ck,unit", [
+    (1024, 1024, 1024, 256), (1024, 512, 512, 256), (1024, 512, 256, 128), (512, 256, 256, 128),
+])
+def test_kernel_walks_exactly_the_planned_units(monkeypatch, t, bq, ck, unit):
+    """The kernels' own walker against the plan: every (row unit, column
+    unit) that ``_walk_triangle`` hands to a kernel, masked or not."""
+    import dtc_tpu.ops.flash_attention as fa
+    from dtc_tpu.ops import vmem
+
+    # a Python loop stands in for the kernel's fori_loop
+    monkeypatch.setattr(
+        jax.lax, "fori_loop",
+        lambda lo, hi, body, c: [body(j, c) for j in range(lo, hi)] and c,
+    )
+    seen, updates = [], 0  # (row unit, column unit, masked), global indices
+
+    def units(row0, nrows, cols, masked):
+        for r in range(row0 // unit, (row0 + nrows) // unit):
+            for c in range(int(cols.start) // unit, (int(cols.start) + cols.size) // unit):
+                seen.append((r, c, masked))
+
+    for i in range(t // bq):
+        def below(cols, i=i):
+            nonlocal updates
+            updates += 1
+            units(i * bq, bq, cols, False)
+
+        def strip(rows, segs, i=i):
+            nonlocal updates
+            updates += 1
+            for cols, mask in segs:
+                units(i * bq + rows.start, rows.stop - rows.start, cols, mask is not None)
+
+        fa._walk_triangle(None if bq == t else i, bq, ck, unit, below, strip)
+    want = vmem.flash_schedule(t, bq, ck, unit)
+    assert len(seen) == len({(r, c) for r, c, _ in seen}) == want["chunks_run"]
+    assert sum(m for *_, m in seen) == want["chunks_masked"]
+    assert updates == want["updates"]
+    for r, c, masked in seen:
+        assert c <= r              # never above the diagonal
+        assert masked == (c == r)  # a mask on the diagonal's units alone
+
+
+def test_explicit_blocks_are_still_honoured(monkeypatch):
+    """Only blocks left at the config's defaults mean "the kernel
+    chooses": a tiling a user sets runs as given, on the grid-walking
+    kernels, and the triangle kernels are not launched."""
+    import dtc_tpu.ops.flash_attention as fa
+
+    launched = []
+    real = fa.pl.pallas_call
+
+    def spy(kernel, *a, **kw):
+        launched.append((kernel.func.__name__, kw["grid"]))
+        return real(kernel, *a, **kw)
+
+    monkeypatch.setattr(fa.pl, "pallas_call", spy)
+    q, k, v = _qkv(jax.random.PRNGKey(11), 1, 512, 4, 64)
+    ref = dense_causal_attention(q, k, v)
+    got = flash_causal_attention(q, k, v, block_q=128, block_kv=256)
+    assert launched == [("_fwd_kernel_packed_multi", (1, 2, 4, 2))]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
+    assert fa.schedule(512, 4, 64, 4, 128, 256)["fwd"] == {
+        "schedule": "grid", "block_q": 128, "kv_chunk": 256, "unit": [128, 256],
+        "updates": 6, "chunks_run": 6, "chunks_masked": 4, "chunks_skipped": 2,
+        "covered_share": 0.75,
+    }
+    # a backward override alone also takes the choice away from the kernel
+    assert fa.schedule(512, 4, 64, 4, block_kv_bwd=256)["fwd"]["schedule"] == "grid"
+
+    launched.clear()
+    got = flash_causal_attention(q, k, v)  # defaults: the kernel chooses
+    assert launched == [("_fwd_kernel_tri", (1, 2, 1))]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
+
+
+def test_default_blocks_are_the_schema_defaults():
+    """"Left at the defaults" is read off the values: the op's sentinel
+    and ``ModelConfig``'s defaults must be the same numbers."""
+    from dtc_tpu.config.schema import ModelConfig
+    from dtc_tpu.ops import vmem
+
+    fields = ModelConfig.__dataclass_fields__
+    assert fields["attention_block_q"].default == vmem.FLASH_DEFAULT_BLOCK
+    assert fields["attention_block_kv"].default == vmem.FLASH_DEFAULT_BLOCK
+    assert fields["attention_block_q_bwd"].default == 0
+    assert fields["attention_block_kv_bwd"].default == 0
+
+
+def test_flash_plan_event_fields():
+    """The trainer's one start-up event: present where attention resolves
+    to flash, absent where it does not."""
+    from dtc_tpu.config.schema import ModelConfig
+    from dtc_tpu.ops.attention import flash_plan_event
+
+    base = dict(vocab_size=128, d_model=1024, n_layers=2, n_heads=16, d_ff=2048,
+                max_seq_len=1024, compute_dtype="bfloat16")
+    ev = flash_plan_event(ModelConfig(**base, attention="flash"))
+    assert (ev["seq_len"], ev["head_dim"], ev["heads"]) == (1024, 64, 16)
+    assert ev["fwd"]["covered_share"] <= 0.625 and ev["bwd"]["covered_share"] <= 0.625
+    assert flash_plan_event(ModelConfig(**base, attention="dense")) is None
+    assert flash_plan_event(ModelConfig(**base, attention="auto")) is None  # CPU
+    longctx = ModelConfig(**{**base, "max_seq_len": 4096}, attention="flash",
+                          attention_block_kv=1024, attention_block_q_bwd=512,
+                          attention_block_kv_bwd=512)
+    ev = flash_plan_event(longctx)
+    assert ev["fwd"]["schedule"] == ev["bwd"]["schedule"] == "grid"
+    assert (ev["fwd"]["kv_chunk"], ev["bwd"]["kv_chunk"]) == (1024, 512)
 
 
 def test_bf16_forward():
@@ -70,7 +238,6 @@ def test_bf16_forward():
     ref = dense_causal_attention(q, k, v)
     got = flash_causal_attention(q, k, v, block_q=128, block_kv=128)
     assert got.dtype == jnp.bfloat16
-    # bf16 has ~3 decimal digits; compare in fp32 with a loose tolerance.
     assert jnp.max(jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32))) < 0.05
 
 
@@ -302,7 +469,7 @@ def test_whole_t_tiles_past_packed_max_t_raise(monkeypatch):
     lse = jnp.zeros((1, h * d // fa._LANES, t, g), jnp.float32)
     with pytest.raises(ValueError, match="whole-T"):
         fa._packed_flash_bwd(
-            t, t, g, d, float(d ** -0.5), 0, 0,
+            t, t, g, d, float(d ** -0.5), 0, 0, None, None,
             (pk(q), pk(k), pk(v), pk(q), lse), pk(q),
         )
     # Multi-tile tilings still route to the split backward and train.
