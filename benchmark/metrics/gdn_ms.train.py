@@ -1,0 +1,11 @@
+"""Gated DeltaNet mixer, trace: self time of the device ops under the module
+``gdn`` in every pass (projections, convolution, the chunked scan, output
+norm and gate, their backward and recomputation). Counted in ``fwd_ms`` /
+``bwd_ms`` / ``recompute_ms`` too. Mean over the kept periods of the traced
+window (ms a step); ``scopes.py``."""
+
+from scopes import scope_ms
+
+
+def read(run: dict):
+    return scope_ms(run, ["gdn"])

@@ -51,6 +51,18 @@ ALLOWLIST: dict[str, frozenset[str]] = {
         "CausalSelfAttention",  # int8 KV scale cache (fp32 scales)
         "OverlapDense",      # param_dtype field default, = nn.Dense's
     }),
+    "models/pattern.py": frozenset({
+        "RMSNorm",           # zero-centred / plain RMS norm: fp32-mandated
+        "rotary",            # cos / sin tables built in fp32
+        "GatedAttention",    # the output gate's sigmoid in fp32
+        "GatedDeltaNet",     # decay, beta, L2 norms: fp32-mandated
+        "SharedExpertMoE",   # router, its softmax and the shared gate: fp32
+    }),
+    "ops/gated_delta.py": frozenset({
+        # The delta rule's decays, cumulative sums, carried state and every
+        # matmul's accumulator are fp32 by design; operands take ``dtype``.
+        "*",
+    }),
     "ops/attention.py": frozenset({
         "decode_attention",  # fp32 scores/softmax — the mandated island
     }),
@@ -82,6 +94,9 @@ ALLOWLIST: dict[str, frozenset[str]] = {
         "top_k_routing", "load_balance_loss", "dispatch_combine_tensors",
         "sort_dispatch", "sort_combine", "einsum_dispatch",
         "slot_to_token",
+        # Held experts: fp32 gates, counters, matmul accumulators, the
+        # combine's scatter-add target and the weights' gradient sums.
+        "held_experts", "_held_tiles_fwd", "_held_tiles_bwd", "_mm",
     }),
     "ops/overlap_collectives.py": frozenset({
         # fp32 MXU accumulation (preferred_element_type) in both ring

@@ -84,6 +84,12 @@ class AdapterConfig:
         return self.alpha / self.rank if self.rank > 0 else 0.0
 
 
+#: The layer kinds a ``layer_pattern`` entry may name; ``models/pattern.py``
+#: maps each to its module.
+PATTERN_MIXERS = ("gdn", "gated_attn")
+PATTERN_FFNS = ("moe_shared",)
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """GPT model hyperparameters.
@@ -189,6 +195,40 @@ class ModelConfig:
     # --- LoRA adapters (dtc_tpu/adapters/; rank 0 = off, the default —
     # the model is then bitwise the pre-adapter model). See AdapterConfig.
     adapter: AdapterConfig = field(default_factory=AdapterConfig)
+    # --- Layer pattern (models/pattern.py). Empty: the GPT-2 block of
+    # models/gpt.py in every layer. Otherwise one period of the stack, one
+    # "<mixer>+<ffn>" entry per position (mixers: gdn | gated_attn; ffns:
+    # moe_shared); n_layers is a whole number of periods and the layer scan
+    # runs over periods. The keys below are read by pattern layers only.
+    layer_pattern: tuple = ()
+    norm_eps: float = 1e-6           # zero-centred RMSNorm epsilon
+    # gated_attn: n_heads query heads of attn_head_dim (0 = d_model /
+    # n_heads) on n_kv_heads KV heads (0 = n_heads); rotary positions on
+    # the first rope_fraction of each head, half-split pairing.
+    n_kv_heads: int = 0
+    attn_head_dim: int = 0
+    rope_theta: float = 10000.0
+    rope_fraction: float = 1.0
+    # gdn (Gated DeltaNet): key heads x key dim for q and k, value heads x
+    # value dim for v and the output gate; a depthwise causal convolution
+    # of gdn_conv_width over (q, k, v); max_seq_len is a multiple of the
+    # scan's chunk (the gdn_chunk property).
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv_width: int = 4
+    # moe_shared: the router scores moe_experts and keeps moe_top_k, gates
+    # renormalised; this process holds experts [rank * held, (rank + 1) *
+    # held) (held 0 = all) of width moe_d_ff and computes only the
+    # assignments that fall on them, plus one shared SwiGLU expert of
+    # moe_shared_d_ff behind a sigmoid gate. Nothing is dropped and there
+    # is no bound: the held assignments run a tile of one expert's rows at
+    # a time, as many tiles as a step's routing fills.
+    moe_experts_held: int = 0
+    moe_expert_rank: int = 0
+    moe_d_ff: int = 0
+    moe_shared_d_ff: int = 0
 
     def __post_init__(self) -> None:
         if self.d_model % self.n_heads != 0:
@@ -271,15 +311,85 @@ class ModelConfig:
                 f"forward), got {self.attention_block_q_bwd}/"
                 f"{self.attention_block_kv_bwd}"
             )
+        if isinstance(self.layer_pattern, list):
+            object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        # YAML 1.1 reads "1e-06" (what json.dump writes) as a string.
+        for name in ("norm_eps", "rope_theta", "rope_fraction"):
+            object.__setattr__(self, name, float(getattr(self, name)))
+        if self.layer_pattern:
+            self._check_pattern()
         if self.remat_mode not in ("none", "block", "block_save_flash", "mlp"):
             raise ValueError(
                 f"unknown remat {self.remat!r}; expected bool, 'none', 'block', "
                 "'block_save_flash' or 'mlp'"
             )
 
+    def _check_pattern(self) -> None:
+        """Cross-field rules of a pattern model (``layer_pattern`` set)."""
+        kinds = [self.layer_kinds(i) for i in range(len(self.layer_pattern))]
+        for entry, (mixer, ffn) in zip(self.layer_pattern, kinds):
+            if mixer not in PATTERN_MIXERS or ffn not in PATTERN_FFNS:
+                raise ValueError(
+                    f"layer_pattern entry {entry!r}: expected '<mixer>+<ffn>' "
+                    f"with mixer in {sorted(PATTERN_MIXERS)} and ffn in {sorted(PATTERN_FFNS)}"
+                )
+        if self.n_layers % len(self.layer_pattern):
+            raise ValueError(
+                f"n_layers={self.n_layers} is not a whole number of periods "
+                f"of {len(self.layer_pattern)} layers"
+            )
+        if self.dropout or self.adapter.rank:
+            raise ValueError("pattern layers have no dropout and no adapters")
+        if self.n_heads % self.kv_heads:
+            raise ValueError(
+                f"n_heads={self.n_heads} is not a multiple of n_kv_heads={self.kv_heads}"
+            )
+        if any(m == "gdn" for m, _ in kinds):
+            if min(self.gdn_key_heads, self.gdn_value_heads,
+                   self.gdn_key_dim, self.gdn_value_dim) <= 0:
+                raise ValueError("gdn layers need gdn_key_heads, gdn_value_heads, "
+                                 "gdn_key_dim and gdn_value_dim")
+            if self.gdn_value_heads % self.gdn_key_heads:
+                raise ValueError("gdn_value_heads must be a multiple of gdn_key_heads")
+            if self.max_seq_len % self.gdn_chunk:
+                raise ValueError(
+                    f"max_seq_len={self.max_seq_len} is not a multiple of "
+                    f"the scan's chunk of {self.gdn_chunk}"
+                )
+        if any(f == "moe_shared" for _, f in kinds):
+            held = self.experts_held
+            if self.moe_experts <= 0 or self.moe_d_ff <= 0 or self.moe_shared_d_ff <= 0:
+                raise ValueError("moe_shared layers need moe_experts, moe_d_ff "
+                                 "and moe_shared_d_ff")
+            if self.moe_experts % held or not 0 <= self.moe_expert_rank < self.moe_experts // held:
+                raise ValueError(
+                    f"moe_experts_held={held} must divide moe_experts="
+                    f"{self.moe_experts}, and moe_expert_rank="
+                    f"{self.moe_expert_rank} name one of the shares"
+                )
+
+    def layer_kinds(self, position: int) -> tuple[str, str]:
+        """(mixer kind, ffn kind) of one position of the period."""
+        mixer, _, ffn = str(self.layer_pattern[position]).partition("+")
+        return mixer, ffn
+
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_experts_held or self.moe_experts
+
+    @property
+    def gdn_chunk(self) -> int:
+        """Positions the Gated DeltaNet scan takes at a time: 64, the public
+        kernels' chunk (``ops/gated_delta.py``), or a shorter sequence whole."""
+        return min(64, self.max_seq_len)
 
     @property
     def kv_store_dtype(self) -> str:

@@ -249,6 +249,10 @@ def generate(
     single jit, so finer host-side splits would be fiction; per-token
     attribution lives in the serving engine's iteration spans and
     ``scripts/profile_step.py --decode``."""
+    if getattr(model.cfg, "layer_pattern", ()):
+        from dtc_tpu.models.pattern import NOT_SERVED
+
+        raise NotImplementedError(NOT_SERVED)
     if tracer is not None and tracer.enabled:
         with tracer.span(
             "generate", cat="generate", batch=int(prompt.shape[0]),

@@ -760,6 +760,10 @@ def param_count(cfg: ModelConfig) -> int:
     """Exact BASE parameter count from config (no tracing needed).
     LoRA adapter params are deliberately excluded — they are per-tenant
     and counted by :func:`adapter_param_count`."""
+    if cfg.layer_pattern:
+        from dtc_tpu.models.pattern import pattern_param_count
+
+        return pattern_param_count(cfg)
     d, v, L, f, s = cfg.d_model, cfg.padded_vocab_size, cfg.n_layers, cfg.d_ff, cfg.max_seq_len
     embed = v * d + s * d
     if cfg.moe_experts > 0:

@@ -1,0 +1,474 @@
+"""Models described by a layer pattern.
+
+``ModelConfig.layer_pattern`` states ONE period of the stack: per position
+a mixer kind and an FFN kind (``"gdn+moe_shared"``). The schema lists the
+kinds' names and this file maps them to modules (:data:`MIXERS`,
+:data:`FFNS`); :class:`PatternLM` scans over periods, each layer under its
+own ``nn.remat`` as ``GPTStage`` does, and :func:`build_model` gives
+``trainer.train`` this model or ``GPT`` from the configuration alone. An
+empty pattern is the GPT-2 block of ``models/gpt.py``, untouched.
+
+Every pattern layer is pre-norm and residual::
+
+    x += mixer(rms(x; w1));  x += ffn(rms(x; w2))
+    rms(x; w) = x * rsqrt(mean(x^2) + eps) * (1 + w)       (zero-centred gain)
+
+with a token embedding only (positions are the mixers' business), a final
+``rms`` and an untied head without bias through the fused head+CE op that
+``GPTHead`` uses. No biases, no dropout.
+
+Mixers:
+
+- ``gated_attn`` — softmax attention whose query projection also yields a
+  per-head output gate; ``n_heads`` query heads on ``n_kv_heads`` KV heads
+  of ``head_dim``; q and k RMS-normed over the head; rotary positions on the
+  first ``rope_fraction`` of the head, half-split pairing. Through
+  ``ops/attention.causal_attention`` (flash on the chip, KV groups picked by
+  the kernels' index maps).
+- ``gdn`` — Gated DeltaNet (``ops/gated_delta.py``): one fused projection
+  to (q, k, v, z), a second to (b, a); a depthwise causal convolution and
+  SiLU over (q, k, v); ``beta = sigmoid(b)``, ``g = -exp(A_log) *
+  softplus(a + dt_bias)``; q, k L2-normalised; the chunked delta-rule scan;
+  a gain-only RMS norm of the output, gated by ``silu(z)``.
+
+FFN:
+
+- ``moe_shared`` — the router scores ``moe_experts``, keeps ``moe_top_k``
+  (gates renormalised), and this process computes the part of the sum that
+  its held experts give (``ops/moe_dispatch.held_experts``: nothing
+  dropped) plus a shared SwiGLU expert behind a sigmoid gate.
+
+The float32 islands are the norms, the router and its softmax, the decay
+and the scan's carried state; the matmuls run in ``compute_dtype``.
+
+Scopes on the device path (``benchmark/spans.py`` reads the op-name path):
+``gdn`` with ``proj`` / ``conv`` / ``scan`` / ``out``; ``attn_full`` with
+``attn_kernel`` around the kernel call; ``moe`` with ``router`` /
+``dispatch`` / ``experts`` / ``combine`` / ``shared``; ``head``.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from flax import linen as nn
+
+from dtc_tpu.config.schema import ModelConfig
+from dtc_tpu.models.gpt import _dtype
+from dtc_tpu.ops import moe_dispatch as md
+from dtc_tpu.ops.attention import causal_attention
+from dtc_tpu.ops.gated_delta import gated_delta_chunked
+
+#: The per-step counters a pattern model sows (collection ``counters``),
+#: one row a layer; the train step returns them beside the loss.
+COUNTERS = md.HELD_COUNTERS
+
+NOT_SERVED = (
+    "a layer-pattern model trains only: there is no cache for recurrent "
+    "state yet, so generate / ServingEngine cannot run it"
+)
+
+
+def _dense(features: int, name: str, cfg: ModelConfig) -> nn.Dense:
+    return nn.Dense(features, name=name, use_bias=False,
+                    dtype=_dtype(cfg.compute_dtype), param_dtype=_dtype(cfg.param_dtype))
+
+
+class RMSNorm(nn.Module):
+    """Float32 RMS norm over the last axis. ``zero_centred``: the gain is
+    ``1 + w`` with ``w`` starting at 0; else a plain gain starting at 1."""
+
+    eps: float
+    zero_centred: bool = True
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        init = nn.initializers.zeros_init() if self.zero_centred else nn.initializers.ones_init()
+        w = self.param("scale", init, (x.shape[-1],), jnp.float32)
+        x = x.astype(jnp.float32)
+        x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps)
+        return x * (1.0 + w if self.zero_centred else w)
+
+
+def rotary(x: jax.Array, theta: float, fraction: float) -> jax.Array:
+    """Rotary positions on the first ``fraction`` of the last axis of
+    ``x`` (B, T, H, D), half-split pairing; the rest passes."""
+    t, d = x.shape[1], x.shape[-1]
+    rot = int(d * fraction)
+    if rot == 0:
+        return x
+    inv = 1.0 / (theta ** (np.arange(0, rot, 2, dtype=np.float64) / rot))
+    ang = np.arange(t, dtype=np.float64)[:, None] * inv[None, :]          # (T, rot/2)
+    cos = jnp.asarray(np.concatenate([np.cos(ang), np.cos(ang)], -1), jnp.float32)[None, :, None]
+    sin = jnp.asarray(np.concatenate([np.sin(ang), np.sin(ang)], -1), jnp.float32)[None, :, None]
+    xr, rest = x[..., :rot], x[..., rot:]
+    x1, x2 = xr[..., : rot // 2], xr[..., rot // 2:]
+    xr = xr * cos + jnp.concatenate([-x2, x1], -1) * sin
+    return jnp.concatenate([xr, rest], -1)
+
+
+class GatedAttention(nn.Module):
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        b, t, _ = x.shape
+        h, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        cdtype = _dtype(cfg.compute_dtype)
+        with jax.named_scope("attn_qkv"):
+            qg = _dense(h * 2 * hd, "q_proj", cfg)(x).reshape(b, t, h, 2 * hd)
+            q, gate = qg[..., :hd], qg[..., hd:]
+            k = _dense(hk * hd, "k_proj", cfg)(x).reshape(b, t, hk, hd)
+            v = _dense(hk * hd, "v_proj", cfg)(x).reshape(b, t, hk, hd)
+            q = rotary(RMSNorm(cfg.norm_eps, name="q_norm")(q), cfg.rope_theta, cfg.rope_fraction)
+            k = rotary(RMSNorm(cfg.norm_eps, name="k_norm")(k), cfg.rope_theta, cfg.rope_fraction)
+            q, k = q.astype(cdtype), k.astype(cdtype)
+        with jax.named_scope("attn_kernel"):
+            out = causal_attention(
+                q, k, v, impl=cfg.attention,
+                block_q=cfg.attention_block_q, block_kv=cfg.attention_block_kv,
+            )
+        with jax.named_scope("attn_proj"):
+            out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(cdtype)
+            return _dense(cfg.d_model, "out_proj", cfg)(out.reshape(b, t, h * hd))
+
+
+def causal_depthwise_conv(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``y_t = sum_j w[:, j] * x_{t - (W-1) + j}`` over (B, T, C) with
+    ``w`` (C, W): W shifted multiply-adds, zeros before the sequence."""
+    width = w.shape[1]
+    t = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    return sum(padded[:, j: j + t] * w[:, j] for j in range(width))
+
+
+class GatedDeltaNet(nn.Module):
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        b, t, _ = x.shape
+        hk, hv, dk, dv = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
+        cdtype, f32 = _dtype(cfg.compute_dtype), jnp.float32
+        nk, nv = hk * dk, hv * dv
+        with jax.named_scope("proj"):
+            qkvz = _dense(2 * nk + 2 * nv, "in_proj_qkvz", cfg)(x)
+            ba = _dense(2 * hv, "in_proj_ba", cfg)(x).astype(f32)
+        with jax.named_scope("conv"):
+            w = self.param("conv", nn.initializers.lecun_normal(), (2 * nk + nv, cfg.gdn_conv_width),
+                           _dtype(cfg.param_dtype))
+            # float32 inside the fusion, compute dtype in HBM
+            qkv = jax.nn.silu(causal_depthwise_conv(
+                qkvz[..., : 2 * nk + nv].astype(f32), w.astype(f32))).astype(cdtype)
+            z = qkvz[..., 2 * nk + nv:].reshape(b, t, hv, dv)
+        with jax.named_scope("scan"):
+            a_log = self.param(
+                "A_log", lambda key, shape: jnp.log(jax.random.uniform(key, shape, f32, 1.0, 16.0)),
+                (hv,))
+            dt_bias = self.param("dt_bias", nn.initializers.ones_init(), (hv,), f32)
+            beta = jax.nn.sigmoid(ba[..., :hv])
+            g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
+
+            def unit(v, scale=1.0):  # L2 over the head, in float32
+                v = v.astype(f32)
+                v = v * (scale * jax.lax.rsqrt(jnp.sum(jnp.square(v), axis=-1, keepdims=True) + 1e-6))
+                return v.astype(cdtype)
+
+            q = unit(qkv[..., :nk].reshape(b, t, hk, dk), dk ** -0.5)
+            k = unit(qkv[..., nk: 2 * nk].reshape(b, t, hk, dk))
+            # each key head serves hv / hk value heads
+            q, k = (jnp.repeat(v, hv // hk, axis=2) for v in (q, k))
+            v = qkv[..., 2 * nk:].reshape(b, t, hv, dv)
+            o = gated_delta_chunked(q, k, v, g, beta, chunk=cfg.gdn_chunk, dtype=cdtype)
+        with jax.named_scope("out"):
+            o = RMSNorm(cfg.norm_eps, zero_centred=False, name="norm")(o)
+            o = (o * jax.nn.silu(z.astype(f32))).astype(cdtype)
+            return _dense(cfg.d_model, "out_proj", cfg)(o.reshape(b, t, nv))
+
+
+class SwiGLU(nn.Module):
+    cfg: ModelConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        h = jax.nn.silu(_dense(self.width, "gate_proj", cfg)(x)) * _dense(self.width, "up_proj", cfg)(x)
+        return _dense(cfg.d_model, "down_proj", cfg)(h)
+
+
+def _data_axis(batch: int):
+    """(mesh, axis, free axes) where the batch axis of the activations is
+    laid over more than one device under the active rules and mesh and
+    divides ``batch``; else None (one device, the batch-1 ``model.init``
+    trace, an already manual region)."""
+    from jax._src.core import trace_state_clean
+
+    from dtc_tpu.parallel.sharding import ambient_mesh
+
+    mesh = None if trace_state_clean() else ambient_mesh(allow_empty=True)
+    if mesh is None or mesh.size == 1:
+        return None
+    free = set(mesh.axis_names) - set(mesh.manual_axes)
+    axis = dict(nn.get_logical_axis_rules()).get("batch")
+    if axis not in free or batch % dict(mesh.shape)[axis]:
+        return None
+    return mesh, axis, free
+
+
+def _per_data_shard(fn, where, x, *rest):
+    """``fn`` on each device's own tokens. The loop over tiles has a
+    device's own trip count, so on a mesh (``where``: :func:`_data_axis`)
+    the sort, the experts' matmuls and the scatter run inside a region that
+    is manual over every free mesh axis (as the flash kernel does,
+    ``ops/attention._flash_per_shard``): tokens stay where their batch rows
+    are, the weights arrive whole (under FSDP: gathered at the region's
+    edge), and the counters are summed / maxed over the devices."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    if where is None:
+        return fn(x, *rest)
+    mesh, axis, free = where
+
+    def local(x, *rest):
+        y, c = fn(x, *rest)
+        total, peak = jax.lax.psum(c, axis), jax.lax.pmax(c, axis)
+        # assigned and dropped add up; the fullest expert anywhere; the
+        # mean load of a device's held experts, averaged
+        return y, jnp.stack([total[0], peak[1], total[2] / jax.lax.psum(1, axis), total[3]])
+
+    tok = P(axis)
+    return shard_map(
+        local, mesh=mesh, in_specs=(tok, tok, tok, *(P(),) * (len(rest) - 2)),
+        out_specs=(tok, P()), axis_names=free, check_vma=False,
+    )(x, *rest)
+
+
+class SharedExpertMoE(nn.Module):
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        b, t, d = x.shape
+        e, k, held, f = cfg.moe_experts, cfg.moe_top_k, cfg.experts_held, cfg.moe_d_ff
+        cdtype, pdtype = _dtype(cfg.compute_dtype), _dtype(cfg.param_dtype)
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
+        w_gate = self.param("w_gate", init, (held, d, f), pdtype)
+        w_up = self.param("w_up", init, (held, d, f), pdtype)
+        w_down = self.param("w_down", init, (held, f, d), pdtype)
+        with jax.named_scope("router"):
+            logits = nn.Dense(e, name="router", use_bias=False, dtype=jnp.float32,
+                              param_dtype=jnp.float32)(x.astype(jnp.float32))
+            gates, idx = md.top_k_gates(jax.nn.softmax(logits.reshape(b * t, e), axis=-1), k)
+        y, counters = _per_data_shard(
+            functools.partial(md.held_experts, first=cfg.moe_expert_rank * held),
+            _data_axis(b), x.reshape(b * t, d), gates, idx,
+            w_gate.astype(cdtype), w_up.astype(cdtype), w_down.astype(cdtype),
+        )
+        self.sow("counters", "moe", counters)
+        with jax.named_scope("shared"):
+            gate = nn.Dense(1, name="shared_gate", use_bias=False, dtype=jnp.float32,
+                            param_dtype=pdtype)(x.astype(jnp.float32))
+            shared = SwiGLU(cfg, cfg.moe_shared_d_ff, name="shared")(x)
+            y = y.reshape(b, t, d) + jax.nn.sigmoid(gate) * shared.astype(jnp.float32)
+        return y.astype(cdtype)
+
+
+#: mixer kind -> (module, its scope on the device path)
+MIXERS = {"gdn": (GatedDeltaNet, "gdn"), "gated_attn": (GatedAttention, "attn_full")}
+#: ffn kind -> (module, scope)
+FFNS = {"moe_shared": (SharedExpertMoE, "moe")}
+
+
+class PatternBlock(nn.Module):
+    cfg: ModelConfig
+    position: int
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        cdtype = _dtype(cfg.compute_dtype)
+        mixer, ffn = cfg.layer_kinds(self.position)
+        (mixer_cls, mixer_name), (ffn_cls, ffn_name) = MIXERS[mixer], FFNS[ffn]
+        h = RMSNorm(cfg.norm_eps, name="norm_1")(x).astype(cdtype)
+        x = x + mixer_cls(cfg, name=mixer_name)(h)
+        h = RMSNorm(cfg.norm_eps, name="norm_2")(x).astype(cdtype)
+        x = x + ffn_cls(cfg, name=ffn_name)(h)
+        return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+
+
+class _Period(nn.Module):
+    """One period of the pattern under ``nn.scan``: its layers in order,
+    each under its own remat."""
+
+    cfg: ModelConfig
+    train: bool
+
+    @nn.compact
+    def __call__(self, h, _):
+        cls = PatternBlock
+        mode = self.cfg.remat_mode
+        if mode != "none" and self.train:
+            # prevent_cse stays on: a period's layers are unrolled in ONE
+            # scan iteration, where XLA would merge a layer's recomputation
+            # with its forward and keep every activation after all
+            # (GPTStage's one-block scan body cannot be merged that way).
+            kwargs: dict = {}
+            if mode == "block_save_flash":
+                kwargs["policy"] = jax.checkpoint_policies.save_only_these_names(
+                    "flash_out", "flash_lse", "flash_q", "flash_k", "flash_v")
+            cls = nn.remat(cls, **kwargs)
+        for position in range(len(self.cfg.layer_pattern)):
+            h = cls(self.cfg, position, name=f"layer_{position}")(h)
+        return h, None
+
+
+class PatternEmbed(nn.Module):
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        wte = nn.Embed(cfg.padded_vocab_size, cfg.d_model, name="wte",
+                       param_dtype=_dtype(cfg.param_dtype))
+        return nn.with_logical_constraint(
+            wte(x).astype(_dtype(cfg.compute_dtype)), ("batch", "seq", "embed"))
+
+
+class PatternStage(nn.Module):
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, h: jax.Array, *, train: bool) -> jax.Array:
+        cfg = self.cfg
+        scanned = nn.scan(
+            _Period,
+            variable_axes={"params": 0, "counters": 0},
+            split_rngs={"params": True},
+            length=cfg.n_layers // len(cfg.layer_pattern),
+            metadata_params={nn.PARTITION_NAME: "layers"},
+        )(cfg, train, name="periods")
+        h, _ = scanned(h, None)
+        return h
+
+
+class PatternHead(nn.Module):
+    """Final RMS norm and the untied head without bias, through the fused
+    head + cross-entropy op (``ops/fused_ce.py``) when ``targets`` are
+    given. The op folds a bias gradient into its dW matmul; the zero bias
+    passed here is a constant, its gradient discarded."""
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, h: jax.Array, targets: jax.Array | None = None) -> jax.Array:
+        from dtc_tpu.ops.fused_ce import fused_head_ce, head_logits
+
+        cfg = self.cfg
+        pdtype = _dtype(cfg.param_dtype)
+        h = RMSNorm(cfg.norm_eps, name="norm_f")(h).astype(_dtype(cfg.compute_dtype))
+        kernel = self.param("lm_head", nn.initializers.lecun_normal(),
+                            (cfg.d_model, cfg.padded_vocab_size), pdtype)
+        bias = jnp.zeros((cfg.padded_vocab_size,), pdtype)
+        if targets is not None:
+            return fused_head_ce(h, kernel, bias, targets, cfg.vocab_size)
+        return head_logits(h, kernel, bias, cfg.vocab_size)
+
+
+class PatternLM(nn.Module):
+    """Decoder-only model of a layer pattern. Param tree ``{"embed",
+    "stage": {"periods": {"layer_<i>": ...}}, "head"}``, every layer leaf
+    stacked over periods. Training and evaluation only."""
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, *, train: bool = True, decode: bool = False,
+                 targets: jax.Array | None = None) -> jax.Array:
+        if decode:
+            raise NotImplementedError(NOT_SERVED)
+        h = PatternEmbed(self.cfg, name="embed")(x)
+        h = PatternStage(self.cfg, name="stage")(h, train=train)
+        return PatternHead(self.cfg, name="head")(h, targets=targets)
+
+
+def build_model(cfg: ModelConfig) -> nn.Module:
+    """The model a configuration describes: :class:`PatternLM` where it
+    states a layer pattern, else ``GPT``."""
+    if cfg.layer_pattern:
+        return PatternLM(cfg)
+    from dtc_tpu.models.gpt import GPT
+
+    return GPT(cfg)
+
+
+def pattern_param_count(cfg: ModelConfig) -> int:
+    """Exact parameter count of :class:`PatternLM` from the configuration
+    (``gpt.param_count`` hands pattern models here)."""
+    d = cfg.d_model
+    nk, nv = cfg.gdn_key_heads * cfg.gdn_key_dim, cfg.gdn_value_heads * cfg.gdn_value_dim
+    hd = cfg.head_dim
+    per = {
+        "gdn": d * (2 * nk + 2 * nv) + d * 2 * cfg.gdn_value_heads
+        + (2 * nk + nv) * cfg.gdn_conv_width + 2 * cfg.gdn_value_heads + cfg.gdn_value_dim + nv * d,
+        "gated_attn": d * 2 * cfg.n_heads * hd + 2 * d * cfg.kv_heads * hd + 2 * hd
+        + cfg.n_heads * hd * d,
+        "moe_shared": d * cfg.moe_experts + cfg.experts_held * 3 * d * cfg.moe_d_ff
+        + 3 * d * cfg.moe_shared_d_ff + d,
+    }
+    kinds = [cfg.layer_kinds(i) for i in range(len(cfg.layer_pattern))]
+    period = sum(per[m] + per[f] + 2 * d for m, f in kinds)
+    periods = cfg.n_layers // len(cfg.layer_pattern)
+    return periods * period + 2 * cfg.padded_vocab_size * d + d
+
+
+def layer_plan(cfg: ModelConfig) -> dict:
+    """Fields of the trainer's one ``layer_plan`` start-up event: the
+    pattern, and per mixer kind the kernel and tiles it will run."""
+    from dtc_tpu.ops.attention import resolve_impl
+
+    kinds = [cfg.layer_kinds(i) for i in range(len(cfg.layer_pattern))]
+    plan: dict = {
+        "pattern": list(cfg.layer_pattern),
+        "periods": cfg.n_layers // len(cfg.layer_pattern),
+        "remat": cfg.remat_mode,
+    }
+    if any(m == "gated_attn" for m, _ in kinds):
+        plan["gated_attn"] = {
+            "kernel": resolve_impl(cfg.attention, cfg.max_seq_len, cfg.head_dim,
+                                   cfg.attention_block_q, cfg.attention_block_kv),
+            "heads": cfg.n_heads, "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim,
+            "block_q": min(cfg.attention_block_q, cfg.max_seq_len),
+            "block_kv": min(cfg.attention_block_kv, cfg.max_seq_len),
+            "rotary_dims": int(cfg.head_dim * cfg.rope_fraction),
+        }
+    if any(m == "gdn" for m, _ in kinds):
+        plan["gdn"] = {
+            "kernel": "chunked_xla", "chunk": cfg.gdn_chunk,
+            "chunks": cfg.max_seq_len // cfg.gdn_chunk,
+            "key_heads": cfg.gdn_key_heads, "value_heads": cfg.gdn_value_heads,
+            "key_dim": cfg.gdn_key_dim, "value_dim": cfg.gdn_value_dim,
+        }
+    return plan
+
+
+def moe_plan(cfg: ModelConfig, tokens_per_device: int) -> dict | None:
+    """Fields of the ``moe_plan`` start-up event, or None without an
+    expert layer."""
+    if not any(cfg.layer_kinds(i)[1] == "moe_shared" for i in range(len(cfg.layer_pattern))):
+        return None
+    held, k = cfg.experts_held, cfg.moe_top_k
+    return {
+        "experts_published": cfg.moe_experts, "experts_held": held,
+        "rank": cfg.moe_expert_rank, "first_expert": cfg.moe_expert_rank * held,
+        "top_k": k, "expert_width": cfg.moe_d_ff, "shared_width": cfg.moe_shared_d_ff,
+        "tokens_per_device": tokens_per_device, "tile_rows": md.HELD_TILE_ROWS,
+        "expected_held": tokens_per_device * k * held / cfg.moe_experts,
+    }

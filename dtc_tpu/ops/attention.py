@@ -63,6 +63,10 @@ def decode_attention(
     own position)."""
     b, t, h, d = q.shape
     s = k.shape[1]
+    if k.shape[2] != h:
+        # Grouped KV heads: q head i reads KV head i // (h / h_kv). The
+        # plain path repeats them (the flash kernels index instead).
+        k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
     scale = d ** -0.5
     scores = jnp.einsum(
         "bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32

@@ -144,11 +144,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 def _fwd_call(q, k, v, block_q, block_kv):
     b, h, t, d = q.shape
+    # Grouped KV heads: q head hi reads KV head hi // grp, chosen by the
+    # block index map; K and V are never expanded in HBM.
+    grp = h // k.shape[1]
     nq, nkv = t // block_q, t // block_kv
     if nkv == 1:
         # Whole KV fits one tile: one-pass kernel, no online-softmax scratch.
         qspec3 = pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, i: (bi, hi, i, 0))
-        kvspec3 = pl.BlockSpec((1, 1, block_kv, d), lambda bi, hi, i: (bi, hi, 0, 0))
+        kvspec3 = pl.BlockSpec((1, 1, block_kv, d), lambda bi, hi, i: (bi, hi // grp, 0, 0))
         return pl.pallas_call(
             functools.partial(_fwd_kernel_single, block_q=block_q, block_kv=block_kv),
             grid=(b, h, nq),
@@ -168,7 +171,7 @@ def _fwd_call(q, k, v, block_q, block_kv):
         )(q, k, v)
     grid = (b, h, nq, nkv)
     qspec = pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, i, j: (bi, hi, i, 0))
-    kvspec = pl.BlockSpec((1, 1, block_kv, d), lambda bi, hi, i, j: (bi, hi, j, 0))
+    kvspec = pl.BlockSpec((1, 1, block_kv, d), lambda bi, hi, i, j: (bi, hi // grp, j, 0))
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_q=block_q, block_kv=block_kv),
         grid=grid,
@@ -278,10 +281,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, block_q, block_kv):
-    j, i = pl.program_id(2), pl.program_id(3)  # kv block j outer, q block i inner
+                dk_scr, dv_scr, *, block_q, block_kv, nq):
+    # kv block j outer; inner: the q heads of this KV head's group, each
+    # with its nq q blocks (one head: the inner index is the q block).
+    j, inner = pl.program_id(2), pl.program_id(3)
+    i = inner % nq
 
-    @pl.when(i == 0)
+    @pl.when(inner == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -303,7 +309,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(i == pl.num_programs(3) - 1)
+    @pl.when(inner == pl.num_programs(3) - 1)
     def _():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
@@ -311,17 +317,20 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
 
 def _bwd_call(q, k, v, out, lse, do, block_q, block_kv):
     b, h, t, d = q.shape
+    hk = k.shape[1]
+    grp = h // hk  # q heads on one KV head (see _fwd_call)
     nq, nkv = t // block_q, t // block_kv
     # delta_i = rowsum(dO ⊙ O): tiny elementwise reduce, leave it to XLA.
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[..., None]
 
     if nq == 1 and nkv == 1:
         spec = pl.BlockSpec((1, 1, t, d), lambda bi, hi: (bi, hi, 0, 0))
+        kspec = pl.BlockSpec((1, 1, t, d), lambda bi, hi: (bi, hi // grp, 0, 0))
         sspec = pl.BlockSpec((1, 1, t, 1), lambda bi, hi: (bi, hi, 0, 0))
-        return pl.pallas_call(
+        dq, dk, dv = pl.pallas_call(
             functools.partial(_bwd_kernel_single, block_q=t, block_kv=t),
             grid=(b, h),
-            in_specs=[spec, spec, spec, spec, sspec, sspec],
+            in_specs=[spec, kspec, kspec, spec, sspec, sspec],
             out_specs=[spec, spec, spec],
             out_shape=[
                 jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
@@ -333,9 +342,15 @@ def _bwd_call(q, k, v, out, lse, do, block_q, block_kv):
             ),
             interpret=_interpret(),
         )(q, k, v, do, lse, delta)
+        if grp > 1:
+            # One tile a head: each q head wrote its own dk / dv; the
+            # group's sum is a small XLA reduce.
+            dk, dv = (x.reshape(b, hk, grp, t, d).astype(jnp.float32).sum(2).astype(x.dtype)
+                      for x in (dk, dv))
+        return dq, dk, dv
 
     qspec = pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, i, j: (bi, hi, i, 0))
-    kvspec_q_outer = pl.BlockSpec((1, 1, block_kv, d), lambda bi, hi, i, j: (bi, hi, j, 0))
+    kvspec_q_outer = pl.BlockSpec((1, 1, block_kv, d), lambda bi, hi, i, j: (bi, hi // grp, j, 0))
     statspec = pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, i, j: (bi, hi, i, 0))
 
     dq = pl.pallas_call(
@@ -351,19 +366,23 @@ def _bwd_call(q, k, v, out, lse, do, block_q, block_kv):
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
-    # dk/dv grid: (b, h, nkv, nq) — q innermost so per-KV-block accumulators
-    # persist in scratch.
-    qspec_kv_outer = pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, j, i: (bi, hi, i, 0))
+    # dk/dv grid: (b, KV heads, nkv, grp * nq) — the group's q heads and
+    # their q blocks innermost, so one KV block's accumulators persist in
+    # scratch over every q head it serves: the group's dk / dv are summed
+    # in VMEM, not by a reduction over expanded heads.
+    qspec_kv_outer = pl.BlockSpec(
+        (1, 1, block_q, d), lambda bi, hi, j, i: (bi, hi * grp + i // nq, i % nq, 0))
     kvspec = pl.BlockSpec((1, 1, block_kv, d), lambda bi, hi, j, i: (bi, hi, j, 0))
-    statspec_kv = pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, j, i: (bi, hi, i, 0))
+    statspec_kv = pl.BlockSpec(
+        (1, 1, block_q, 1), lambda bi, hi, j, i: (bi, hi * grp + i // nq, i % nq, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=block_q, block_kv=block_kv),
-        grid=(b, h, nkv, nq),
+        functools.partial(_dkv_kernel, block_q=block_q, block_kv=block_kv, nq=nq),
+        grid=(b, hk, nkv, grp * nq),
         in_specs=[qspec_kv_outer, kvspec, kvspec, qspec_kv_outer, statspec_kv, statspec_kv],
         out_specs=[kvspec, kvspec],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, t, d), v.dtype),
+            jax.ShapeDtypeStruct((b, hk, t, d), k.dtype),
+            jax.ShapeDtypeStruct((b, hk, t, d), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_kv, d), jnp.float32),
@@ -1322,7 +1341,12 @@ def flash_causal_attention(
                     f"512/1024 defaults)"
                 )
 
-    g = _packed_group(d, h)
+    # Fewer KV heads than q heads (k, v are (B, T, H_kv, D), H a multiple
+    # of H_kv): the transposed-layout kernels pick each q head's KV head in
+    # their block index maps; the packed kernels know no groups.
+    if h % k.shape[2] or k.shape != v.shape:
+        raise ValueError(f"q heads {h} are not a multiple of KV heads {k.shape[2]}")
+    g = _packed_group(d, h) if k.shape[2] == h else None
     if (block_q_bwd or block_kv_bwd) and g is None:
         # The transpose-layout fallback has no independent backward tiling;
         # silently running the forward tiling there would make sweep-tuned

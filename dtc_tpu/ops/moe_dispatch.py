@@ -37,10 +37,12 @@ reference in ``tests/test_moe.py`` and A/B-benched in ``bench.py`` /
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 MOE_DISPATCH_MODES = ("einsum", "sort")
 
@@ -233,3 +235,253 @@ def sort_combine(y_e: jax.Array, r: Routing, cap: int) -> jax.Array:
     y_a = jnp.take_along_axis(flat, safe[..., None], axis=1).reshape(b, t, k, d)
     w = (r.gates * r.keep).astype(y_e.dtype)                 # (B, T, k)
     return jnp.sum(y_a * w[..., None], axis=2)
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing over a held share of the experts (models/pattern.py,
+# ffn kind ``moe_shared``). No capacity and no bound: every assignment that
+# falls on an expert held here is computed. The router scores every
+# published expert; this process holds ``held`` of them from ``first`` on
+# and computes their part of the layer's sum. The held assignments are
+# sorted by expert and run a tile of :data:`HELD_TILE_ROWS` rows of ONE
+# expert at a time, in a loop whose trip count follows the tiles the step's
+# routing fills: gather, the expert's three matmuls and the scatter-add
+# (a group of tiles at a time) all grow with the assignments there are —
+# not with the published count, not with the room, and a layer the routers
+# have left runs no tile.
+# ---------------------------------------------------------------------------
+
+#: What :func:`held_experts` counts, in order (the trainer's per-step
+#: counters; README "Observability").
+HELD_COUNTERS = ("moe_assigned_held", "moe_load_max", "moe_load_mean", "moe_dropped")
+
+#: Rows of one expert that go through its matmuls at a time. An expert's
+#: last tile is part filled, so a step computes at most ``held`` tiles'
+#: worth of rows more than it has assignments. A tile reads its expert's
+#: weights and adds to their gradients whatever rows it holds, so few large
+#: tiles beat many small ones (v5e, d 2048, width 512: 512 rows was the
+#: fastest of 128 / 256 / 512, PERF.md section 6).
+HELD_TILE_ROWS = 512
+
+#: Tiles whose rows are added into the tokens' rows by ONE scatter. XLA's
+#: TPU scatter copies its whole (tokens, d) operand on every call, however
+#: few rows it adds (0.4-0.8 ms at 16384 x 2048 float32), so the loop
+#: stages its tiles' outputs and scatters them a group at a time: with a
+#: scatter a tile, three quarters of the layer's time were those copies.
+HELD_GROUP_TILES = 16
+
+
+def top_k_gates(probs: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """The ``k`` largest of ``probs`` (N, E) per row, renormalised to sum 1:
+    (gates (N, k) float32, expert ids (N, k) int32)."""
+    top, idx = jax.lax.top_k(probs, k)
+    return top / jnp.sum(top, axis=-1, keepdims=True), idx.astype(jnp.int32)
+
+
+class _Tiles(NamedTuple):
+    """The step's tiles, in expert order: per tile its expert, and the
+    span of the expert-sorted assignment list it covers (``stop`` is where
+    the expert's assignments end, so the last tile of an expert is cut
+    there); ``count`` tiles hold an assignment."""
+
+    expert: jax.Array  # (most,) int32
+    start: jax.Array   # (most,) int32
+    stop: jax.Array    # (most,) int32
+    count: jax.Array   # () int32
+
+
+def _plan_tiles(ends: jax.Array, slots: int, tile: int) -> _Tiles:
+    """Tiles from the experts' cumulative loads ``ends`` (held,). ``most``
+    is static: every expert's part-filled tile beside the full ones that
+    ``slots`` assignments could make."""
+    held = ends.shape[0]
+    loads = jnp.diff(ends, prepend=0)
+    last = jnp.cumsum((loads + tile - 1) // tile)           # tiles up to and with expert e
+    at = jnp.arange(slots // tile + held, dtype=jnp.int32)
+    expert = jnp.minimum(jnp.searchsorted(last, at, side="right"), held - 1).astype(jnp.int32)
+    nth = at - jnp.concatenate([jnp.zeros(1, last.dtype), last])[expert]
+    start = (ends - loads)[expert] + nth * tile
+    return _Tiles(expert, start.astype(jnp.int32), ends[expert], last[-1].astype(jnp.int32))
+
+
+def _tile_rows(t, tiles: _Tiles, order, k: int, tile: int):
+    """Tile ``t``: (expert, slots into the flat (token, choice) list, their
+    tokens, how many hold an assignment). Rows past the expert's end get
+    indices past the arrays' ends, ascending, so every index list stays
+    sorted and unique: such a row gathers zeros and its scatter is dropped."""
+    start = tiles.start[t]
+    spare = jnp.arange(tile, dtype=jnp.int32)
+    valid = start + spare < tiles.stop[t]
+    slots = jnp.where(valid, jax.lax.dynamic_slice_in_dim(order, start, tile), order.shape[0] + spare)
+    return tiles.expert[t], slots, jnp.where(valid, slots // k, order.shape[0] + spare), jnp.sum(valid)
+
+
+_SORTED = dict(indices_are_sorted=True, unique_indices=True)
+
+
+def _take(a, index):
+    return a.at[index].get(mode="fill", fill_value=0, **_SORTED)
+
+
+def _mm(a, b, contract):
+    """``a`` and ``b`` contracted over one axis each, float32 out."""
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _expert(w, e):
+    return jax.lax.dynamic_index_in_dim(w, e, keepdims=False)
+
+
+def _swiglu(xs, wg, wu):
+    """(gate pre-activation, its sigmoid, up, hidden in the operands' type)."""
+    a, b = _mm(xs, wg, (1, 0)), _mm(xs, wu, (1, 0))
+    sig = jax.nn.sigmoid(a)
+    return a, sig, b, (a * sig * b).astype(xs.dtype)
+
+
+def _over_groups(tiles: _Tiles, tile: int, stage, totals, one_tile, flush):
+    """The loop over the step's tiles, :data:`HELD_GROUP_TILES` at a time.
+    ``stage()`` makes a group's staging arrays; ``one_tile(t, at, staged,
+    totals)`` computes tile ``t`` and writes what it has for the tokens'
+    rows at row ``at`` of them; ``flush(staged, totals)`` adds a group's
+    staged rows into the totals, which it returns after the last group.
+    Tiles past the count are skipped: a part-filled group computes nothing
+    it has no assignment for."""
+    group = HELD_GROUP_TILES
+
+    def body(carry):
+        g, totals = carry
+
+        def step(j, c):
+            t = g * group + j
+            return jax.lax.cond(t < tiles.count, lambda c: one_tile(t, j * tile, *c), lambda c: c, c)
+
+        return g + 1, flush(*jax.lax.fori_loop(0, group, step, (stage(), totals)))
+
+    groups = (tiles.count + group - 1) // group
+    return jax.lax.while_loop(lambda c: c[0] < groups, body, (jnp.zeros((), jnp.int32), totals))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_tiles(x, gates, w_gate, w_up, w_down, order, tiles, k, tile):
+    """``y[token] += down_e(silu(gate_e x) * up_e x) * gate`` over the tiles
+    that hold assignments; also the assignments it computed. Its own
+    backward: the loop's trip count is the step's, which reverse-mode
+    differentiation of a loop cannot follow; the backward walks the same
+    tiles, recomputes each one's hidden and pulls ``dy`` through it, so
+    nothing is kept per tile; the weights' gradients add up in float32."""
+    return _held_tiles_fwd(x, gates, w_gate, w_up, w_down, order, tiles, k, tile)[0]
+
+
+def _put(staged, value, at):
+    return jax.lax.dynamic_update_slice_in_dim(staged, value, at, 0)
+
+
+def _held_tiles_fwd(x, gates, w_gate, w_up, w_down, order, tiles, k, tile):
+    flat = gates.reshape(-1)
+    staged_rows = HELD_GROUP_TILES * tile
+
+    def stage():  # indices past the end until a tile writes them: dropped
+        return (jnp.full((staged_rows,), order.shape[0], jnp.int32),
+                jnp.zeros((staged_rows, x.shape[1]), jnp.float32))
+
+    def one_tile(t, at, staged, totals):
+        (rows_all, ys_all), (y, done) = staged, totals
+        with jax.named_scope("dispatch"):
+            e, slots, rows, filled = _tile_rows(t, tiles, order, k, tile)
+            xs, weight = _take(x, rows), _take(flat, slots)[:, None]
+        with jax.named_scope("experts"):
+            *_, h = _swiglu(xs, _expert(w_gate, e), _expert(w_up, e))
+            ys = _mm(h, _expert(w_down, e), (1, 0)) * weight
+        return (_put(rows_all, rows, at), _put(ys_all, ys, at)), (y, done + filled)
+
+    def flush(staged, totals):
+        (rows_all, ys_all), (y, done) = staged, totals
+        with jax.named_scope("combine"):
+            return y.at[rows_all].add(ys_all, mode="drop"), done
+
+    totals = (jnp.zeros(x.shape, jnp.float32), jnp.zeros((), jnp.int32))
+    return (_over_groups(tiles, tile, stage, totals, one_tile, flush),
+            (x, gates, w_gate, w_up, w_down, order, tiles))
+
+
+def _held_tiles_bwd(k, tile, res, cotangents):
+    x, gates, w_gate, w_up, w_down, order, tiles = res
+    dy, _ = cotangents
+    flat = gates.reshape(-1)
+    dtype = x.dtype
+    staged_rows = HELD_GROUP_TILES * tile
+
+    def stage():
+        past = jnp.full((staged_rows,), order.shape[0], jnp.int32)
+        return (past, jnp.zeros((staged_rows, x.shape[1]), jnp.float32),
+                past, jnp.zeros((staged_rows,), jnp.float32))
+
+    def add_at(acc, e, g):  # one expert's slice, in place
+        return jax.lax.dynamic_update_index_in_dim(acc, _expert(acc, e) + g, e, 0)
+
+    def one_tile(t, at, staged, totals):
+        (rows_all, dxs_all, slots_all, dweight_all), (dx, dflat, dwg, dwu, dwd) = staged, totals
+        with jax.named_scope("dispatch"):
+            e, slots, rows, _ = _tile_rows(t, tiles, order, k, tile)
+            xs, weight, dys = _take(x, rows), _take(flat, slots)[:, None], _take(dy, rows)
+        with jax.named_scope("experts"):
+            wg_e, wu_e, wd_e = _expert(w_gate, e), _expert(w_up, e), _expert(w_down, e)
+            a, sig, b, h = _swiglu(xs, wg_e, wu_e)
+            dweight = jnp.sum(dys * _mm(h, wd_e, (1, 0)), axis=-1)
+            do = (dys * weight).astype(dtype)
+            dh = _mm(do, wd_e, (1, 1))
+            da = (dh * b * sig * (1 + a * (1 - sig))).astype(dtype)
+            db = (dh * a * sig).astype(dtype)
+            dxs = _mm(da, wg_e, (1, 1)) + _mm(db, wu_e, (1, 1))
+            dwg, dwu, dwd = (add_at(acc, e, _mm(lhs, rhs, (0, 0))) for acc, lhs, rhs in
+                             ((dwg, xs, da), (dwu, xs, db), (dwd, h, do)))
+        staged = (_put(rows_all, rows, at), _put(dxs_all, dxs, at),
+                  _put(slots_all, slots, at), _put(dweight_all, dweight, at))
+        return staged, (dx, dflat, dwg, dwu, dwd)
+
+    def flush(staged, totals):
+        (rows_all, dxs_all, slots_all, dweight_all), (dx, dflat, *dws) = staged, totals
+        with jax.named_scope("combine"):
+            return (dx.at[rows_all].add(dxs_all, mode="drop"),
+                    dflat.at[slots_all].add(dweight_all, mode="drop"), *dws)
+
+    totals = (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(flat),
+              *(jnp.zeros(w.shape, jnp.float32) for w in (w_gate, w_up, w_down)))
+    dx, dflat, dwg, dwu, dwd = _over_groups(tiles, tile, stage, totals, one_tile, flush)
+    no = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731  (integer inputs)
+    return (dx.astype(dtype), dflat.reshape(gates.shape),
+            dwg.astype(w_gate.dtype), dwu.astype(w_up.dtype), dwd.astype(w_down.dtype),
+            no(order), jax.tree.map(no, tiles))
+
+
+_held_tiles.defvjp(_held_tiles_fwd, _held_tiles_bwd)
+
+
+def held_experts(x, gates, idx, w_gate, w_up, w_down, *, first: int):
+    """``sum_e gate_e * down_e(silu(gate_e x) * up_e x)`` over the held
+    experts ``[first, first + held)``, for tokens ``x`` (N, d) with their
+    ``(N, k)`` gates and expert ids; the matmuls take their operands in
+    ``x``'s type. Returns ``(y (N, d) float32, counters (4,) float32)`` in
+    :data:`HELD_COUNTERS` order; ``moe_dropped`` is the held assignments
+    less those the loop over tiles computed."""
+    k = idx.shape[1]
+    held = w_gate.shape[0]
+    with jax.named_scope("dispatch"):
+        local = idx.reshape(-1) - first
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        # Loads from the sorted keys: expert e's assignments end where the
+        # first key > e stands.
+        ends = jnp.searchsorted(
+            key[order], jnp.arange(1, held + 1, dtype=key.dtype), side="left").astype(jnp.int32)
+        tiles = _plan_tiles(ends, key.shape[0], HELD_TILE_ROWS)
+        # room for the last tile's slice to stay inside the list
+        order = jnp.pad(order, (0, HELD_TILE_ROWS))
+    y, done = _held_tiles(x, gates, w_gate, w_up, w_down, order, tiles, k, HELD_TILE_ROWS)
+    loads = jnp.diff(ends, prepend=0).astype(jnp.float32)
+    n_held = ends[-1]
+    counters = jnp.stack([n_held.astype(jnp.float32), jnp.max(loads), jnp.mean(loads),
+                          (n_held - done).astype(jnp.float32)])
+    return y, counters

@@ -218,6 +218,29 @@ PARAM_AXES_TABLE: tuple[tuple[tuple[str, ...], tuple[str | None, ...]], ...] = (
     (("moe", "bi"), ("layers", "experts_p", None)),
     (("moe", "wo"), ("layers", "experts_p", None, "embed_p")),
     (("moe", "bo"), ("layers", "experts_p", "embed_p")),
+    # --- layer-pattern models (models/pattern.py): leaves stack over
+    # PERIODS (the scan's axis, "layers" again); q/k/v/out_proj kernels and
+    # the router take the rows above. Norm gains over a head, the
+    # convolution taps and the per-head decay parameters are replicated.
+    (("norm_1", "scale"), ("layers", "embed_p")),
+    (("norm_2", "scale"), ("layers", "embed_p")),
+    (("norm_f", "scale"), ("embed_p",)),
+    (("lm_head",), ("embed_p", "vocab_out")),
+    (("q_norm", "scale"), ("layers", None)),
+    (("k_norm", "scale"), ("layers", None)),
+    (("in_proj_qkvz", "kernel"), ("layers", "embed_p", "qkv")),
+    (("in_proj_ba", "kernel"), ("layers", "embed_p", None)),
+    (("gdn", "conv"), ("layers", None, None)),
+    (("gdn", "A_log"), ("layers", None)),
+    (("gdn", "dt_bias"), ("layers", None)),
+    (("gdn", "norm", "scale"), ("layers", None)),
+    (("moe", "w_gate"), ("layers", "experts_p", "embed_p", None)),
+    (("moe", "w_up"), ("layers", "experts_p", "embed_p", None)),
+    (("moe", "w_down"), ("layers", "experts_p", None, "embed_p")),
+    (("shared_gate", "kernel"), ("layers", "embed_p", None)),
+    (("gate_proj", "kernel"), ("layers", "embed_p", "mlp")),
+    (("up_proj", "kernel"), ("layers", "embed_p", "mlp")),
+    (("down_proj", "kernel"), ("layers", "mlp", "embed_p")),
 )
 
 

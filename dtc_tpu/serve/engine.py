@@ -165,6 +165,10 @@ class ServingEngine:
         self.params = params
         self.cfg = cfg
         self.mcfg = model.cfg
+        if getattr(self.mcfg, "layer_pattern", ()):
+            from dtc_tpu.models.pattern import NOT_SERVED
+
+            raise NotImplementedError(NOT_SERVED)
         if getattr(self.mcfg, "debug_checks", False):
             # The model would emit checkify.check guards that must be
             # functionalized before jit (see generate.py's debug path);

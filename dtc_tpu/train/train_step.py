@@ -212,11 +212,21 @@ def cross_entropy_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:
     return _stats_loss(logits, targets)[0]
 
 
+def stack_counters(mutated: dict) -> jax.Array:
+    """The sowed "counters" collection (pattern models: one row of
+    ``models.pattern.COUNTERS`` a layer that counts) as one ``(layers,
+    n)`` float32 array, in the tree's order."""
+    rows = [leaf.reshape(-1, leaf.shape[-1])
+            for leaf in jax.tree.leaves(mutated.get("counters", {}))]
+    return jnp.concatenate(rows, axis=0)
+
+
 def create_gspmd_train_step(
     mesh: Mesh,
     rules: Sequence[tuple[str, str | None]] = DEFAULT_RULES,
     state: TrainState | None = None,
     base_params: PyTree | None = None,
+    counters: bool = False,
 ) -> Callable[[TrainState, Batch, jax.Array], tuple[TrainState, jax.Array]]:
     """Build the jitted DP/TP/DP×TP train step.
 
@@ -233,11 +243,16 @@ def create_gspmd_train_step(
     in as a non-donated, non-differentiated argument, gradients and the
     optimizer update touch the adapter alone — which is exactly what makes
     adapter checkpoints/rollback operate on the tiny subtree for free.
+
+    ``counters`` (a model that sows the "counters" collection): the step
+    returns ``(state, loss, counters)``, the third a small device array the
+    caller fetches with the loss — never by a sync of its own.
     """
     jit_kwargs: dict[str, Any] = {"donate_argnums": (0,)}
     if state is not None:
+        replicated = NamedSharding(mesh, P())
         jit_kwargs["out_shardings"] = (
-            state_shardings(state, mesh), NamedSharding(mesh, P())
+            state_shardings(state, mesh), *(replicated,) * (2 if counters else 1)
         )
 
     # Donating the state lets XLA update params/opt-state in place instead of
@@ -262,13 +277,15 @@ def create_gspmd_train_step(
             with jax.named_scope("fwd"):
                 loss, mut = state.apply_fn(
                     {"params": params}, x, train=True, rngs={"dropout": rng},
-                    targets=y, mutable=["aux_loss"],
+                    targets=y, mutable=["aux_loss", "counters"],
                 )
-                return loss + sum_aux_loss(mut)
+                return loss + sum_aux_loss(mut), mut
 
-        loss, grads = jax.value_and_grad(loss_fn)(state.params)
+        (loss, mut), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
         with jax.named_scope("optimizer"):
             state = state.apply_gradients(grads=grads)
+        if counters:
+            return state, loss, stack_counters(mut)
         return state, loss
 
     if base_params is None:
@@ -353,6 +370,13 @@ def create_train_step(
     pins out_shardings to avoid the layout-churn double compile.
     ``base_params`` selects the LoRA-adapter step (state = adapter subtree,
     base frozen) — GSPMD modes only."""
+    pattern = bool(getattr(getattr(model, "cfg", None), "layer_pattern", ()))
+    if pattern and (mesh.shape.get("pipe", 1) > 1 or mesh.shape.get("model", 1) > 1):
+        raise ValueError(
+            "a layer-pattern model runs under dp and fsdp only: no pipeline "
+            "stages (pipe > 1) and no mesh axis over heads or experts "
+            f"(model > 1) yet; got mesh {dict(mesh.shape)}"
+        )
     if mesh.shape.get("pipe", 1) > 1:
         if base_params is not None:
             raise ValueError(
@@ -374,5 +398,5 @@ def create_train_step(
             model, mesh, num_microbatches=num_microbatches, rules=rules
         )
     return create_gspmd_train_step(
-        mesh, rules, state=state, base_params=base_params
+        mesh, rules, state=state, base_params=base_params, counters=pattern
     )

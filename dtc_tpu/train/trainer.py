@@ -39,6 +39,7 @@ from dtc_tpu.config.schema import ModelConfig, OptimConfig, TrainConfig
 from dtc_tpu.data.prefetch import ShardedPrefetchIterator
 from dtc_tpu.data.synthetic import synthetic_batch_iterator, synthetic_row_batches
 from dtc_tpu.models.gpt import GPT
+from dtc_tpu.models.pattern import build_model
 from dtc_tpu.parallel.mesh import mesh_from_config
 from dtc_tpu.parallel.pipeline import pp_param_specs, pp_stack_params
 from dtc_tpu.parallel.sharding import DEFAULT_RULES, batch_spec, param_specs
@@ -194,6 +195,22 @@ def _reshard_onto(tree: PyTree, mesh: Mesh) -> PyTree:
         return jax.device_put(np.asarray(a), NamedSharding(mesh, spec))
 
     return jax.tree.map(leaf, tree)
+
+
+def _emit_counters(tele, steps: list[int], counted: np.ndarray) -> None:
+    """One ``moe_counters`` event a step from the fetched ``(steps,
+    layers, n)`` counters."""
+    from dtc_tpu.models.pattern import COUNTERS
+
+    for step, rows in zip(steps, counted):
+        by_name = dict(zip(COUNTERS, rows.T))
+        tele.registry.emit(
+            "moe_counters", step=int(step),
+            moe_assigned_held=[float(v) for v in by_name["moe_assigned_held"]],
+            moe_load_max=float(by_name["moe_load_max"].max()),
+            moe_load_mean=float(by_name["moe_load_mean"].mean()),
+            moe_dropped=float(by_name["moe_dropped"].sum()),
+        )
 
 
 def _guarded_optimizer(train_cfg: TrainConfig, opt_cfg: OptimConfig):
@@ -431,7 +448,7 @@ def _train(
     # master-weight wrapper, so the pair can never half-apply.
     model_cfg = resolve_precision(opt_cfg, model_cfg)
 
-    model = GPT(model_cfg)
+    model = build_model(model_cfg)
     # LoRA finetune mode (dtc_tpu/adapters/): the TrainState is the
     # adapter subtree, the base is a frozen step input. One flag here —
     # the loop below is identical either way (that is the design).
@@ -759,15 +776,10 @@ def _train(
         # model FLOPs, the chip peak, and the static collective-census
         # estimate, so `trace_report.py --device` derives device-time MFU
         # and runs the census cross-check offline without the model.
-        from dtc_tpu.utils.metrics import (
-            gpt_step_flops, moe_step_flops, peak_flops_per_chip,
-        )
+        from dtc_tpu.utils.metrics import peak_flops_per_chip, step_flops
 
-        step_flops_fn = (
-            moe_step_flops if model_cfg.moe_experts > 0 else gpt_step_flops
-        )
         tele.set_device_profile_context(
-            step_flops=step_flops_fn(
+            step_flops=step_flops(
                 model_cfg, train_cfg.batch, model_cfg.max_seq_len
             ),
             peak_flops=peak_flops_per_chip(),
@@ -806,6 +818,17 @@ def _train(
         flash_plan = flash_plan_event(model_cfg)
         if flash_plan is not None:
             tele.registry.emit("flash_plan", **flash_plan)
+        if model_cfg.layer_pattern:
+            # What a pattern model will run, once: the pattern with each
+            # mixer kind's kernel and tiles, and the expert layer's share.
+            from dtc_tpu.models.pattern import layer_plan, moe_plan
+
+            tele.registry.emit("layer_plan", **layer_plan(model_cfg))
+            per_device = (train_cfg.batch // max(mesh.shape.get("data", 1), 1)
+                          ) * model_cfg.max_seq_len
+            plan = moe_plan(model_cfg, per_device)
+            if plan is not None:
+                tele.registry.emit("moe_plan", **plan)
         # Auto timing semantics: when rows are being logged, sync each step
         # so elapsed_time is step time, not dispatch time (see schema.py).
         sync_every_step = train_cfg.sync_every_step
@@ -1179,7 +1202,8 @@ def _train(
             warm_key = jax.random.fold_in(key, 2**31 - 1)  # stream disjoint from steps
             for i in range(warmup_steps):
                 x, y = next(data_it)
-                state, loss = train_step(state, Batch(x=x, y=y), jax.random.fold_in(warm_key, i))
+                # a pattern model's step also returns its counters
+                state, loss, *_ = train_step(state, Batch(x=x, y=y), jax.random.fold_in(warm_key, i))
             delivered += warmup_steps
             if warmup_steps:
                 # Sync via value fetch.
@@ -1199,7 +1223,7 @@ def _train(
                 state_copy = jax.tree.map(
                     lambda v: jnp.copy(v) if isinstance(v, jax.Array) else v, state
                 )
-                _, compile_loss = train_step(
+                _, compile_loss, *_ = train_step(
                     state_copy, Batch(x=dummy, y=dummy), jax.random.fold_in(key, 0)
                 )
                 jax.device_get(compile_loss)
@@ -1214,6 +1238,10 @@ def _train(
             if lead:
                 print("Start measuring")
             device_losses: list[jax.Array] = []
+            # A pattern model's per-step counters (models/pattern.COUNTERS,
+            # one row a layer): kept on the device beside the losses and
+            # fetched with them at the log boundary, never on their own.
+            device_counters: list[jax.Array] = []
             pending_rows: list[tuple[int, float]] = []
             # The snapshot dispatch's per-leaf copy executables compile on
             # the FIRST begin() for a given mesh; attribute that one tick
@@ -1256,7 +1284,7 @@ def _train(
                     with tele.clock.phase("rng"):
                         step_key = jax.random.fold_in(key, step)
                     with tele.clock.phase("launch"):
-                        state, loss = train_step(state, Batch(x=x, y=y), step_key)
+                        state, loss, *counters = train_step(state, Batch(x=x, y=y), step_key)
                 if chaos is not None:
                     poisoned, loss = chaos.maybe_poison(step, state, loss)
                     if poisoned is not state:
@@ -1266,6 +1294,7 @@ def _train(
                         # on_step_end flag a phantom train-step recompile.
                         tele.record_aux_compile(step, "chaos_poison")
                 device_losses.append(loss)
+                device_counters.extend(counters)
                 if sync_every_step:
                     with tele.clock.phase("block"):
                         jax.block_until_ready(loss)
@@ -1329,6 +1358,7 @@ def _train(
                         # checkpoint from it).
                         step = target
                         device_losses, pending_rows = [], []
+                        device_counters = []
                         window_start = time.perf_counter()
                         window_steps = 0
                         if wd is not None:
@@ -1375,8 +1405,15 @@ def _train(
                             * max(train_cfg.log_every, 1),
                         )
                     # One stacked transfer, not len(window) scalar fetches.
-                    losses = [float(v) for v in jax.device_get(jnp.stack(device_losses))]
+                    fetched, counted = jax.device_get((
+                        jnp.stack(device_losses),
+                        jnp.stack(device_counters) if device_counters else None,
+                    ))
+                    losses = [float(v) for v in fetched]
                     now = time.perf_counter()  # after the device sync
+                    if counted is not None:
+                        _emit_counters(tele, [s for s, _ in pending_rows], counted)
+                        device_counters = []
                     # Anomaly guard rides the losses ALREADY fetched for
                     # logging — zero additional per-step syncs.
                     if guard is not None:
@@ -1407,6 +1444,7 @@ def _train(
                                 # and replay from the restored step.
                                 step = target
                                 device_losses, pending_rows = [], []
+                                device_counters = []
                                 window_start = time.perf_counter()
                                 window_steps = 0
                                 if wd is not None:

@@ -72,6 +72,59 @@ def gpt_step_flops(cfg: ModelConfig, batch: int, seq_len: int) -> float:
     return dense + attn
 
 
+def pattern_matmul_params(cfg: ModelConfig) -> dict[str, float]:
+    """Matmul parameters a token passes in ONE layer of each kind of a
+    layer-pattern model (``models/pattern.py``), and in the head. A routed
+    expert counts at the share of tokens it expects, ``top_k / experts``;
+    the embedding gather, the norms and the depthwise convolution are not
+    matmuls."""
+    d = cfg.d_model
+    nk, nv = cfg.gdn_key_heads * cfg.gdn_key_dim, cfg.gdn_value_heads * cfg.gdn_value_dim
+    q_out, kv_out = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    routed = cfg.experts_held * cfg.moe_top_k / max(cfg.moe_experts, 1)
+    return {
+        "gdn": d * (2 * nk + 2 * nv) + d * 2 * cfg.gdn_value_heads + nv * d,
+        "gated_attn": d * 2 * q_out + 2 * d * kv_out + q_out * d,
+        "moe_shared": d * cfg.moe_experts + 3 * d * cfg.moe_shared_d_ff + d
+        + routed * 3 * d * cfg.moe_d_ff,
+        "head": d * cfg.padded_vocab_size,
+    }
+
+
+def gdn_scan_flops(cfg: ModelConfig, tokens: int) -> float:
+    """Least work of one Gated DeltaNet layer's recurrence, forward and
+    backward: per token and value head the state is decayed (dk dv), read
+    twice (S^T k, S^T q) and given a rank-one update, 7 dk dv in all;
+    the backward is twice the forward."""
+    return 3.0 * 7.0 * cfg.gdn_key_dim * cfg.gdn_value_dim * cfg.gdn_value_heads * tokens
+
+
+def pattern_step_flops(cfg: ModelConfig, batch: int, seq_len: int) -> float:
+    """Training FLOPs of one step of a layer-pattern model: 6 x matmul
+    parameters x tokens (head counted), causal attention as
+    :func:`gpt_step_flops` counts it (12 B T^2 H hd / 2 a layer), and the
+    recurrence's least work. Recomputation is not counted.
+    ``benchmark/flops_qwen3_next.py`` holds a copy; a test keeps them equal."""
+    tokens = batch * seq_len
+    per = pattern_matmul_params(cfg)
+    periods = cfg.n_layers // len(cfg.layer_pattern)
+    kinds = [cfg.layer_kinds(i) for i in range(len(cfg.layer_pattern))]
+    n_matmul = periods * sum(per[m] + per[f] for m, f in kinds) + per["head"]
+    n_attn = periods * sum(m == "gated_attn" for m, _ in kinds)
+    n_gdn = periods * sum(m == "gdn" for m, _ in kinds)
+    attn = 12.0 * n_attn * batch * seq_len**2 * cfg.n_heads * cfg.head_dim / 2.0
+    return 6.0 * n_matmul * tokens + attn + n_gdn * gdn_scan_flops(cfg, tokens)
+
+
+def step_flops(cfg: ModelConfig, batch: int, seq_len: int) -> float:
+    """The step's operation count for whatever model ``cfg`` describes."""
+    if cfg.layer_pattern:
+        return pattern_step_flops(cfg, batch, seq_len)
+    if cfg.moe_experts > 0:
+        return moe_step_flops(cfg, batch, seq_len)
+    return gpt_step_flops(cfg, batch, seq_len)
+
+
 def moe_step_flops(cfg: ModelConfig, batch: int, seq_len: int) -> float:
     """Training FLOPs/step for the MoE model (``moe_experts > 0``).
 
@@ -565,9 +618,8 @@ def mfu(
     peak = peak_flops_per_chip()
     if peak is None or step_time_s <= 0:
         return None
-    if cfg.moe_experts > 0:
-        fn = moe_step_flops_useful if moe_basis == "useful" else moe_step_flops
-        flops = fn(cfg, batch, seq_len)
+    if cfg.moe_experts > 0 and not cfg.layer_pattern and moe_basis == "useful":
+        flops = moe_step_flops_useful(cfg, batch, seq_len)
     else:
-        flops = gpt_step_flops(cfg, batch, seq_len)
+        flops = step_flops(cfg, batch, seq_len)
     return flops / (step_time_s * peak * n_chips)

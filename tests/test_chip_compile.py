@@ -342,3 +342,51 @@ def test_flagship_dp_train_step_fits_one_chip(topo, shipped):
         + mem.temp_size_in_bytes - mem.alias_size_in_bytes
     )
     assert 0 < need < V5E_HBM_BYTES, mem
+
+
+def test_pattern_cell_train_step_fits_one_chip(topo):
+    """The layer-pattern cell of the benchmark (Qwen3-Next's one period, 32
+    of 512 experts held, 2 rows x 8192) through the trainer's own step
+    builder: grouped KV heads at head size 256 through the flash kernels,
+    the experts' loop over tiles, and the whole under the chip's memory —
+    it is the tight resource of that cell."""
+    import json
+
+    from flax import linen as nn
+    from flax.training.train_state import TrainState
+
+    from dtc_tpu.config.schema import ModelConfig, OptimConfig
+    from dtc_tpu.models.pattern import build_model
+    from dtc_tpu.parallel.mesh import build_mesh
+    from dtc_tpu.parallel.sharding import DEFAULT_RULES
+    from dtc_tpu.train.optimizer import create_optimizer
+    from dtc_tpu.train.train_step import Batch, create_gspmd_train_step
+
+    cell = "qwen3-next-80b-a3b.train-ep16share-b2x8192"
+    with open(os.path.join(REPO, "benchmark", "configs", "qwen3-next-80b-a3b.json")) as f:
+        model = json.load(f)["model"]
+    with open(os.path.join(REPO, "benchmark", "workloads", f"{cell}.json")) as f:
+        workload = json.load(f)
+    cfg = ModelConfig(**{**model, **workload["train"]["model"]})
+    rows = workload["traffic"]["rows"]
+    mesh = build_mesh((1, 1, 1), devices=[topo.devices[0]])
+    replicated = NamedSharding(mesh, P())
+    net = build_model(cfg)
+    tx = create_optimizer(OptimConfig(**workload["optim"]), total_steps=1_000_000)
+    tokens = jnp.ones((1, cfg.max_seq_len), jnp.int32)
+
+    def init():
+        params = net.init({"params": jax.random.PRNGKey(0)}, tokens, train=False)["params"]
+        return TrainState.create(apply_fn=net.apply, params=params, tx=tx)
+
+    state = jax.tree.map(lambda x: _sds(x.shape, x.dtype, replicated), jax.eval_shape(init))
+    xy = _sds((rows, cfg.max_seq_len), jnp.int32, replicated)
+    rng = _sds((2,), jnp.uint32, replicated)
+    with mesh, nn.logical_axis_rules(DEFAULT_RULES):
+        step = create_gspmd_train_step(mesh, DEFAULT_RULES, counters=True)
+        compiled = step.lower(state, Batch(x=xy, y=xy), rng).compile()
+    # flash forward, dq and dk/dv
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    print("peak_memory_in_bytes", peak)
+    assert 0 < peak < V5E_HBM_BYTES
