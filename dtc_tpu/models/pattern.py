@@ -60,7 +60,7 @@ from dtc_tpu.config.schema import ModelConfig
 from dtc_tpu.models.gpt import _dtype
 from dtc_tpu.ops import moe_dispatch as md
 from dtc_tpu.ops.attention import causal_attention
-from dtc_tpu.ops.gated_delta import gated_delta_chunked
+from dtc_tpu.ops.gated_delta import gated_delta_chunked, supports_chunk_kernel
 
 #: The per-step counters a pattern model sows (collection ``counters``),
 #: one row a layer; the train step returns them beside the loss.
@@ -181,10 +181,11 @@ class GatedDeltaNet(nn.Module):
 
             q = unit(qkv[..., :nk].reshape(b, t, hk, dk), dk ** -0.5)
             k = unit(qkv[..., nk: 2 * nk].reshape(b, t, hk, dk))
-            # each key head serves hv / hk value heads
-            q, k = (jnp.repeat(v, hv // hk, axis=2) for v in (q, k))
+            # each key head serves hv / hk value heads: the scan picks it
             v = qkv[..., 2 * nk:].reshape(b, t, hv, dv)
-            o = gated_delta_chunked(q, k, v, g, beta, chunk=cfg.gdn_chunk, dtype=cdtype)
+            o = _rows_per_data_shard(
+                functools.partial(gated_delta_chunked, chunk=cfg.gdn_chunk, dtype=cdtype),
+                q, k, v, g, beta)
         with jax.named_scope("out"):
             o = RMSNorm(cfg.norm_eps, zero_centred=False, name="norm")(o)
             o = (o * jax.nn.silu(z.astype(f32))).astype(cdtype)
@@ -202,11 +203,10 @@ class SwiGLU(nn.Module):
         return _dense(cfg.d_model, "down_proj", cfg)(h)
 
 
-def _data_axis(batch: int):
-    """(mesh, axis, free axes) where the batch axis of the activations is
-    laid over more than one device under the active rules and mesh and
-    divides ``batch``; else None (one device, the batch-1 ``model.init``
-    trace, an already manual region)."""
+def _free_axes():
+    """(mesh, its axes that are not manual yet) under a mesh of more than
+    one device with such an axis; else None (one device, no mesh, an already
+    manual region)."""
     from jax._src.core import trace_state_clean
 
     from dtc_tpu.parallel.sharding import ambient_mesh
@@ -215,10 +215,41 @@ def _data_axis(batch: int):
     if mesh is None or mesh.size == 1:
         return None
     free = set(mesh.axis_names) - set(mesh.manual_axes)
+    return (mesh, free) if free else None
+
+
+def _data_axis(batch: int):
+    """(mesh, axis, free axes) where the batch axis of the activations is
+    laid over a free mesh axis under the active rules and divides ``batch``;
+    else None (:func:`_free_axes` is, or the batch-1 ``model.init`` trace)."""
+    where = _free_axes()
+    if where is None:
+        return None
+    mesh, free = where
     axis = dict(nn.get_logical_axis_rules()).get("batch")
     if axis not in free or batch % dict(mesh.shape)[axis]:
         return None
     return mesh, axis, free
+
+
+def _rows_per_data_shard(fn, *args):
+    """``fn`` over batch-leading arrays, each device on its own rows: XLA
+    cannot partition a Mosaic kernel (the scan's chunk-local pair), and the
+    scan is independent across rows, so on a mesh it runs in a region that
+    is manual over every free axis, as the flash kernel does — whole on
+    every device where the rows do not divide (the batch-1 ``model.init``
+    trace)."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    where = _free_axes()
+    if where is None:
+        return fn(*args)
+    mesh, free = where
+    data = _data_axis(args[0].shape[0])
+    rows = P(data[1]) if data else P()
+    return shard_map(fn, mesh=mesh, in_specs=(rows,) * len(args), out_specs=rows,
+                     axis_names=free, check_vma=False)(*args)
 
 
 def _per_data_shard(fn, where, x, *rest):
@@ -450,12 +481,22 @@ def layer_plan(cfg: ModelConfig) -> dict:
             "rotary_dims": int(cfg.head_dim * cfg.rope_fraction),
         }
     if any(m == "gdn" for m, _ in kinds):
+        from dtc_tpu.config.schema import DTYPE_BYTES
+
+        local = supports_chunk_kernel(
+            cfg.gdn_chunk, cfg.gdn_key_dim, cfg.gdn_value_dim, cfg.gdn_value_heads,
+            cfg.gdn_key_heads, DTYPE_BYTES[cfg.compute_dtype])
         plan["gdn"] = {
-            "kernel": "chunked_xla", "chunk": cfg.gdn_chunk,
+            "kernel": "mosaic" if local else "xla", "chunk": cfg.gdn_chunk,
             "chunks": cfg.max_seq_len // cfg.gdn_chunk,
             "key_heads": cfg.gdn_key_heads, "value_heads": cfg.gdn_value_heads,
             "key_dim": cfg.gdn_key_dim, "value_dim": cfg.gdn_value_dim,
         }
+        if local:
+            # the chunk-local kernels' grid step: value heads x chunk positions
+            plan["gdn"]["tile"] = [local["tiles"], local["chunk"]]
+            plan["gdn"]["vmem_limit_bytes"] = {
+                leg: local[leg]["vmem_limit_bytes"] for leg in ("fwd", "bwd")}
     return plan
 
 

@@ -37,6 +37,19 @@ over chunks keeps each chunk's incoming ``S`` (``T / C`` states, not
 ``T``); the backward rule walks the chunks in reverse, recomputes one
 chunk's step from its saved ``S`` and pulls the cotangents of the outputs
 and of the carried state through it.
+
+**The chunk-local part is a Mosaic kernel pair where its tiles are legal**
+(:func:`supports_chunk_kernel`; ``ops/vmem.py:gdn_chunk_plan``): one grid
+step takes a few (chunk, value head) tiles, reads q, k and v straight from
+the ``(B, T, H * d)`` layout — each value head's key head chosen by the
+slice, so nothing is repeated in HBM — and builds ``ratio``, ``A``, ``T``
+and the products in VMEM; only the scan's five operands go out, chunk-major.
+The backward is a kernel of its own that rebuilds a tile's ``T`` from the
+same inputs (the residuals are the inputs, so nothing is checkpointed) and
+writes ``dq``, ``dk`` (summed over the value heads a key head serves),
+``dv`` and the gradients for the cumulative decay and beta. Elsewhere (toy
+widths, a chunk off the sublane count) the ``jax.numpy`` form runs, which
+is also the kernels' oracle.
 """
 
 from __future__ import annotations
@@ -45,26 +58,54 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from dtc_tpu.ops import vmem
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def unit_lower_inverse(a: jax.Array, dtype=jnp.float32) -> jax.Array:
-    """``(I + A)^-1`` for strictly lower-triangular ``A`` of ``(..., C, C)``:
-    with ``N = -A`` nilpotent, ``sum_i N^i = (I + N)(I + N^2)(I + N^4)...`` —
-    ``log2 C`` squarings and as many products, all MXU-shaped and batched
-    over every chunk and head, where a substitution would be C dependent
-    steps. The products take their operands in ``dtype`` and accumulate in
-    float32; each factor is applied as ``out + out N^k``, so that no operand
-    holds a ``1 + small`` whose small part the rounding would lose (the
-    diagonal stays exactly 1). The backward is the inverse's own,
-    ``dA = -T^T dT T^T``: two products and no saved power."""
+    """``(I + A)^-1`` for strictly lower-triangular ``A`` of ``(..., C, C)``,
+    block by block: a lower-triangular ``[[L11, 0], [L21, L22]]`` has the
+    inverse ``[[T11, 0], [-T22 L21 T11, T22]]``, so with ``D`` the inverses
+    of all diagonal blocks of ``m`` positions side by side and ``B`` the
+    blocks of ``A`` under them, ``D - D B D`` holds the inverses of the
+    blocks of ``2 m`` — ``log2 C`` levels of two products each, all
+    MXU-shaped and batched over every chunk and head, where a substitution
+    would be C dependent steps. The products take their operands in
+    ``dtype`` and accumulate in float32. Every operand is an inverse of a
+    part of the system (its diagonal exactly 1) or a part of ``A``: nothing
+    larger than the answer is ever rounded. Not the squaring product
+    ``(I + N)(I + N^2)(I + N^4)...`` of ``N = -A``, for all its shorter
+    chain: its powers grow with the binomials where a chunk's keys align —
+    rows of ``|A|`` summing to 10 put errors of 3 to 14 on a ``T`` whose
+    entries are under 1, and the benchmark cell's loss was NaN three steps
+    later. The backward is the inverse's own, ``dA = -T^T dT T^T``: two
+    products and nothing saved but ``T``."""
+    return _blocked_inverse(a, dtype, _mm)
+
+
+def _blocked_inverse(a, dtype, mm):
     c = a.shape[-1]
-    power = -a
-    out = jnp.eye(c, dtype=a.dtype) + power
-    span = 2
-    while span < c:
-        power = _mm(power, power, dtype)
-        out = out + _mm(out, power, dtype)
-        span *= 2
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+
+    def under(level):
+        """The lower-left block of ``2 ** level`` positions in every
+        diagonal block of twice that."""
+        return ((row >> (level + 1)) == (col >> (level + 1))) & (((row >> level) & 1) == 1) & (
+            ((col >> level) & 1) == 0)
+
+    out = jnp.where(row == col, 1.0, 0.0).astype(a.dtype) - jnp.where(under(0), a, 0.0)
+    level = 1
+    while 1 << level < c:
+        out = out - mm(mm(out, jnp.where(under(level), a, 0.0), dtype), out, dtype)
+        level += 1
     return out
 
 
@@ -134,18 +175,23 @@ def _scan_bwd(dtype, res, dout):
 _scan_chunks.defvjp(_scan_fwd, _scan_bwd)
 
 
-@functools.partial(jax.checkpoint, static_argnums=(5,))
-def _chunk_local(q, k, v, g, beta, dtype):
-    """Everything of a chunk that needs no state, for all chunks at once
-    (chunk-major ``(N, B, H, C, *)``): the scan's operands ``qg``, ``w``,
-    ``local``, ``kdec`` in ``dtype``, ``u`` and ``decay`` in float32. Under
-    ``jax.checkpoint``: the (C, C) intermediates (a dozen arrays the size of
-    q each, padded to the lane width at C = 64) are recomputed in the
-    backward, not kept."""
-    f32 = jnp.float32
-    chunk = q.shape[-2]
-    q, k, v = (x.astype(f32) for x in (q, k, v))
-    gamma = jnp.cumsum(g, axis=-1)                                      # (N, B, H, C)
+def _chunk_major(x, chunk):  # (B, T, H, *) -> (N, B, H, C, *)
+    b, t, h = x.shape[:3]
+    x = x.reshape(b, t // chunk, chunk, h, *x.shape[3:])
+    return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
+
+
+def _chunk_local_xla(q, k, v, gamma, beta, dtype):
+    """Everything of a chunk that needs no state, for all chunks at once,
+    from the kernels' arguments — q, k ``(B, T, Hk, dk)``, v ``(B, T, H,
+    dv)``, the decay's log summed up inside the chunk and beta ``(N, B, H,
+    C)``: the scan's operands ``qg``, ``w``, ``local``, ``kdec`` in
+    ``dtype`` and ``u`` in float32, chunk-major ``(N, B, H, C, *)``. The
+    ``jax.numpy`` form: each key head repeated for the value heads it
+    serves, a dozen (C, C) intermediates through HBM."""
+    rep, chunk = v.shape[2] // q.shape[2], gamma.shape[-1]
+    q, k = (jnp.repeat(x, rep, axis=2) if rep > 1 else x for x in (q, k))
+    q, k, v = (_chunk_major(x, chunk).astype(jnp.float32) for x in (q, k, v))
     diff = gamma[..., :, None] - gamma[..., None, :]
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))
     # exp of a masked difference: the upper part would be exp(+x).
@@ -158,34 +204,215 @@ def _chunk_local(q, k, v, g, beta, dtype):
     w = _mm(tri, kb * jnp.exp(gamma)[..., None], dtype)
     local = _mm(q, kt, dtype) * ratio
     qg = q * jnp.exp(gamma)[..., None]
-    last = gamma[..., -1:]
-    kdec = k * jnp.exp(last - gamma)[..., None]
+    kdec = k * jnp.exp(gamma[..., -1:] - gamma)[..., None]
     qg, w, local, kdec = (x.astype(dtype) for x in (qg, w, local, kdec))
-    return qg, w, u, local, kdec, jnp.exp(last[..., 0])
+    return qg, w, u, local, kdec
+
+
+# ---------------------------------------------------------------------------
+# the chunk-local part as a Mosaic kernel pair
+
+
+def supports_chunk_kernel(chunk: int, dk: int, dv: int, hv: int, hk: int,
+                          itemsize: int = 2) -> dict | None:
+    """The planner's plan where the chunk-local kernels run — ``dk`` and
+    ``dv`` multiples of the lane width, the chunk a multiple of the sublane
+    count, the blocks inside the budget — else None, and the ``jax.numpy``
+    form runs. Off the TPU the kernels run interpreted."""
+    plan = vmem.gdn_chunk_plan(chunk, dk, dv, hv, hk, itemsize)
+    return plan if plan is not None and plan["fits"] else None
+
+
+def _bmm(x, y, dtype, lhs=2, rhs=1):
+    """Batched over the leading axis, contracting ``x``'s axis ``lhs`` with
+    ``y``'s axis ``rhs`` ((2, 1): ``x y``; (2, 2): ``x y^T``; (1, 1): ``x^T
+    y``); operands in ``dtype``, float32 out."""
+    return jax.lax.dot_general(x.astype(dtype), y.astype(dtype), (((lhs,), (rhs,)), ((0,), (0,))),
+                               preferred_element_type=jnp.float32)
+
+
+def _masks(c):
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    return row == col, row >= col, row > col
+
+
+def _heads(ref, n, width):
+    """A ``(1, C, n * width)`` block as ``(n, C, width)`` float32: a lane
+    tile a head."""
+    return jnp.stack([ref[0, :, i * width:(i + 1) * width] for i in range(n)]).astype(jnp.float32)
+
+
+def _decays(gam_ref, beta_ref, eye, lower):
+    """Of the step's tiles ``(G, ...)``: beta and gamma as columns ``(G, C,
+    1)``, ``exp(gamma)``, the decay to the chunk's end, and ``ratio`` — every
+    ``exp`` of a difference that is never positive. A row ``(G, 1, C)``
+    becomes a column without a transpose: its diagonal summed along the
+    lanes."""
+    col = lambda row: jnp.sum(jnp.where(eye, row, 0.0), axis=2, keepdims=True)  # noqa: E731
+    g_row = gam_ref[0, 0]
+    g_col, b_col = col(g_row), col(beta_ref[0, 0])
+    ratio = jnp.where(lower, jnp.exp(jnp.where(lower, g_col - g_row, 0.0)), 0.0)
+    return b_col, jnp.exp(g_col), jnp.exp(g_row[:, :, -1:] - g_col), ratio
+
+
+def _local_fwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref,
+                      qg_ref, w_ref, u_ref, local_ref, kdec_ref, *, tiles, rep, dk, dv, dtype):
+    """All of the step's tiles at once, batched over the leading axis: a
+    tile's ten dependent 64-wide products then lie beside the other tiles'
+    and do not wait on the MXU's latency one after the other. ``k k^T`` and
+    ``q k^T`` once a key head, for the value heads it serves."""
+    eye, lower, strict = _masks(q_ref.shape[1])
+    per = lambda x: jnp.repeat(x, rep, axis=0) if rep > 1 else x  # noqa: E731  key head -> value heads
+    q, k = _heads(q_ref, tiles // rep, dk), _heads(k_ref, tiles // rep, dk)
+    kk, qk = per(_bmm(k, k, dtype, 2, 2)), per(_bmm(q, k, dtype, 2, 2))
+    q, k = per(q), per(k)
+    b_col, e_col, x_col, ratio = _decays(gam_ref, beta_ref, eye, lower)
+    tri = _blocked_inverse(jnp.where(strict, kk * b_col * ratio, 0.0), dtype, _bmm)
+    u_ref[0, 0] = _bmm(tri, _heads(v_ref, tiles, dv) * b_col, dtype)
+    w_ref[0, 0] = _bmm(tri, k * (b_col * e_col), dtype).astype(w_ref.dtype)
+    local_ref[0, 0] = (qk * ratio).astype(local_ref.dtype)
+    qg_ref[0, 0] = (q * e_col).astype(qg_ref.dtype)
+    kdec_ref[0, 0] = (k * x_col).astype(kdec_ref.dtype)
+
+
+def _local_bwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref,
+                      dqg_ref, dw_ref, du_ref, dlocal_ref, dkdec_ref,
+                      dq_ref, dk_ref, dv_ref, dgam_ref, dbeta_ref, *, tiles, rep, dk, dv, dtype):
+    """The tiles' forward rebuilt, and the five cotangents pulled through
+    it: ``dT = du (beta v)^T + dw (beta k e^gamma)^T``, ``dA = -T^T dT T^T``
+    under the strict mask, then the products' own rules. ``ratio_ij =
+    exp(gamma_i - gamma_j)`` gives ``+ds`` to row i's gamma and ``-ds`` to
+    column j's, ``ds = d(ratio) * ratio``. ``dq`` and ``dk`` are summed over
+    the value heads a key head serves."""
+    f32 = jnp.float32
+    c = q_ref.shape[1]
+    eye, lower, strict = _masks(c)
+    rowsum = lambda x: jnp.sum(x, axis=2, keepdims=True)                              # noqa: E731
+    as_row = lambda col: jnp.sum(jnp.where(eye, col, 0.0), axis=1, keepdims=True)     # noqa: E731
+    per = lambda x: jnp.repeat(x, rep, axis=0) if rep > 1 else x                      # noqa: E731
+    over = lambda x: x.reshape(tiles // rep, rep, *x.shape[1:]).sum(axis=1) if rep > 1 else x  # noqa: E731
+    q1, k1 = _heads(q_ref, tiles // rep, dk), _heads(k_ref, tiles // rep, dk)         # a key head each
+    kk, qk = per(_bmm(k1, k1, dtype, 2, 2)), per(_bmm(q1, k1, dtype, 2, 2))
+    q, k, v = per(q1), per(k1), _heads(v_ref, tiles, dv)
+    b_col, e_col, x_col, ratio = _decays(gam_ref, beta_ref, eye, lower)
+    tri = _blocked_inverse(jnp.where(strict, kk * b_col * ratio, 0.0), dtype, _bmm).astype(dtype)
+    du, dw = du_ref[0, 0], dw_ref[0, 0].astype(f32)
+    dqg, dkdec = dqg_ref[0, 0].astype(f32), dkdec_ref[0, 0].astype(f32)
+    dlocal = dlocal_ref[0, 0].astype(f32) * ratio
+    be = b_col * e_col
+    dtri = _bmm(du, v * b_col, dtype, 2, 2) + _bmm(dw, k * be, dtype, 2, 2)
+    dvb, dkbe = _bmm(tri, du, dtype, 1, 1), _bmm(tri, dw, dtype, 1, 1)
+    da = jnp.where(strict, -_bmm(_bmm(tri, dtri, dtype, 1, 1), tri, dtype, 2, 2), 0.0) * ratio   # dA * ratio
+    ds = da * (b_col * kk) + dlocal * qk
+    z = rowsum(dkdec * k) * x_col                              # d(decay to the chunk's end), times it
+    dkbe_k = rowsum(dkbe * k)
+    dgam = rowsum(ds) + dkbe_k * be + rowsum(dqg * q) * e_col - z
+    dbeta = rowsum(da * kk) + rowsum(dvb * v) + dkbe_k * e_col
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1) == c - 1
+    dgam_ref[0, 0] = (as_row(dgam) - jnp.sum(ds, axis=1, keepdims=True)
+                      + jnp.where(last, jnp.sum(z, axis=1, keepdims=True), 0.0))
+    dbeta_ref[0, 0] = as_row(dbeta)
+    dkk, dqk = over(da * b_col), over(dlocal)
+    dq = over(dqg * e_col) + _bmm(dqk, k1, dtype)
+    dk_ = (over(dkbe * be + dkdec * x_col) + _bmm(dqk, q1, dtype, 1, 1)
+           + _bmm(dkk, k1, dtype) + _bmm(dkk, k1, dtype, 1, 1))
+    dv_ = dvb * b_col
+    for i in range(tiles // rep):
+        dq_ref[0, :, i * dk:(i + 1) * dk] = dq[i].astype(dq_ref.dtype)
+        dk_ref[0, :, i * dk:(i + 1) * dk] = dk_[i].astype(dk_ref.dtype)
+    for i in range(tiles):
+        dv_ref[0, :, i * dv:(i + 1) * dv] = dv_[i].astype(dv_ref.dtype)
+
+
+def _launch(kernel, leg, q, k, v, gamma, beta, cts, dtype):
+    """One kernel of the pair over the grid (rows, chunks, groups of value
+    heads). q / k and v are blocks of ``(B, T, H * d)``: a chunk's positions
+    by the lanes of the step's heads; a scan operand is a block of ``(N, B,
+    H, C, width)``, the decay and beta rows among them with a ``C`` of 1 (a
+    head's row a tile of its own). The forward writes the five operands;
+    the backward reads their cotangents ``cts`` too and writes a gradient
+    for every input, in the input's shape."""
+    (b, t, hk, dk), (hv, dv), c = q.shape, v.shape[2:], gamma.shape[-1]
+    plan = supports_chunk_kernel(c, dk, dv, hv, hk, q.dtype.itemsize)
+    tiles, kheads = plan["tiles"], plan["key_heads_per_step"]
+    qk = pl.BlockSpec((1, c, kheads * dk), lambda bi, ni, hi: (bi, ni, hi))
+    vs = pl.BlockSpec((1, c, tiles * dv), lambda bi, ni, hi: (bi, ni, hi))
+    operand = lambda width, rows=c: pl.BlockSpec(  # noqa: E731
+        (1, 1, tiles, rows, width), lambda bi, ni, hi: (ni, bi, hi, 0, 0))
+    inputs = (q.reshape(b, t, hk * dk), k.reshape(b, t, hk * dk), v.reshape(b, t, hv * dv),
+              gamma[..., None, :], beta[..., None, :])
+    in_specs = [qk, qk, vs, operand(c, 1), operand(c, 1)]
+    scan_specs = [operand(dk), operand(dk), operand(dv), operand(c), operand(dk)]
+    if leg == "fwd":
+        out = lambda width, dt: jax.ShapeDtypeStruct((t // c, b, hv, c, width), dt)  # noqa: E731
+        out_specs = scan_specs
+        out_shape = [out(dk, dtype), out(dk, dtype), out(dv, jnp.float32), out(c, dtype), out(dk, dtype)]
+    else:
+        out_specs, in_specs = in_specs, in_specs + scan_specs
+        out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in inputs]
+    return pl.pallas_call(
+        functools.partial(kernel, tiles=tiles, rep=tiles // kheads, dk=dk, dv=dv, dtype=dtype),
+        name=f"gdn_chunk_local_{leg}",
+        grid=(b, t // c, hv // tiles), in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=plan[leg]["vmem_limit_bytes"],
+        ),
+        interpret=_interpret(),
+    )(*inputs, *cts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _chunk_local(q, k, v, gamma, beta, dtype, mosaic):
+    """The scan's operands ``(qg, w, u, local, kdec)``, chunk-major, from q,
+    k ``(B, T, Hk, dk)``, v ``(B, T, Hv, dv)`` and the chunks' cumulative
+    decay and beta ``(N, B, Hv, C)``. The residuals are the inputs: the
+    backward rebuilds what it needs (inside the kernel, or as the
+    ``jax.numpy`` form's own pullback), so no (C, C) array is kept."""
+    if mosaic:
+        return tuple(_launch(_local_fwd_kernel, "fwd", q, k, v, gamma, beta, (), dtype))
+    return _chunk_local_xla(q, k, v, gamma, beta, dtype)
+
+
+def _chunk_local_fwd(q, k, v, gamma, beta, dtype, mosaic):
+    return _chunk_local(q, k, v, gamma, beta, dtype, mosaic), (q, k, v, gamma, beta)
+
+
+def _chunk_local_bwd(dtype, mosaic, res, cts):
+    if mosaic:
+        q, k, v, gamma, beta = res
+        dq, dk, dv, dgamma, dbeta = _launch(_local_bwd_kernel, "bwd", *res, cts, dtype)
+        return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+                dgamma.reshape(gamma.shape), dbeta.reshape(beta.shape))
+    return jax.vjp(lambda *a: _chunk_local_xla(*a, dtype), *res)[1](cts)
+
+
+_chunk_local.defvjp(_chunk_local_fwd, _chunk_local_bwd)
 
 
 def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64, dtype=jnp.float32):
     """The recurrence of the module docstring over ``(B, T, H, *)`` inputs.
 
-    ``q`` and ``k`` are ``(B, T, H, dk)`` (normalised and scaled by the
-    caller), ``v`` is ``(B, T, H, dv)``, ``g`` (the log of the decay, never
-    positive) and ``beta`` are ``(B, T, H)`` float32. ``T`` is a multiple of
-    ``chunk``. Returns ``(B, T, H, dv)`` float32.
+    ``q`` and ``k`` are ``(B, T, Hk, dk)`` (normalised and scaled by the
+    caller), ``v`` is ``(B, T, H, dv)`` with ``H`` a multiple of ``Hk``
+    (each key head serves ``H / Hk`` value heads), ``g`` (the log of the
+    decay, never positive) and ``beta`` are ``(B, T, H)`` float32. ``T`` is
+    a multiple of ``chunk``. Returns ``(B, T, H, dv)`` float32.
     """
-    b, t, h, dk = q.shape
+    b, t, hk, dk = q.shape
+    h, dv = v.shape[2:]
     if t % chunk:
         raise ValueError(f"sequence length {t} is not a multiple of the chunk {chunk}")
-    n = t // chunk
+    if h % hk:
+        raise ValueError(f"{h} value heads are not a multiple of {hk} key heads")
     f32 = jnp.float32
-
-    def chunks(x):  # (B, T, H, *) -> (N, B, H, C, *)
-        x = x.reshape(b, n, chunk, h, *x.shape[3:])
-        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
-
-    q, k, v = (chunks(x) for x in (q, k, v))
-    g, beta = chunks(g.astype(f32)), chunks(beta.astype(f32))
+    mosaic = supports_chunk_kernel(chunk, dk, dv, h, hk, q.dtype.itemsize) is not None
     with jax.named_scope("chunk_local"):
-        qg, w, u, local, kdec, decay = _chunk_local(q, k, v, g, beta, dtype)
+        gamma = jnp.cumsum(_chunk_major(g.astype(f32), chunk), axis=-1)    # (N, B, H, C)
+        qg, w, u, local, kdec = _chunk_local(
+            q, k, v, gamma, _chunk_major(beta.astype(f32), chunk), dtype, mosaic)
+        decay = jnp.exp(gamma[..., -1])
     with jax.named_scope("chunk_carry"):
         out = _scan_chunks(qg, w, u, local, kdec, decay, dtype)        # (N, B, H, C, dv)
     out = jnp.moveaxis(jnp.moveaxis(out, 2, 3), 0, 1)                   # (B, N, C, H, dv)

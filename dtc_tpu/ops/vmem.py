@@ -515,6 +515,64 @@ def flash_grid_tile_fits(block_q: int, block_kv: int, itemsize: int = 4) -> bool
 
 
 # ---------------------------------------------------------------------------
+# gated deltanet, the chunk-local part (ops/gated_delta.py)
+# ---------------------------------------------------------------------------
+
+#: Sublane count of a float32 tile: the chunk's positions lie along it.
+SUBLANE = 8
+
+#: (chunk, head) tiles one grid step of the chunk-local kernels takes where
+#: the value heads allow it: enough that a step's fixed cost (~0.35 us) and
+#: the latency of a tile's dependent 64-wide products are shared, few enough
+#: that the unrolled body stays small.
+GDN_TILES_PER_STEP = 8
+
+
+def gdn_chunk_plan(
+    chunk: int, dk: int, dv: int, hv: int, hk: int, itemsize: int = 2,
+) -> dict[str, Any] | None:
+    """The chunk-local kernels' plan (``ops/gated_delta.py``) for a chunk
+    of ``chunk`` positions, ``hk`` key heads of ``dk`` serving ``hv`` value
+    heads of ``dv``, q / k / v in ``itemsize`` bytes: the value heads one
+    grid step takes, per pass the bytes of its blocks (as the BlockSpecs
+    state them: q and k lane blocks of the step's key heads, v and the
+    operands of the scan a block a value head, the chunk's decay and beta
+    rows in float32), the float32 (C, C) and (C, d) temporaries of the
+    tiles in flight, and the ``vmem_limit_bytes`` the kernel states.
+
+    None where a tile is not legal: ``dk`` and ``dv`` multiples of the
+    lane width, the chunk a multiple of the sublane count and no wider
+    than a lane tile, the key heads dividing the value heads."""
+    if dk % LANE or dv % LANE or chunk % SUBLANE or chunk > LANE or hk < 1 or hv % hk:
+        return None
+    rep = hv // hk
+    # a step takes whole key heads: as many value heads as divide hv, up to 8
+    tiles = max((g for g in range(rep, max(GDN_TILES_PER_STEP, rep) + 1, rep) if hv % g == 0))
+    cc = chunk * max(chunk, LANE)                 # a (C, C) block pads to the lane width
+    qk = 2 * (tiles // rep) * chunk * dk          # elements
+    rows = 2 * tiles * SUBLANE * LANE * 4         # a head's row a float32 tile of its own
+    operands = tiles * (3 * chunk * dk * itemsize + chunk * dv * 4 + cc * itemsize)  # qg w kdec, u, local
+    fwd_blocks = (qk + tiles * chunk * dv) * itemsize + rows + operands
+    bwd_blocks = 2 * (qk + tiles * chunk * dv) * itemsize + 2 * rows + operands
+    # per tile in flight a dozen float32 (C, C) arrays and as many (C, d)
+    transient = tiles * 12 * (cc + chunk * max(dk, dv)) * 4
+    plan: dict[str, Any] = {
+        "kernel": "gdn_chunk_local", "chunk": chunk, "key_dim": dk, "value_dim": dv,
+        "tiles": tiles, "key_heads_per_step": tiles // rep, "budget_bytes": VMEM_BUDGET_BYTES,
+    }
+    for name, blocks in (("fwd", fwd_blocks), ("bwd", bwd_blocks)):
+        total = 2 * blocks                        # blocks double-buffered
+        plan[name] = {
+            "bytes": total,
+            "modeled_transient_bytes": transient,
+            "fits": total <= VMEM_BUDGET_BYTES,
+            "vmem_limit_bytes": total + transient + VMEM_COMPILER_ALLOWANCE_BYTES,
+        }
+    plan["fits"] = plan["fwd"]["fits"] and plan["bwd"]["fits"]
+    return plan
+
+
+# ---------------------------------------------------------------------------
 # per-layer decode kernels (ops/decode_attention.py)
 # ---------------------------------------------------------------------------
 

@@ -84,11 +84,11 @@ def _as_on_the_chip(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
 
     from dtc_tpu.ops import (
-        attention, decode_attention, decode_fused, flash_attention,
+        attention, decode_attention, decode_fused, flash_attention, gated_delta,
         overlap_collectives,
     )
 
-    for mod in (flash_attention, decode_attention, decode_fused, overlap_collectives):
+    for mod in (flash_attention, decode_attention, decode_fused, overlap_collectives, gated_delta):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     prev = jax.config.jax_enable_compilation_cache
@@ -196,6 +196,63 @@ def test_zigzag_ring_block_kernels(one_chip):
         return fa._block_call(q, k, v, d ** -0.5, False, g, d, do=out, o=out, lse=lse)
 
     _compile(fwd_bwd, x, x, x)
+
+
+# ---------------------------------------------------------------------------
+# gated deltanet
+
+
+def test_gdn_chunk_local_fwd_bwd(one_chip):
+    """The chunk-local kernel pair of the Gated DeltaNet scan at the
+    benchmark cell's shape — 2 rows x 8192, 16 key heads serving 32 value
+    heads of 128, chunks of 64, bf16 — through ``gated_delta_chunked``:
+    (64, 64) blocks, transposed 64-wide operands and the stated
+    ``vmem_limit_bytes`` are what interpret mode cannot refuse."""
+    from dtc_tpu.ops.gated_delta import gated_delta_chunked, supports_chunk_kernel
+
+    b, t, hk, hv, d = 2, 8192, 16, 32, 128
+    assert supports_chunk_kernel(64, d, d, hv, hk, 2)
+    qk = _sds((b, t, hk, d), jnp.bfloat16, one_chip)
+    v = _sds((b, t, hv, d), jnp.bfloat16, one_chip)
+    g = _sds((b, t, hv), jnp.float32, one_chip)
+
+    def loss(q, k, v, g, beta):
+        return gated_delta_chunked(q, k, v, g, beta, chunk=64, dtype=jnp.bfloat16).sum()
+
+    text = _compile(jax.grad(loss, argnums=range(5)), qk, qk, v, g, g).as_text()
+    assert "gdn_chunk_local_fwd" in text and "gdn_chunk_local_bwd" in text
+
+
+def test_gdn_layer_on_a_four_chip_mesh(topo):
+    """The mixer at the cell's widths with its rows over data=4 (dp / fsdp
+    of a pattern model): the kernel pair must sit in a manual region, each
+    chip on its own rows — a bare ``pallas_call`` is refused on a mesh
+    ("cannot be automatically partitioned"), which the CPU never shows."""
+    import json
+
+    from flax import linen as nn
+
+    from dtc_tpu.config.schema import ModelConfig
+    from dtc_tpu.models.pattern import GatedDeltaNet
+    from dtc_tpu.parallel.mesh import build_mesh
+    from dtc_tpu.parallel.sharding import DEFAULT_RULES
+
+    with open(os.path.join(REPO, "benchmark", "configs", "qwen3-next-80b-a3b.json")) as f:
+        cfg = replace(ModelConfig(**json.load(f)["model"]), max_seq_len=1024)
+    mesh = build_mesh((1, 4, 1), devices=list(topo.devices))
+    layer = GatedDeltaNet(cfg)
+    x = _sds((4, cfg.max_seq_len, cfg.d_model), jnp.bfloat16, NamedSharding(mesh, P("data")))
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, NamedSharding(mesh, P())),
+        jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.ones((1, *x.shape[1:]), x.dtype))))
+
+    def loss(p, x):
+        return layer.apply(p, x).astype(jnp.float32).sum()
+
+    with mesh, nn.logical_axis_rules(DEFAULT_RULES):
+        text = _compile(jax.grad(loss, argnums=(0, 1)), params, x).as_text()
+    assert "gdn_chunk_local_fwd" in text and "gdn_chunk_local_bwd" in text
+    assert "all-gather" not in text  # each chip's rows stay home
 
 
 # ---------------------------------------------------------------------------
@@ -348,7 +405,7 @@ def test_pattern_cell_train_step_fits_one_chip(topo):
     """The layer-pattern cell of the benchmark (Qwen3-Next's one period, 32
     of 512 experts held, 2 rows x 8192) through the trainer's own step
     builder: grouped KV heads at head size 256 through the flash kernels,
-    the experts' loop over tiles, and the whole under the chip's memory —
+    the scan's chunk-local kernel pair, the experts' loop over tiles, and the whole under the chip's memory —
     it is the tight resource of that cell."""
     import json
 
@@ -385,8 +442,10 @@ def test_pattern_cell_train_step_fits_one_chip(topo):
     with mesh, nn.logical_axis_rules(DEFAULT_RULES):
         step = create_gspmd_train_step(mesh, DEFAULT_RULES, counters=True)
         compiled = step.lower(state, Batch(x=xy, y=xy), rng).compile()
-    # flash forward, dq and dk/dv
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    # flash forward, dq and dk/dv; the scan's chunk-local forward and backward
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 5
+    assert "gdn_chunk_local_fwd" in text and "gdn_chunk_local_bwd" in text
     peak = compiled.memory_analysis().peak_memory_in_bytes
     print("peak_memory_in_bytes", peak)
     assert 0 < peak < V5E_HBM_BYTES

@@ -97,27 +97,163 @@ def normed_input(cfg, seed=0, rows=2):
 # the scan: chunked against the token recurrence
 
 
-@pytest.mark.parametrize("dtype,tol", [("float32", TIGHT), ("bfloat16", LOOSE)])
-@pytest.mark.parametrize("chunk", [16, 32])  # 4 and 2 chunks of T = 64
-def test_gdn_chunked_equals_recurrence(chunk, dtype, tol):
-    b, t, h, dk, dv = 2, 64, 4, 16, 16
-    ks = jax.random.split(jax.random.PRNGKey(chunk), 6)
+def _scan_inputs(b, t, hk, h, dk, dv, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
-    q = unit(jax.random.normal(ks[0], (b, t, h, dk))) / np.sqrt(dk)
-    k = unit(jax.random.normal(ks[1], (b, t, h, dk)))
+    q = unit(jax.random.normal(ks[0], (b, t, hk, dk))) / np.sqrt(dk)
+    k = unit(jax.random.normal(ks[1], (b, t, hk, dk)))
     v = jax.random.normal(ks[2], (b, t, h, dv))
     g = -2.0 * jax.nn.softplus(jax.random.normal(ks[3], (b, t, h)))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
-    co = jax.random.normal(ks[5], (b, t, h, dv))
-    args = (q, k, v, g, beta)
+    return (q, k, v, g, beta), jax.random.normal(ks[5], (b, t, h, dv))
+
+
+def _out_and_grads(fn, args, co):
+    return (fn(*args), *jax.grad(lambda *a: jnp.sum(fn(*a) * co), argnums=range(5))(*args))
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", TIGHT), ("bfloat16", LOOSE)])
+@pytest.mark.parametrize("chunk", [16, 32])  # 4 and 2 chunks of T = 64
+def test_gdn_chunked_equals_recurrence(chunk, dtype, tol):
+    args, co = _scan_inputs(2, 64, 4, 4, 16, 16, seed=chunk)
     with jax.default_matmul_precision("highest"):
-        want = ref.delta_rule(*args)
-        want_g = jax.grad(lambda *a: jnp.sum(ref.delta_rule(*a) * co), argnums=range(5))(*args)
-    fn = lambda *a: gated_delta_chunked(*a, chunk=chunk, dtype=jnp.dtype(dtype))  # noqa: E731
-    close(fn(*args), want, tol)
-    got_g = jax.grad(lambda *a: jnp.sum(fn(*a) * co), argnums=range(5))(*args)
-    for a, b_ in zip(got_g, want_g):
+        want = _out_and_grads(ref.delta_rule, args, co)
+    got = _out_and_grads(lambda *a: gated_delta_chunked(*a, chunk=chunk, dtype=jnp.dtype(dtype)), args, co)
+    for a, b_ in zip(got, want):
         close(a, b_, tol)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", TIGHT), ("bfloat16", LOOSE)])
+@pytest.mark.parametrize("key_heads", [1, 2])  # a key head serving two value heads, and one each
+def test_gdn_chunk_kernels_equal_recurrence_and_xla_form(monkeypatch, key_heads, dtype, tol):
+    """The chunk-local kernel pair (interpret mode, at a shape the gate
+    takes): output and all five gradients against the token recurrence at
+    ``highest`` and against the ``jax.numpy`` form from the same inputs."""
+    from dtc_tpu.ops import gated_delta as gd
+
+    args, co = _scan_inputs(1, 128, key_heads, 2, 128, 128)
+    rep = lambda a: jnp.repeat(a, 2 // key_heads, axis=2)  # noqa: E731
+    recurrence = lambda q, k, *a: ref.delta_rule(rep(q), rep(k), *a)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want = _out_and_grads(recurrence, args, co)
+
+    def scan():  # a new function each time: a trace is cached by the function
+        return lambda *a: gated_delta_chunked(*a, chunk=64, dtype=jnp.dtype(dtype))
+
+    assert "pallas_call" in str(jax.make_jaxpr(scan())(*args))
+    got = _out_and_grads(scan(), args, co)
+    monkeypatch.setattr(gd, "supports_chunk_kernel", lambda *a: None)
+    assert "pallas_call" not in str(jax.make_jaxpr(scan())(*args))
+    xla = _out_and_grads(scan(), args, co)
+    for a, b_, c in zip(got, want, xla):
+        close(a, b_, tol)
+        close(a, c, tol)
+
+
+def test_gdn_chunk_kernels_fast_forgetting_head_is_finite():
+    """g of about -30 a token: the chunk's decay underflows to 0, and every
+    ``exp`` in the kernels is still of a difference that is never positive."""
+    (q, k, v, g, beta), co = _scan_inputs(1, 128, 1, 2, 128, 128, seed=1)
+    g = g.at[..., 0].set(-30.0 + 0.1 * g[..., 0])
+    fn = lambda *a: gated_delta_chunked(*a, chunk=64, dtype=jnp.float32)  # noqa: E731
+    assert "pallas_call" in str(jax.make_jaxpr(fn)(q, k, v, g, beta))
+    got = _out_and_grads(fn, (q, k, v, g, beta), co)
+    assert all(bool(jnp.all(jnp.isfinite(a))) for a in got)
+    with jax.default_matmul_precision("highest"):
+        want = _out_and_grads(ref.delta_rule, (jnp.repeat(q, 2, 2), jnp.repeat(k, 2, 2), v, g, beta), co)
+    close(got[0], want[0], TIGHT)
+    close(got[3], want[3], TIGHT)
+
+
+def _aligned_keys(shape, seed=5):
+    """Unit keys that share a direction: (k_i . k_j) ~ 0.8 for every pair."""
+    noise = jax.random.normal(jax.random.PRNGKey(seed), shape)
+    k = jax.random.normal(jax.random.PRNGKey(seed + 1), shape[-1:]) + 0.5 * noise
+    return k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("c", [12, 64])
+def test_unit_lower_inverse_where_keys_align(c, dtype, tol):
+    """A chunk whose keys align and hardly decay: rows of ``|A|`` sum to 8
+    (c 12) and 46 (c 64) while no entry of the inverse passes 1. The blocked
+    inverse rounds nothing larger than the answer; the squaring product it
+    replaced read 2e11 off here in bfloat16 (3e6 in float32), and the
+    benchmark's cell went NaN on such chunks."""
+    from dtc_tpu.ops.gated_delta import unit_lower_inverse
+
+    k = _aligned_keys((c, 128))
+    a = jnp.tril(0.9 * (k @ k.T), -1)
+    want = np.linalg.inv(np.eye(c) + np.asarray(a, np.float64))
+    assert np.abs(a).sum(-1).max() > 0.6 * c and np.abs(want).max() <= 1.0
+    got = np.asarray(unit_lower_inverse(a, jnp.dtype(dtype)), np.float64)
+    assert np.max(np.abs(got - want)) <= tol
+
+
+@pytest.mark.parametrize("form", ["mosaic", "xla"])
+def test_gdn_aligned_keys_slow_decay_stay_with_the_recurrence(monkeypatch, form):
+    """The same chunks through the whole scan in bfloat16, kernel pair and
+    ``jax.numpy`` form: output and gradients finite and with the token
+    recurrence."""
+    from dtc_tpu.ops import gated_delta as gd
+
+    (q, _, v, g, beta), co = _scan_inputs(1, 128, 1, 2, 128, 128, seed=2)
+    args = (q, _aligned_keys((1, 128, 1, 128)), v, 0.01 * g, 0.5 + 0.5 * beta)
+    if form == "xla":
+        monkeypatch.setattr(gd, "supports_chunk_kernel", lambda *a: None)
+    fn = lambda *a: gated_delta_chunked(*a, chunk=64, dtype=jnp.bfloat16)  # noqa: E731
+    assert ("pallas_call" in str(jax.make_jaxpr(fn)(*args))) == (form == "mosaic")
+    got = _out_and_grads(fn, args, co)
+    with jax.default_matmul_precision("highest"):
+        want = _out_and_grads(lambda q, k, *a: ref.delta_rule(jnp.repeat(q, 2, 2), jnp.repeat(k, 2, 2), *a), args, co)
+    for a, b_ in zip(got, want):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        close(a, b_, LOOSE)
+
+
+@pytest.mark.parametrize("chunk,dk,dv,hv,hk,takes", [
+    (64, 128, 128, 32, 16, True),    # the benchmark's cell
+    (64, 128, 128, 2, 1, True),
+    (64, 16, 16, 4, 2, False),       # toy widths: not a lane tile
+    (64, 128, 64, 4, 2, False),
+    (12, 128, 128, 4, 2, False),     # a chunk off the sublane count
+    (64, 128, 128, 12, 8, False),    # key heads that do not divide the value heads
+    (64, 128, 128, 100, 2, False),   # no 8 heads a step, and all 100 are over the budget
+])
+def test_gdn_chunk_kernel_gate(chunk, dk, dv, hv, hk, takes):
+    """The gate asks the planner; where it refuses, the ``jax.numpy`` form
+    runs and gives the recurrence's values."""
+    from dtc_tpu.ops import vmem
+    from dtc_tpu.ops.gated_delta import supports_chunk_kernel
+
+    plan = supports_chunk_kernel(chunk, dk, dv, hv, hk)
+    assert (plan is not None) == takes
+    if takes:
+        assert plan == vmem.gdn_chunk_plan(chunk, dk, dv, hv, hk) and hv % plan["tiles"] == 0
+        for leg in ("fwd", "bwd"):
+            assert plan[leg]["bytes"] <= vmem.VMEM_BUDGET_BYTES
+            assert plan[leg]["vmem_limit_bytes"] > plan[leg]["bytes"] + plan[leg]["modeled_transient_bytes"]
+    elif hv <= 4:
+        args, _ = _scan_inputs(1, 2 * chunk, hk, hv, dk, dv)
+        fn = lambda *a: gated_delta_chunked(*a, chunk=chunk, dtype=jnp.float32)  # noqa: E731
+        assert "pallas_call" not in str(jax.make_jaxpr(fn)(*args))
+        with jax.default_matmul_precision("highest"):
+            want = ref.delta_rule(*(jnp.repeat(a, hv // hk, 2) for a in args[:2]), *args[2:])
+        close(fn(*args), want, TIGHT)
+
+
+def test_layer_plan_names_the_chunk_local_implementation(cfg):
+    """``layer_plan``'s ``gdn`` entry: the kernel pair with its tile and
+    ``vmem_limit_bytes`` at the benchmark cell's widths, the ``jax.numpy``
+    form at the toy's."""
+    with open(os.path.join(REPO, "benchmark", "configs", "qwen3-next-80b-a3b.json")) as f:
+        cell = ModelConfig(**json.load(f)["model"])
+    gdn = pattern.layer_plan(cell)["gdn"]
+    assert gdn["kernel"] == "mosaic" and gdn["tile"] == [8, cell.gdn_chunk]
+    assert set(gdn["vmem_limit_bytes"]) == {"fwd", "bwd"}
+    assert all(16 * 2**20 <= v < 32 * 2**20 for v in gdn["vmem_limit_bytes"].values())
+    toy = pattern.layer_plan(cfg)["gdn"]
+    assert toy["kernel"] == "xla" and "tile" not in toy and "vmem_limit_bytes" not in toy
 
 
 # ---------------------------------------------------------------------------
@@ -354,6 +490,7 @@ def test_trainer_runs_three_steps_from_yaml_files(tmp_path, cfg):
     by_type = {e["etype"]: e for e in events}
     assert by_type["layer_plan"]["pattern"] == list(cfg.layer_pattern)
     assert by_type["layer_plan"]["gdn"]["chunks"] == 2 and by_type["layer_plan"]["gdn"]["chunk"] == 64
+    assert by_type["layer_plan"]["gdn"]["kernel"] == "xla"  # key / value width 16: no lane tile
     assert by_type["moe_plan"]["experts_held"] == 4 and by_type["moe_plan"]["experts_published"] == 8
     counted = [e for e in events if e["etype"] == "moe_counters"]
     assert [e["step"] for e in counted] == [1, 2, 3]
