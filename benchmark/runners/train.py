@@ -8,8 +8,9 @@ length of the one ``train`` call:
 
 - ``init_state``: the program builds its state as always; the parameters are
   then replaced, in their own shardings, by the benchmark's weights made on
-  the device from ``--seed`` in one jitted call (``reference.make_weights``,
-  the call the plain reference makes too).
+  the device from ``--seed`` (or the workload's ``weights_seed``) in one
+  jitted call (``reference.make_weights``, the call the plain reference
+  makes too).
 - ``create_train_step``: the compiled step the program builds is wrapped by
   a recorder. The trainer's warm-up steps are the set-up's first steps: they
   go through the loop's own call and feed, on rows that all differ, and the
@@ -36,6 +37,7 @@ import time
 from typing import Any
 
 import numpy as np
+import yaml
 
 import compare
 import reference
@@ -197,6 +199,13 @@ def _seams(model: dict, seed: int, seconds: float, box: dict):
         trainer.init_state, trainer.create_train_step = real_init, real_create
 
 
+def weights_seed(workload: dict, seed: int) -> int:
+    """The seed of a run's weights: ``--seed``, or the workload's
+    ``weights_seed`` where it names one checkpoint for every run to start
+    from (the rows stay ``--seed``'s)."""
+    return int(workload.get("weights_seed", seed))
+
+
 def build_configs(cell) -> tuple[dict, dict, dict]:
     """The program's three configuration mappings for this cell."""
     wl = cell.workload
@@ -219,9 +228,10 @@ def _load_program_configs(cell):
     train, model, optim = build_configs(cell)
     paths = []
     for name, data in (("train", train), ("model", model), ("optim", optim)):
-        path = os.path.join(cell.out_dir, f"{name}_config.yaml")  # JSON is YAML
+        path = os.path.join(cell.out_dir, f"{name}_config.yaml")
         with open(path, "w") as f:
-            json.dump(data, f, indent=1)
+            # not json.dump: it writes 1e-05, which YAML 1.1 reads as a string
+            yaml.safe_dump(data, f)
         paths.append(path)
     return load_config(*paths), model, optim
 
@@ -266,7 +276,7 @@ def drive(cell) -> dict:
     feed = traffic_mod.token_rows(wl["traffic"], model["vocab_size"], seq_len, cell.seed)
     box: dict = {}
     t_train = time.perf_counter()
-    with _seams(model, cell.seed, cell.seconds, box):
+    with _seams(model, weights_seed(wl, cell.seed), cell.seconds, box):
         result = trainer.train(train_cfg, model_cfg, opt_cfg, host_iterator=feed)
     rec: Recorder = box["recorder"]
     step_ends = [float(t) for t in result.elapsed_times]
@@ -303,7 +313,7 @@ def follow(run: dict, **how) -> dict:
     batches = [traffic_mod.token_rows_at(wl["traffic"], model["vocab_size"],
                                          model["max_seq_len"], run["seed"], i)
                for i in range(SETUP_STEPS)]
-    return reference.run_steps(model, run["optim"], run["seed"], batches,
+    return reference.run_steps(model, run["optim"], weights_seed(wl, run["seed"]), batches,
                                devices=jax.devices()[:run["chips"]], **how)
 
 

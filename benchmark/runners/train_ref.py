@@ -200,7 +200,7 @@ def drive(cell) -> dict:
     feed = traffic_mod.token_rows(wl["traffic"], model["vocab_size"], seq_len, cell.seed)
     box: dict = {}
     t_train = time.perf_counter()
-    with _seams(ref, names, model, cell.seed, cell.seconds, box):
+    with _seams(ref, names, model, base.weights_seed(wl, cell.seed), cell.seconds, box):
         result = trainer.train(train_cfg, model_cfg, opt_cfg, host_iterator=feed)
     rec: Recorder = box["recorder"]
     step_ends = [float(t) for t in result.elapsed_times]
@@ -238,7 +238,7 @@ def follow(run: dict, **how) -> dict:
     batches = [traffic_mod.token_rows_at(wl["traffic"], model["vocab_size"],
                                          model["max_seq_len"], run["seed"], i)
                for i in range(SETUP_STEPS)]
-    return ref.run_steps(model, run["optim"], run["seed"], batches,
+    return ref.run_steps(model, run["optim"], base.weights_seed(wl, run["seed"]), batches,
                          devices=jax.devices()[:run["chips"]], **how)
 
 

@@ -61,10 +61,9 @@ import functools
 import gzip
 from dataclasses import dataclass
 
-from xtrace import (HOST, MODULES_LINE, OPS_LINE, Row, base_op, find_xplane, is_collective,
-                    op_rows, subtract, total, union)
+from xtrace import (HOST, MODULES_LINE, OPS_LINE, SPAN, Row, base_op, charge, find_xplane,
+                    is_collective, op_rows, subtract, total, union)
 
-SPAN = "train."
 #: The runtime's own host events the alignment reads, by name.
 ENQUEUE = "tpu::System::Execute"
 NOTICES = ("ReadSyncFlag", "tpu::System::Execute=>Done")
@@ -268,21 +267,6 @@ def shift_bounds(execs: list[Row], host: list[Span]) -> tuple[float, float] | No
         notice = max(seen) if seen else block.t0 + block.dur
         hi = min(hi, notice - (ex.t0 + ex.dur))
     return lo, hi
-
-
-def charge(pieces: list[tuple[float, float]], spans: list[Span]) -> dict[str, float]:
-    """Seconds of ``pieces`` (host clock) under each phase: every piece is
-    cut at the spans' borders and goes to the innermost (shortest) span over
-    it, under its phase's name; to ``""`` where no span is."""
-    out: dict[str, float] = {}
-    for lo, hi in pieces:
-        cuts = sorted({lo, hi, *(t for s in spans for t in (s.t0, s.t0 + s.dur) if lo < t < hi)})
-        for a, b in zip(cuts, cuts[1:]):
-            mid = 0.5 * (a + b)
-            over = [s for s in spans if s.t0 <= mid < s.t0 + s.dur]
-            name = min(over, key=lambda s: s.dur).name[len(SPAN):] if over else ""
-            out[name] = out.get(name, 0.0) + (b - a)
-    return out
 
 
 # ---- rows to metrics ------------------------------------------------------

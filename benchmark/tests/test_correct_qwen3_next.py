@@ -76,6 +76,7 @@ def test_sound_run_is_correct_and_its_counters_are_read(tmp_path):
     read = lambda name: harness.load_module("metrics", name).read(run)  # noqa: E731
     assert read("recompiles.train") == 0 and read("moe_dropped.train") == 0
     assert 1.0 <= read("moe_load_max_over_mean.train") < 4.0
+    assert 0.0 < read("moe_held_swing_pct.train") < 20.0  # the scatter of 8 rows x 128 tokens
     assert read("gdn_ms.train") is None          # no trace: nothing to read, nothing raised
     assert read("full_attn_roofline.train") is None
 
@@ -98,4 +99,7 @@ def test_control_and_faults_are_not_correct(how):
     ok, checks = _follow(**how)
     assert not ok, checks
     if "frozen" in how:
-        assert checks["dparam3"]["value"] == pytest.approx(1.0)
+        # not exactly 1 at lr 1e-05: the comparison makes the first weights
+        # again in a program of its own, an ulp apart on a few elements,
+        # against a reference change of 3e-5 (0.99997 here; the limit is 0.08)
+        assert checks["dparam3"]["value"] == pytest.approx(1.0, abs=1e-3)

@@ -1,6 +1,7 @@
 """The harness is data; the yardstick agrees with what it copies; the run
 refuses a machine without a chip."""
 
+import importlib
 import json
 import os
 import subprocess
@@ -84,6 +85,66 @@ def test_benchmark_json_keeps_to_the_contract():
     exposed = next(m for m in bench["per_layer"] if m["name"].startswith("collective_exposed"))
     assert all(next(w for w in bench["workloads"] if w["name"] == n)["chips"] == 4
                for n in exposed["workloads"])
+
+
+@pytest.mark.parametrize("totals, expected", [
+    ([40960.0] * 5, 0.0),                       # the routers stand still
+    ([40000.0, 45000.0, 50000.0, 55000.0, 60000.0], 40.0),  # 20 k of wander around 50 k
+    ([], None),                                 # a GPT-2 cell: no such event, nothing to read
+], ids=["flat", "40k_to_60k", "no_events"])
+def test_held_swing_reads_how_far_the_routers_moved(totals, expected):
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = next(m for m in bench["per_layer"] if m["name"] == "moe_held_swing_pct.train")
+    assert set(entry["workloads"]) <= {w["name"] for w in bench["workloads"]}
+    events = [{"etype": "step", "step": 1}]
+    events += [{"etype": "moe_counters", "step": i, "moe_assigned_held": [t / 4] * 4}
+               for i, t in enumerate(totals, 1)]
+    got = harness.load_module("metrics", entry["name"]).read({"events": events})
+    assert got == (None if expected is None else pytest.approx(expected))
+
+
+@pytest.mark.parametrize("cell, expected", [
+    ("gpt2-medium.train-b8", 2**31 + 5),                          # no checkpoint named: the weights are --seed's
+    ("qwen3-next-80b-a3b.train-ep16share-b2x8192", 20261002),     # one checkpoint for every run; the rows stay --seed's
+], ids=["from_the_seed", "one_checkpoint"])
+def test_weights_seed_is_the_workloads_or_the_runs(cell, expected, monkeypatch):
+    """Both runners make the program's weights and the reference's from the
+    same seed: ``drive`` and ``follow`` ask the one function."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        _, _, workload = harness.load_cell(json.load(f), cell)
+    runner = harness.load_module("runners", workload["runner"])
+    base = getattr(runner, "base", runner)
+    assert base.weights_seed(workload, 2**31 + 5) == expected
+    ref = importlib.import_module(workload["runner"] == "train_ref" and "reference_qwen3_next" or "reference")
+    seen = []
+    monkeypatch.setattr(ref, "run_steps", lambda model, optim, seed, batches, **kw: seen.append(seed))
+    model = {"vocab_size": 50, "max_seq_len": 8}
+    runner.follow({"workload": workload, "model": model, "seed": 2**31 + 5, "chips": 1, "optim": {},
+                   "reference_module": "reference_qwen3_next"})
+    assert seen == [expected]
+
+
+@pytest.mark.parametrize("steps_ms, traced, expected", [
+    ([550.0] * 40, None, 0.0),                               # every step on one level
+    ([550.0] * 37 + [553.7] * 3, None, 100 * 3.7 / 550),     # the p95 rests on three steps a level up
+    ([550.0] * 38 + [553.7] * 2, None, 0.0),                 # two such steps: the third-longest is not one
+    ([550.0] * 4 + [700.0, 550.0, 9000.0] + [550.0] * 33, [5, 7], 0.0),  # the profiler's own steps are left out
+    ([550.0, 551.0], None, None),                            # too few steps to have a tail
+], ids=["one_level", "three_steps_up", "two_steps_up", "profiled_steps_left_out", "two_steps"])
+def test_p95_over_median_reads_the_steps_that_pay_more(steps_ms, traced, expected):
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = next(m for m in bench["per_layer"] if m["name"] == "step_p95_over_median_pct.train")
+    assert entry["moves"] == "train_step_ms_p95" and "workloads" not in entry
+    ends, t = [], 0.0
+    for ms in steps_ms:
+        t += ms / 1e3
+        ends.append(t)
+    run = {"step_ends": ends, "profile_dir": "somewhere" if traced else None,
+           "workload": {"trace_steps": traced}}
+    got = harness.load_module("metrics", entry["name"]).read(run)
+    assert got == (None if expected is None else pytest.approx(expected, abs=1e-9))
 
 
 def test_new_files_need_no_edit(tmp_path):

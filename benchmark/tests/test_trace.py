@@ -6,7 +6,7 @@ import os
 import pytest
 
 import xtrace
-from toy import ROOT
+from toy import BENCH, ROOT
 from xtrace import HOST, OPS_LINE, Row
 
 
@@ -54,6 +54,47 @@ def test_idle_gap_is_named_by_the_launching_thread():
     (name, dur), = xtrace.reduce_rows(r)["breakdown"]["idle_gaps"]
     assert dur == pytest.approx(2.0)
     assert name == "api.py:1 block_until_ready < trainer.py:1 _train"
+
+
+def test_idle_gap_is_named_by_the_programs_span_over_it():
+    """Where the trace holds the loop's ``train.*`` spans, a gap takes the
+    name of the phase that holds most of it, not a Python frame's and not
+    the whole step's ``train``."""
+    r = [Row(0, OPS_LINE, "%fusion.1 = f32[] fusion()", 0.0, 1.0),
+         Row(0, OPS_LINE, "%fusion.2 = f32[] fusion()", 3.0, 1.0),
+         Row(HOST, "python#2", "train", 0.0, 9.0),
+         Row(HOST, "python#2", "train.block", 0.2, 2.0),        # 1.2 s of the gap
+         Row(HOST, "python#2", "train.dispatch", 2.2, 0.9),     # 0.1 s of its own
+         Row(HOST, "python#2", "train.rng", 2.2, 0.5),
+         Row(HOST, "python#2", "train.launch", 2.8, 0.3),
+         Row(HOST, "python#2", "$api.py:1 block_until_ready", 0.9, 2.3),
+         Row(HOST, "python#2", "PjitFunction(step)", 2.8, 0.1)]
+    (name, dur), = xtrace.reduce_rows(r)["breakdown"]["idle_gaps"]
+    assert (name, dur) == ("train.block", pytest.approx(2.0))
+
+
+def test_recorded_chip_trace_names_its_gaps_under_python3(tmp_path):
+    """The recorded v5e trace was taken under ``python3``: its host line is
+    called so. Every gap is named by a phase of the loop, and the device
+    figures are what they were when host lines were looked for by name."""
+    import gzip
+
+    path = tmp_path / "t.xplane.pb"
+    with gzip.open(os.path.join(BENCH, "tests", "fixtures", "train-b8.v5e.xplane.pb.gz")) as f:
+        path.write_bytes(f.read())
+    rows = xtrace.rows_from_xplane(str(path))
+    assert {r.name for r in rows if r.device == HOST} >= {"train.block", "train.rng", "train.launch"}
+    t = xtrace.reduce_rows(rows)
+    gaps = t["breakdown"]["idle_gaps"]
+    assert gaps and all(name.startswith("train.") for name, _ in gaps)
+    assert {name for name, _ in gaps} >= {"train.rng", "train.block"}
+    # without its host rows (what a reader that wants a line called
+    # ``python`` sees here) only the gaps' names differ
+    bare = xtrace.reduce_rows([r for r in rows if r.device != HOST])
+    assert all(name.startswith("dev0: after ") for name, _ in bare["breakdown"]["idle_gaps"])
+    assert [d for _, d in gaps] == [d for _, d in bare["breakdown"]["idle_gaps"]]
+    t["breakdown"]["idle_gaps"] = bare["breakdown"]["idle_gaps"]
+    assert t == bare
 
 
 def test_interval_arithmetic():

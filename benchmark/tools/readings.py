@@ -1,6 +1,6 @@
 """The readings a cell's limits are set from, in one process on the chip:
 
-    python benchmark/tools/readings.py --workload <cell> --seeds 12 [--controls 3] [--out FILE]
+    python benchmark/tools/readings.py --workload <cell> --seeds 12 [--controls 3] [--draws] [--out FILE]
 
 For each seed: the program's set-up steps through the runner (a window of
 ``--seconds``, short: the readings need none), the float32 reference, and
@@ -36,12 +36,16 @@ def main() -> int:
     ap.add_argument("--controls", type=int, default=3)
     ap.add_argument("--seconds", type=float, default=1.0)
     ap.add_argument("--cpu", action="store_true", help="rehearsal: skip the look for a chip")
+    ap.add_argument("--draws", action="store_true",
+                    help="each seed draws its weights too: a workload's weights_seed is set aside")
     ap.add_argument("--out", default="")
     args = ap.parse_args()
 
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entry, config, workload = harness.load_cell(bench, args.workload)
+    if args.draws:
+        workload.pop("weights_seed", None)
     from dtc_tpu.utils.dist import configure_compile_cache
 
     configure_compile_cache()

@@ -65,12 +65,16 @@ def find_xplane(profile_dir: str) -> str | None:
 
 
 HOST = -1  # Row.device of a host thread's events
+SPAN = "train."  # the program's own host spans (``dtc_tpu/obs/stepclock.py``)
 
 
 def rows_from_xplane(path: str) -> list[Row]:
-    """Device rows of every ``/device:TPU:<n>`` plane, and the host's Python
-    frames (plane ``/host:CPU``, lines ``python``) as rows of device HOST:
-    they are on the same clock and name what the host did in an idle gap."""
+    """Device rows of every ``/device:TPU:<n>`` plane, and as rows of device
+    HOST the interpreter's threads of plane ``/host:CPU``: every line that
+    holds one of the program's ``train.*`` spans or a Python frame, whatever
+    the line is called (the thread's name is the interpreter's: ``python``,
+    or ``python3`` under the driver). They are on the same clock and name
+    what the host did in an idle gap."""
     from jax.profiler import ProfileData
 
     rows = []
@@ -82,11 +86,11 @@ def rows_from_xplane(path: str) -> list[Row]:
         else:
             continue
         for i, line in enumerate(plane.lines):
-            if dev == HOST and line.name != "python":
+            events = [(e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9) for e in line.events]
+            if dev == HOST and not any(n.startswith((SPAN, "$", "PjitFunction(")) for n, _, _ in events):
                 continue
             name = f"python#{i}" if dev == HOST else line.name
-            for e in line.events:
-                rows.append(Row(dev, name, e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9))
+            rows += [Row(dev, name, n, t0, dur) for n, t0, dur in events]
     return rows
 
 
@@ -103,10 +107,36 @@ def main_thread(host: list[Row]) -> list[Row]:
     return [r for r in host if r.line == line]
 
 
+def charge(pieces: list[tuple[float, float]], spans: list[Row]) -> dict[str, float]:
+    """Seconds of ``pieces`` (host clock) under each phase: every piece is
+    cut at the spans' borders and goes to the innermost (shortest) span over
+    it, under its phase's name; to ``""`` where no span is."""
+    out: dict[str, float] = {}
+    for lo, hi in pieces:
+        cuts = sorted({lo, hi, *(t for s in spans for t in (s.t0, s.t0 + s.dur) if lo < t < hi)})
+        for a, b in zip(cuts, cuts[1:]):
+            mid = 0.5 * (a + b)
+            over = [s for s in spans if s.t0 <= mid < s.t0 + s.dur]
+            name = min(over, key=lambda s: s.dur).name[len(SPAN):] if over else ""
+            out[name] = out.get(name, 0.0) + (b - a)
+    return out
+
+
 def host_name_for(gap: tuple[float, float], host: list[Row]) -> str | None:
-    """What the host was doing in ``gap``: of the Python frames that cover
-    at least half of it, the two shortest, inner < outer."""
+    """What the host was doing in ``gap``: the program's ``train.<phase>``
+    span that holds most of it (the innermost span at each instant, as
+    ``spans.py`` charges the ``idle_*`` rows); where no such span is over it,
+    of the Python frames that cover at least half of it the two shortest,
+    inner < outer. The gap is taken where the device's clock puts it:
+    ``spans.py`` first shifts the device's timeline by the skew it finds
+    (0.04 to 1.3 ms), so a gap that reaches over a span's border by less
+    than that may carry the neighbouring phase's name here. The ``idle_*``
+    rows are the measure; these names point at the longest gaps."""
     lo, hi = gap
+    held = charge([gap], [r for r in host if r.name.startswith(SPAN)])
+    held.pop("", None)
+    if held:
+        return SPAN + max(held, key=held.get)
     over = [r for r in host if min(hi, r.t0 + r.dur) - max(lo, r.t0) >= 0.5 * (hi - lo)]
     over.sort(key=lambda r: r.dur)
     return " < ".join(r.name.lstrip("$") for r in over[:2]) or None
