@@ -79,6 +79,7 @@ from __future__ import annotations
 
 import ast
 import contextlib
+import dataclasses
 import json
 import os
 from typing import Any, Iterable, Iterator
@@ -96,7 +97,16 @@ _CONFIG_DIR = os.path.join(_REPO_ROOT, "configs")
 #: The audited ladder rungs: the measured flagship plus the two
 #: static-audit-only scale points (no training run — the point is to
 #: certify the kernel plans BEFORE a chip is spent on them).
-LADDER_RUNGS = ("flagship", "ladder_350m", "ladder_1b")
+#: Each maps to its file under configs/ and the fields the audit sets over
+#: it: the flagship is the model configs/model_config.yaml trains, priced
+#: at its serving deployment (megakernel decode, remat on, no dropout).
+LADDER_RUNGS = {
+    "flagship": ("model_config.yaml", {
+        "dropout": 0.0, "remat": True, "decode_attention": "fused_layers",
+    }),
+    "ladder_350m": ("model_ladder_350m.yaml", {}),
+    "ladder_1b": ("model_ladder_1b.yaml", {}),
+}
 
 #: ops/ modules allowed to launch a pallas_call without consulting the
 #: shared VMEM planner, with the reason (emitted as an info finding so
@@ -643,24 +653,14 @@ def lint_gate_coverage(
 
 
 def rung_config(name: str):
-    """The ModelConfig of one ladder rung. ``flagship`` is built from
-    the ONE bench definition (bench.flagship_model_cfg) at its serving
-    deployment (megakernel decode); the ladder rungs load from
-    configs/model_ladder_*.yaml."""
-    if name == "flagship":
-        import dataclasses
-
-        from bench import flagship_model_cfg
-
-        return dataclasses.replace(
-            flagship_model_cfg(dropout=0.0),
-            decode_attention="fused_layers",
-        )
+    """The ModelConfig of one ladder rung: its file under configs/ with
+    the overrides :data:`LADDER_RUNGS` states."""
     from dtc_tpu.config.loader import load_yaml_dataclass
     from dtc_tpu.config.schema import ModelConfig
 
-    path = os.path.join(_CONFIG_DIR, f"model_{name}.yaml")
-    return load_yaml_dataclass(path, ModelConfig)
+    filename, overrides = LADDER_RUNGS[name]
+    cfg = load_yaml_dataclass(os.path.join(_CONFIG_DIR, filename), ModelConfig)
+    return dataclasses.replace(cfg, **overrides)
 
 
 #: The deployment shape all rung plans are priced at: the 8-device ring

@@ -137,8 +137,7 @@ class ModelConfig:
     # grows with E), "sort" = slot-permutation + segment gathers
     # (MegaBlocks-style, O(B·T·k·d) data movement at any E). Routing
     # numerics are identical — this is a pure execution-strategy A/B
-    # (bench.py MoE rows measure both; einsum stays default until the
-    # on-chip A/B says otherwise, PERF.md).
+    # (einsum stays default until an on-chip A/B says otherwise, PERF.md).
     moe_dispatch: str = "einsum"
     # Decode (KV-cache inference) attention backend: "fused_layers" = ONE
     # Pallas launch per TOKEN that scans the layer axis inside the kernel

@@ -247,8 +247,7 @@ def generate(
     ``tracer`` (an :class:`dtc_tpu.obs.trace.Tracer`) wraps the whole
     compiled call in one ``generate`` span — the prefill+scan is a
     single jit, so finer host-side splits would be fiction; per-token
-    attribution lives in the serving engine's iteration spans and
-    ``scripts/profile_step.py --decode``."""
+    attribution lives in the serving engine's iteration spans."""
     if getattr(model.cfg, "layer_pattern", ()):
         from dtc_tpu.models.pattern import NOT_SERVED
 

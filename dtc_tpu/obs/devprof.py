@@ -3,8 +3,8 @@
 PR 7 answered "where did the host wall-clock go" with span timelines; this
 module adds the device-side leg so the two merge into one Perfetto view and
 device-time attribution becomes a programmatic, regression-gated metric
-instead of a hand-driven ``scripts/profile_step.py`` round transcribed into
-PERF.md by a human. Three layers:
+instead of a hand-driven profiling round transcribed into PERF.md by a
+human. Three layers:
 
 - **Parser** — backend-free (pure string/JSON processing, no JAX imports at
   module level) reader of the profiler's ``*.trace.json.gz`` output into
@@ -13,9 +13,8 @@ PERF.md by a human. Three layers:
   processes (``/device:TPU:N`` pids — the PERF.md methodology) with a CPU
   fallback (the TFRT CPU backend has no device pid; its XLA op events carry
   an ``hlo_op`` arg instead). Umbrella events (``jit_*`` module spans, bare
-  step-number markers) are skipped on device pids exactly as
-  ``profile_step.parse`` always did — they nest the real op events and
-  would double-count.
+  step-number markers) are skipped on device pids — they nest the real
+  op events and would double-count.
 
 - **Attribution** — rolls op durations up to model components (embed /
   attn_qkv / attn_kernel / attn_proj / mlp-or-moe / ln / head) and phases
@@ -136,8 +135,8 @@ def trace_process_names(events: list[dict[str, Any]]) -> dict[int, str]:
 
 
 def device_pids(events: list[dict[str, Any]]) -> set[int]:
-    """Processes whose events are DEVICE op executions — the selection
-    ``profile_step.parse`` has always used (TPU device streams)."""
+    """Processes whose events are DEVICE op executions (TPU device
+    streams)."""
     return {
         p for p, n in trace_process_names(events).items()
         if "TPU" in n or "/device" in n.lower()
@@ -459,9 +458,8 @@ def self_times(rows: list[OpRow]) -> list[float]:
     inside it on the same (pid, tid) line.
 
     Trace lines nest — a ``while`` loop op wraps every op its body
-    executes, a ``call`` wraps the callee's thunks (the old
-    ``profile_step.parse`` NOTE: "rows are NOT additive"). Attribution
-    needs ADDITIVE numbers, so each event's immediate children are
+    executes, a ``call`` wraps the callee's thunks: raw rows are NOT
+    additive. Attribution needs ADDITIVE numbers, so each event's immediate children are
     subtracted from it; parents of fully-traced children end up with
     just their own overhead."""
     order = sorted(range(len(rows)), key=lambda i: (

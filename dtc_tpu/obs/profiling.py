@@ -1,8 +1,6 @@
 """Profiler trace capture around a training-step window (hardened).
 
-Moved from ``dtc_tpu/utils/profiling.py`` into the obs subsystem; the old
-import path re-exports this class. Two failure modes that used to kill a
-run now warn-and-disable instead:
+Two failure modes that would kill a run warn-and-disable instead:
 
 - a profiler session already active in the process (an outer harness, a
   previous run that leaked its session) — ``start_trace`` raises;

@@ -72,9 +72,8 @@ class HistogramLayoutError(ValueError):
 class Histogram:
     """Streaming summary with fixed log-bucketed quantiles.
 
-    Originally count/sum/min/max only — which could not answer the
-    p50/p99 questions the serving SLOs are phrased in, forcing bench.py
-    to hold private per-request sample lists. Observations now also land
+    Count/sum/min/max alone cannot answer the p50/p99 questions the
+    serving SLOs are phrased in. Observations also land
     in log-spaced buckets (relative width ``HIST_BUCKET_GROWTH``-1 ≈ 10%,
     O(hundreds) of buckets over the microsecond..hour range, O(1) per
     observe), so ``percentile(q)`` answers within one bucket width of the
@@ -295,7 +294,7 @@ class CsvSink:
 
 
 class MemorySink:
-    """Collect events in a list — bench.py and tests read results back
+    """Collect events in a list — callers and tests read results back
     without touching the filesystem."""
 
     def __init__(self):

@@ -1,8 +1,8 @@
 """Online SLO monitor: objectives evaluated DURING the run (ISSUE 7).
 
-Before this module the repo's SLO story was post-hoc: bench.py computed
-p99s after the run ended, so an operator found out a latency objective
-was blown "at bench time". The monitor moves that to "at iteration k":
+A p99 computed after the run ended tells an operator that a latency
+objective was blown only once it is over. The monitor moves that to "at
+iteration k":
 configurable objectives (TTFT p99, ms/token p99, queue-wait p99, shed
 rate for serving; step-time / data-wait p99 for training) are evaluated
 over sliding sample windows at the runtime's own cadence and breaches

@@ -539,10 +539,9 @@ class Telemetry:
         return True
 
     def request_device_profile(self, reason: str = "on_demand") -> bool:
-        """Arm an on-demand devprof capture window at the next step —
-        the programmatic replacement for hand-driving
-        ``scripts/profile_step.py`` against a live run. False when the
-        observatory is off, disabled, or already capturing/pending."""
+        """Arm an on-demand devprof capture window at the next step of
+        a live run. False when the observatory is off, disabled, or
+        already capturing/pending."""
         if self.devprof is None:
             return False
         return self.devprof.request(reason)

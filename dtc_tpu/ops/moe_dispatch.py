@@ -31,8 +31,7 @@ contiguous per-expert token blocks: a blocked matmul), and carry the same
 ``parallel/sharding.py``) and the all-to-all it induces hold for either.
 
 Everything here is pure jnp — unit-tested against a brute-force per-token
-reference in ``tests/test_moe.py`` and A/B-benched in ``bench.py`` /
-``scripts/sweep_moe.py``.
+reference in ``tests/test_moe.py``.
 """
 
 from __future__ import annotations

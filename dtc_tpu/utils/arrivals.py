@@ -1,15 +1,11 @@
-"""Seeded open-loop arrival generation, shared by bench and pool.
+"""Seeded open-loop arrival generation for the pool.
 
-Extracted from ``bench.py``'s serve/fleet rows (ISSUE 17): the seeded
-Poisson arrival schedule and the seeded prompt set were duplicated
-per-bench, and the pool's chaos spike needs the exact same request
-material — one generator means a bench row, a pool smoke, and a chaos
-drill all draw from the same distribution and a seed reproduces any of
-them bit-for-bit.
+One generator for the seeded Poisson arrival schedule and the seeded
+prompt set means a pool smoke and a chaos drill draw from the same
+distribution and a seed reproduces either bit-for-bit.
 
 The draw ORDER is part of the contract: arrivals first, then prompts,
-from one ``np.random.RandomState(seed)`` — the order the benches have
-always used, so extracting the helper changes no committed BENCH row.
+from one ``np.random.RandomState(seed)``.
 """
 
 from __future__ import annotations

@@ -32,8 +32,8 @@ COMPILE_CACHE_DIR = os.path.join(
 
 def configure_compile_cache() -> None:
     """Turn on JAX's persistent compilation cache; call before the first
-    compile (``main.py``, ``bench.py``, ``chip_smoke.py`` do, first
-    thing). Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
+    compile (``main.py``, ``chip_smoke.py`` and ``benchmark/run.py`` do,
+    first thing). Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
     itself and nothing is configured here, so the cache can be placed
     from outside; otherwise it lives at :data:`COMPILE_CACHE_DIR`."""
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):

@@ -1,12 +1,11 @@
-"""Nearest-rank percentile — THE percentile definition shared by bench
-rows, the trace analyzer, and the quantile-histogram parity tests.
+"""Nearest-rank percentile — THE percentile definition shared by the
+trace analyzer, the reports, and the quantile-histogram parity tests.
 
-Extracted from bench.py's private ``_pct`` (ISSUE 7 satellite): three
-call sites had started growing their own copies, and the registry
-histogram's bucketed p50/p99 needs one exact oracle to be tested
-against. Nearest-rank (no interpolation) is deliberate: for the small
-samples serving benches produce (tens of requests), interpolated
-percentiles manufacture values nobody measured.
+One definition because call sites had started growing their own copies,
+and the registry histogram's bucketed p50/p99 needs one exact oracle to
+be tested against. Nearest-rank (no interpolation) is deliberate: for
+the small samples a serving run produces (tens of requests),
+interpolated percentiles manufacture values nobody measured.
 """
 
 from __future__ import annotations

@@ -87,7 +87,7 @@ train(train_cfg, model_cfg, opt_cfg)
 def run_tpu_flagship(steps: int) -> None:
     """Flagship GPT-89.6M on the attached TPU chip, at the tuned round-4/5
     configuration (batch 32, ``remat="block_save_flash"``, fused head-CE,
-    rbg dropout — the bench.py ``tuned_b32_remat`` config, MFU 0.42).
+    rbg dropout).
     Rows at log_every boundaries (and the final total) are device-synced
     times; intermediate rows are dispatch stamps (see sync_every_step
     below)."""

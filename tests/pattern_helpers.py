@@ -1,0 +1,120 @@
+"""What the three files of pattern-model tests share (``test_pattern_model.py``,
+``test_pattern_ops.py``, ``test_pattern_parallel.py``).
+
+Everything is compared with ``benchmark/reference_qwen3_next.py`` (float32
+``jax.numpy``, Gated DeltaNet as the token recurrence, dense attention,
+experts as a loop), loaded by path — there is no second copy — on weights
+from its own ``make_weights``, at a toy size: d 64, 2 key / 4 value heads
+of 16, 2 query heads on 1 KV head of 32 with 8 rotary dims, 8 experts
+top-2 of width 32, vocabulary 256.
+
+Tolerances. float32 ``tight``: 2e-4 of the largest element — the two sides
+differ in summation order only (chunked matmuls against a recurrence, a
+sorted buffer against a loop; measured 1e-6 to 3e-5). bfloat16 ``loose``:
+4e-2 of the largest element, an 8-bit mantissa through a few matmuls
+(measured up to 1.5e-2); the routed sum is compared in float32 only, since
+a top-k choice that flips on a bf16 near-tie moves a whole expert's output.
+"""
+
+import dataclasses
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dtc_tpu.config.loader import load_yaml_dataclass
+from dtc_tpu.config.schema import ModelConfig
+from dtc_tpu.models import pattern
+from tests.conftest import make_train_cfg
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TOY_YAML = os.path.join(REPO, "configs", "model_config_pattern_dev.yaml")
+TIGHT, LOOSE = 2e-4, 4e-2
+
+
+def load_by_path(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = load_by_path(os.path.join(REPO, "benchmark", "reference_qwen3_next.py"), "reference_qwen3_next")
+with open(os.path.join(REPO, "benchmark", "configs", "qwen3-next-80b-a3b.json")) as f:
+    LEAF_NAMES = json.load(f)["leaf_names"]
+
+
+@pytest.fixture(scope="module")
+def cfg() -> ModelConfig:
+    return load_yaml_dataclass(TOY_YAML, ModelConfig)
+
+
+def as_model(cfg: ModelConfig) -> dict:
+    return {k: (list(v) if isinstance(v, tuple) else v) for k, v in dataclasses.asdict(cfg).items()}
+
+
+def weights(cfg: ModelConfig, seed: int = 3) -> dict:
+    with jax.default_matmul_precision("highest"):
+        return ref.make_weights(as_model(cfg), jnp.asarray(ref.seed_words(seed)))
+
+
+def program_params(w: dict) -> dict:
+    """The reference's leaves laid out as the program's parameter tree."""
+    tree: dict = {}
+    for path, name in LEAF_NAMES.items():
+        node = tree
+        *parents, leaf = path.split("/")
+        for p in parents:
+            node = node.setdefault(p, {})
+        node[leaf] = w[name]
+    return tree
+
+
+def layer_of(w: dict, position: int) -> tuple[dict, dict]:
+    """(program subtree, reference dict) of one layer, periods axis taken off."""
+    tree = program_params(w)["stage"]["periods"][f"layer_{position}"]
+    return (jax.tree.map(lambda a: a[0], tree),
+            {k: v[0] for k, v in ref.layer_params(w, position).items()})
+
+
+def close(got, want, tol):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert got.shape == want.shape
+    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want)), (
+        np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+def normed_input(cfg, seed=0, rows=2):
+    x = jax.random.normal(jax.random.PRNGKey(seed), (rows, cfg.max_seq_len, cfg.d_model))
+    return x / jnp.sqrt(jnp.mean(jnp.square(x), -1, keepdims=True))
+
+
+def one_device_steps(cfg, opt_cfg, batches, w=None):
+    """The program's own state and compiled step on a mesh of one device,
+    over ``batches`` ((rows, T + 1) arrays); with ``w`` the reference's
+    weights replace the program's draw. Returns the last step's outputs
+    and every loss."""
+    from dtc_tpu.parallel.mesh import build_mesh
+    from dtc_tpu.parallel.sharding import DEFAULT_RULES
+    from dtc_tpu.train.train_step import Batch, create_train_step
+    from dtc_tpu.train.trainer import init_state
+    from flax import linen as nn
+
+    mesh = build_mesh((1, 1, 1), devices=jax.devices()[:1])
+    model = pattern.build_model(cfg)
+    with mesh, nn.logical_axis_rules(DEFAULT_RULES):
+        state = init_state(model, cfg, make_train_cfg("dp", batch=batches[0].shape[0]), opt_cfg, mesh)
+        if w is not None:
+            state = state.replace(params=jax.tree.map(
+                lambda a, b: jnp.asarray(b, a.dtype), state.params, program_params(w)))
+        step = create_train_step(mesh, model=model, state=state)
+        losses = []
+        for batch in batches:
+            batch = jnp.asarray(batch)
+            state, loss, counters = step(state, Batch(x=batch[:, :-1], y=batch[:, 1:]), jax.random.PRNGKey(0))
+            losses.append(float(loss))
+    return state, losses, counters

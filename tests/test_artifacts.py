@@ -19,8 +19,12 @@ defenses now exist:
 from __future__ import annotations
 
 import csv
+import fnmatch
 import os
 import re
+import subprocess
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -71,146 +75,43 @@ def test_committed_logs_match_readme():
         )
 
 
-def _bench_file(path, detail: dict | None, malformed: bool = False) -> None:
-    """Write one committed-BENCH-shaped wrapper file (the real files wrap
-    the run's stdout tail; the detail dict rides the '# bench-detail:'
-    line — see bench._bench_detail)."""
-    import json
-
-    if malformed:
-        body = {"tail": ["not", "a", "string"]}
-    elif detail is None:
-        body = {"n": 1, "rc": 0, "tail": "no detail line here\n"}
-    else:
-        body = {"n": 1, "rc": 0, "tail": "# bench-detail: " + json.dumps(detail)}
-    with open(path, "w") as f:
-        json.dump(body, f)
+# `ops/attention.py` or ``main.py``; scripts/x.py and configs/x.yaml bare; BENCH_*.json.
+# Lower case only: this repo's files are, and the reference project's files that
+# comments also cite (`MLP.py`, `/root/reference/model/GPTModel.py`) are not ours to find.
+_NAMED_FILE = re.compile(
+    r"`(/?[a-z0-9_./*-]+\.py)`|\b(scripts/[\w*-]+\.py)|\b(configs/[\w*-]+\.yaml)|\b(BENCH_[\w*]+\.json)"
+)
 
 
-def test_decode_drift_guard_degrades_gracefully(tmp_path, capsys):
-    """ISSUE 5 satellite: the guard must warn — never raise, never flag —
-    when NO committed BENCH file carries decode rows, fall back past a
-    decode-less newest file to an older one that has them, and still
-    catch a real >20% ms/token regression against that fallback."""
-    from bench import decode_drift_guard
-
-    d = str(tmp_path)
-    run = {"decode_b8": {"ms_per_token": 10.0}, "devices": 1}
-
-    # No BENCH files at all: silent no-op.
-    assert decode_drift_guard(dict(run), d) == []
-
-    # Files exist but none carry decode rows (one malformed for good
-    # measure): warn, return [], raise nothing.
-    _bench_file(os.path.join(d, "BENCH_r01.json"), {"moe_e8": {"mfu": 0.3}})
-    _bench_file(os.path.join(d, "BENCH_r02.json"), None, malformed=True)
-    extra = dict(run)
-    assert decode_drift_guard(extra, d) == []
-    assert "no committed BENCH" in capsys.readouterr().out
-    assert "decode_regressions" not in extra
-
-    # An OLDER file gains decode rows; the newest still has none — the
-    # guard degrades to the newest file WITH rows instead of going blind.
-    _bench_file(
-        os.path.join(d, "BENCH_r01.json"),
-        {"decode_b8": {"ms_per_token": 5.0}},
-    )
-    extra = dict(run)  # 10.0 vs 5.0 = +100%: flag
-    flags = decode_drift_guard(extra, d)
-    assert len(flags) == 1 and "BENCH_r01.json" in flags[0]
-    assert extra["decode_regressions"] == flags
-
-    # Within the 20% band: clean.
-    extra = {"decode_b8": {"ms_per_token": 5.5}}
-    assert decode_drift_guard(extra, d) == []
+def _tracked() -> list[str]:
+    try:
+        out = subprocess.run(["git", "ls-files"], cwd=REPO, capture_output=True, text=True, check=True)
+        return out.stdout.split()
+    except (OSError, subprocess.CalledProcessError):  # a checkout without git holds tracked files only
+        return [os.path.relpath(os.path.join(d, f), REPO) for d, _, files in os.walk(REPO) for f in files]
 
 
-def test_decode_drift_guard_same_config_only(tmp_path):
-    """ISSUE 11 satellite: rows compare only when their
-    decode_attention/kv_cache_dtype labels match — a label re-pointed at
-    a different backend/cache dtype must not be judged against its old
-    self. Rows committed before the fields existed normalize to the
-    config they actually ran ("fused"/"auto")."""
-    from bench import decode_drift_guard
-
-    d = str(tmp_path)
-    _bench_file(
-        os.path.join(d, "BENCH_r01.json"),
-        {
-            "decode_b8": {"ms_per_token": 5.0},  # pre-ISSUE-11: no fields
-            "decode_b8_int8": {
-                "ms_per_token": 4.0, "decode_attention": "fused_layers",
-                "kv_cache_dtype": "int8",
-            },
-        },
-    )
-    # Same label, DIFFERENT config: not comparable — no flag despite 3x.
-    extra = {"decode_b8": {
-        "ms_per_token": 15.0, "decode_attention": "fused_layers",
-        "kv_cache_dtype": "auto",
-    }}
-    assert decode_drift_guard(extra, d) == []
-    # Same label, matching config (normalized old row): flags as before.
-    extra = {"decode_b8": {
-        "ms_per_token": 15.0, "decode_attention": "fused",
-        "kv_cache_dtype": "auto",
-    }}
-    assert len(decode_drift_guard(extra, d)) == 1
-    # int8 row vs its committed int8 self: matching explicit fields.
-    extra = {"decode_b8_int8": {
-        "ms_per_token": 9.0, "decode_attention": "fused_layers",
-        "kv_cache_dtype": "int8",
-    }}
-    assert len(decode_drift_guard(extra, d)) == 1
-
-
-def test_decode_drift_guard_spec_keys(tmp_path):
-    """ISSUE 19 satellite: the same-config rule gains the speculative
-    keys (spec_k / draft_layers / spec_acceptance) — a spec row's
-    ms-per-ACCEPTED-token must never be judged against a plain row's
-    sequential ms/token (or vice versa), and rows committed before
-    ISSUE 19 normalize to spec-off (spec_k 0 / draft_layers 0 /
-    acceptance "off"), the config they actually ran — the same
-    normalization pattern as ISSUE 11's kv_cache_dtype above."""
-    from bench import decode_drift_guard
-
-    d = str(tmp_path)
-    _bench_file(
-        os.path.join(d, "BENCH_r01.json"),
-        {
-            "decode_b8": {  # pre-ISSUE-19: no spec fields
-                "ms_per_token": 5.0, "decode_attention": "fused_layers",
-                "kv_cache_dtype": "auto",
-            },
-            "spec_b8_k4": {
-                "ms_per_accepted_token": 2.0,
-                "decode_attention": "fused_layers",
-                "kv_cache_dtype": "auto", "spec_k": 4, "draft_layers": 2,
-                "spec_acceptance": "greedy",
-            },
-        },
-    )
-    base = {
-        "decode_attention": "fused_layers", "kv_cache_dtype": "auto",
-    }
-    # A label re-pointed from plain to speculative: not comparable — no
-    # flag despite 3x (accepted-token ms is a different metric).
-    extra = {"decode_b8": dict(
-        base, ms_per_token=15.0, spec_k=4, draft_layers=2,
-        spec_acceptance="greedy",
-    )}
-    assert decode_drift_guard(extra, d) == []
-    # Spec-off run vs the normalized pre-ISSUE-19 row: still guarded.
-    extra = {"decode_b8": dict(
-        base, ms_per_token=15.0, spec_k=0, draft_layers=0,
-        spec_acceptance="off",
-    )}
-    assert len(decode_drift_guard(extra, d)) == 1
-    # Spec row vs its committed spec self (the spec_* family, guarded on
-    # ms-per-ACCEPTED-token): matching explicit keys flag; a different
-    # spec_k (2 vs 4) is a different config — silent.
-    spec = dict(base, spec_k=4, draft_layers=2, spec_acceptance="greedy")
-    extra = {"spec_b8_k4": dict(spec, ms_per_accepted_token=9.0)}
-    assert len(decode_drift_guard(extra, d)) == 1
-    extra = {"spec_b8_k4": dict(spec, ms_per_accepted_token=9.0, spec_k=2)}
-    assert decode_drift_guard(extra, d) == []
+@pytest.mark.parametrize("group", ["README.md", "configs", "dtc_tpu", "scripts"])
+def test_no_document_names_a_missing_file(group):
+    """Every ``<path>.py`` in backticks, ``scripts/<name>.py``,
+    ``configs/<name>.yaml`` and ``BENCH_*.json`` that a file of the group
+    names is a tracked file (whole path or its tail; a glob must match
+    one): an instruction to run a deleted script, or a record that cites a
+    deleted file, goes red. CHANGES.md, ROADMAP.md and PERF.md are history
+    and are not read."""
+    tracked = _tracked()
+    documents = [p for p in tracked if p == group
+                 or (p.startswith(group + "/") and p.endswith((".py", ".yaml", ".md", ".sh")))]
+    assert documents
+    missing = []
+    for doc in documents:
+        with open(os.path.join(REPO, doc), encoding="utf-8") as f:
+            for n, line in enumerate(f, 1):
+                for groups in _NAMED_FILE.findall(line):
+                    name = next(g for g in groups if g)
+                    if name.startswith("/"):  # an absolute path is outside the repo
+                        continue
+                    if not any(fnmatch.fnmatchcase(p, name) or fnmatch.fnmatchcase(p, "*/" + name)
+                               for p in tracked):
+                        missing.append(f"{doc}:{n} names {name}")
+    assert not missing, missing

@@ -11,11 +11,8 @@ capture->attribute pipeline is exercised by ``scripts/devprof_smoke.py``
 """
 
 import glob
-import importlib
 import json
 import os
-import sys
-import warnings
 
 import pytest
 
@@ -261,30 +258,6 @@ class TestMergedExport:
 
 
 # ---------------------------------------------------------------------------
-# profile_step.parse: byte-compatible --top output over the shared parser
-
-
-class TestProfileStepParity:
-    def test_parse_output_format(self, capsys):
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "scripts",
-        ))
-        import profile_step
-
-        profile_step.parse(FIXTURE, steps=2, top=3)
-        out = capsys.readouterr().out.splitlines()
-        assert out[0].startswith("# trace: ")
-        assert out[1].startswith("# NOTE: rows are NOT additive")
-        # RAW durations (not self-times) by event name, desc, /steps:
-        # fusion.1 10ms, fusion.4 9ms, all-reduce.7 8ms over 2 steps.
-        assert out[3] == f"{5.0:8.3f} ms/step  fusion.1"
-        assert out[4] == f"{4.5:8.3f} ms/step  fusion.4"
-        assert out[5] == f"{4.0:8.3f} ms/step  all-reduce.7"
-        assert len(out) == 6  # --top honored
-
-
-# ---------------------------------------------------------------------------
 # capture windows (mechanics only; the devprof smoke covers the full path)
 
 
@@ -460,17 +433,6 @@ class TestSatellites:
         assert set(w) == {"peak_hbm_bytes", "hbm_bytes_in_use"}
         # CPU backend: explicit nulls, never a crash
         assert w["peak_hbm_bytes"] is None or w["peak_hbm_bytes"] >= 0
-
-    def test_utils_profiling_deprecation_warning(self):
-        sys.modules.pop("dtc_tpu.utils.profiling", None)
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            importlib.import_module("dtc_tpu.utils.profiling")
-        assert any(
-            issubclass(w.category, DeprecationWarning)
-            and "dtc_tpu.obs.profiling" in str(w.message)
-            for w in caught
-        )
 
     def test_fixture_is_committed_not_generated(self):
         """Tests must not depend on live profiler output: the fixture's

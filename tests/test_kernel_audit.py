@@ -438,6 +438,30 @@ def test_ladder_configs_load_and_verdicts():
         assert vmem.decode_blocked_plan(cfg)["fits"] is True
 
 
+#: Where each rung is defined, and the only fields the audit may set over it.
+_RUNG_SOURCES = {
+    "flagship": ("model_config.yaml", {"dropout", "remat", "decode_attention"}),
+    "ladder_350m": ("model_ladder_350m.yaml", set()),
+    "ladder_1b": ("model_ladder_1b.yaml", set()),
+}
+
+
+@pytest.mark.parametrize("rung", K.LADDER_RUNGS)
+def test_a_rung_is_its_config_file_and_the_stated_overrides(rung):
+    """Each rung is defined in one place, a file under configs/: the audit's
+    config differs from the loaded file in the fields listed above, which
+    are the ones ``K.LADDER_RUNGS`` states, and in nothing else."""
+    from dtc_tpu.config.loader import load_yaml_dataclass
+
+    filename, overridden = _RUNG_SOURCES[rung]
+    assert K.LADDER_RUNGS[rung][0] == filename
+    from_file = load_yaml_dataclass(os.path.join(K._CONFIG_DIR, filename), ModelConfig)
+    audited = K.rung_config(rung)
+    differs = {f.name for f in dataclasses.fields(ModelConfig)
+               if getattr(audited, f.name) != getattr(from_file, f.name)}
+    assert differs == overridden == set(K.LADDER_RUNGS[rung][1])
+
+
 def test_committed_kernel_baselines_match_recompute():
     """The drift gate the CI pre-gate runs: recomputing every rung's
     static plan must reproduce the committed kernels_<rung>.json."""

@@ -777,36 +777,6 @@ def test_reducer_fleet_percentiles(tmp_path):
     assert "ms_per_token_p99" not in red["hosts"]["1"]  # no samples
 
 
-def test_drift_guard_fleet_rows_require_matching_replicas(tmp_path):
-    """serve_fleet_* rows ride the serve drift family with the replica-
-    count (and kill-leg) same-config rule: a 3-replica row is never
-    judged against a 2-replica one."""
-    from bench import decode_drift_guard
-
-    d = str(tmp_path)
-    base = {"platform": "cpu", "serve_model": "tiny",
-            "kill_replica_at": 0}
-    detail = {"serve_fleet_load90": {
-        "ms_per_token": 10.0, "n_replicas": 3, **base}}
-    with open(os.path.join(d, "BENCH_r01.json"), "w") as f:
-        json.dump({"n": 1, "rc": 0,
-                   "tail": "# bench-detail: " + json.dumps(detail)}, f)
-    # Same replica count, +100%: flagged.
-    extra = {"serve_fleet_load90": {
-        "ms_per_token": 20.0, "n_replicas": 3, **base}}
-    flags = decode_drift_guard(extra, d)
-    assert len(flags) == 1 and "serve_fleet_load90" in flags[0]
-    # Different replica count: not comparable.
-    extra = {"serve_fleet_load90": {
-        "ms_per_token": 20.0, "n_replicas": 2, **base}}
-    assert decode_drift_guard(extra, d) == []
-    # Kill leg vs clean leg: not comparable either.
-    extra = {"serve_fleet_load90": {
-        "ms_per_token": 20.0, "n_replicas": 3, "platform": "cpu",
-        "serve_model": "tiny", "kill_replica_at": 8}}
-    assert decode_drift_guard(extra, d) == []
-
-
 def test_resume_submit_engine_level(fleet_model):
     """The engine's cross-replica resume primitive in isolation: partial
     progress on engine A resumes on engine B token-identically, with

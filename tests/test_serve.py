@@ -770,72 +770,3 @@ def test_fused_decode_attention_per_row_matches_oracle():
     ).reshape(b, 1, h, d)
     want = decode_attention(q, k, v, starts)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-
-
-# ---------------------------------------------------------------------------
-# bench wiring
-# ---------------------------------------------------------------------------
-
-def test_bench_pct_helper():
-    """bench's _pct is now the SHARED textbook nearest-rank helper
-    (dtc_tpu/utils/percentile.py, ISSUE 7): rank = ceil(q*n), so the
-    even-sample median is the lower neighbor (2.0, not the old ad-hoc
-    int(q*n) indexing's 3.0). Edge cases live in test_trace.py."""
-    from bench import _pct
-
-    assert _pct([], 0.5) is None
-    assert _pct([3.0], 0.99) == 3.0
-    assert _pct([1.0, 2.0, 3.0, 4.0], 0.5) == 2.0
-    assert _pct([1.0, 2.0, 3.0, 4.0], 0.99) == 4.0
-
-
-def test_drift_guard_covers_serve_rows(tmp_path):
-    """Serve rows ride the decode drift guard: same-platform+model
-    regressions flag; cross-platform (a CPU-measured row against a TPU
-    one) and cross-model (tiny vs flagship rows share labels)
-    comparisons are skipped."""
-    import json
-    import os
-
-    from bench import decode_drift_guard
-
-    d = str(tmp_path)
-    detail = {
-        "serve_load50": {
-            "ms_per_token": 10.0, "platform": "cpu", "serve_model": "tiny",
-        },
-    }
-    with open(os.path.join(d, "BENCH_r01.json"), "w") as f:
-        json.dump({"n": 1, "rc": 0,
-                   "tail": "# bench-detail: " + json.dumps(detail)}, f)
-    # Same platform + model, +100%: flagged.
-    extra = {"serve_load50": {
-        "ms_per_token": 20.0, "platform": "cpu", "serve_model": "tiny"}}
-    flags = decode_drift_guard(extra, d)
-    assert len(flags) == 1 and "serve_load50" in flags[0]
-    # Different platform: skipped, not compared.
-    extra = {"serve_load50": {
-        "ms_per_token": 20.0, "platform": "tpu", "serve_model": "tiny"}}
-    assert decode_drift_guard(extra, d) == []
-    # Different serve model, same platform: skipped (not comparable).
-    extra = {"serve_load50": {
-        "ms_per_token": 1000.0, "platform": "cpu", "serve_model": "flagship"}}
-    assert decode_drift_guard(extra, d) == []
-    # Within band: clean.
-    extra = {"serve_load50": {
-        "ms_per_token": 11.0, "platform": "cpu", "serve_model": "tiny"}}
-    assert decode_drift_guard(extra, d) == []
-    # A NEWER file whose rows are all incomparable (TPU) must not
-    # deactivate the guard: it falls back to the older comparable file.
-    tpu_detail = {
-        "serve_load50": {
-            "ms_per_token": 0.5, "platform": "tpu", "serve_model": "tiny",
-        },
-    }
-    with open(os.path.join(d, "BENCH_r02.json"), "w") as f:
-        json.dump({"n": 2, "rc": 0,
-                   "tail": "# bench-detail: " + json.dumps(tpu_detail)}, f)
-    extra = {"serve_load50": {
-        "ms_per_token": 20.0, "platform": "cpu", "serve_model": "tiny"}}
-    flags = decode_drift_guard(extra, d)
-    assert len(flags) == 1 and "BENCH_r01.json" in flags[0]

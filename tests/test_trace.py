@@ -59,15 +59,11 @@ def test_nearest_rank_edge_cases():
     assert nearest_rank([3, 1, 2, 4], 1.0) == 4   # q=1 -> max
     assert nearest_rank([1, 2, 3, 4], 0.5) == 2   # ceil(0.5*4)=2nd
     assert nearest_rank([1, 2, 3, 4], 0.51) == 3
+    assert nearest_rank([1, 2, 3, 4], 0.99) == 4
+    assert nearest_rank([3.0], 0.99) == 3.0
     assert nearest_rank(range(1, 101), 0.99) == 99
     with pytest.raises(ValueError):
         nearest_rank([1.0], 1.5)
-
-
-def test_bench_shares_nearest_rank():
-    import bench
-
-    assert bench._pct is nearest_rank
 
 
 # ---------------------------------------------------------------------------
