@@ -234,7 +234,7 @@ def _data_axis(batch: int):
 
 def _rows_per_data_shard(fn, *args):
     """``fn`` over batch-leading arrays, each device on its own rows: XLA
-    cannot partition a Mosaic kernel (the scan's chunk-local pair), and the
+    cannot partition a Mosaic kernel (the scan's fused kernels), and the
     scan is independent across rows, so on a mesh it runs in a region that
     is manual over every free axis, as the flash kernel does — whole on
     every device where the rows do not divide (the batch-1 ``model.init``
@@ -487,13 +487,16 @@ def layer_plan(cfg: ModelConfig) -> dict:
             cfg.gdn_chunk, cfg.gdn_key_dim, cfg.gdn_value_dim, cfg.gdn_value_heads,
             cfg.gdn_key_heads, DTYPE_BYTES[cfg.compute_dtype])
         plan["gdn"] = {
-            "kernel": "mosaic" if local else "xla", "chunk": cfg.gdn_chunk,
+            # one Mosaic kernel a pass with the state in VMEM across a row's
+            # chunks, or the jax.numpy form with XLA's scan carrying it
+            "kernel": "mosaic" if local else "xla", "carry": "vmem" if local else "scan",
+            "chunk": cfg.gdn_chunk,
             "chunks": cfg.max_seq_len // cfg.gdn_chunk,
             "key_heads": cfg.gdn_key_heads, "value_heads": cfg.gdn_value_heads,
             "key_dim": cfg.gdn_key_dim, "value_dim": cfg.gdn_value_dim,
         }
         if local:
-            # the chunk-local kernels' grid step: value heads x chunk positions
+            # the kernels' grid step: value heads x chunk positions
             plan["gdn"]["tile"] = [local["tiles"], local["chunk"]]
             plan["gdn"]["vmem_limit_bytes"] = {
                 leg: local[leg]["vmem_limit_bytes"] for leg in ("fwd", "bwd")}

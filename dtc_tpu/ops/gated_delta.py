@@ -32,29 +32,39 @@ at ``highest`` precision: 254 ms of a 0.9 s step in batched 64 x 64
 six-pass products, for an ``A`` that itself comes out of a ``dtype``
 product).
 
-**The backward keeps no per-token state.** The forward rule of the scan
-over chunks keeps each chunk's incoming ``S`` (``T / C`` states, not
-``T``); the backward rule walks the chunks in reverse, recomputes one
-chunk's step from its saved ``S`` and pulls the cotangents of the outputs
-and of the carried state through it.
+**The backward keeps no per-token state.** The forward rule keeps each
+chunk's incoming ``S`` (``T / C`` states, not ``T``); the backward rule
+walks the chunks in reverse, recomputes one chunk's step from its saved
+``S`` and pulls the cotangents of the outputs and of the carried state
+through it.
 
-**The chunk-local part is a Mosaic kernel pair where its tiles are legal**
-(:func:`supports_chunk_kernel`; ``ops/vmem.py:gdn_chunk_plan``): one grid
-step takes a few (chunk, value head) tiles, reads q, k and v straight from
-the ``(B, T, H * d)`` layout — each value head's key head chosen by the
-slice, so nothing is repeated in HBM — and builds ``ratio``, ``A``, ``T``
-and the products in VMEM; only the scan's five operands go out, chunk-major.
-The backward is a kernel of its own that rebuilds a tile's ``T`` from the
-same inputs (the residuals are the inputs, so nothing is checkpointed) and
-writes ``dq``, ``dk`` (summed over the value heads a key head serves),
-``dv`` and the gradients for the cumulative decay and beta. Elsewhere (toy
-widths, a chunk off the sublane count) the ``jax.numpy`` form runs, which
-is also the kernels' oracle.
+**One Mosaic kernel a pass where its tiles are legal**
+(:func:`supports_chunk_kernel`; ``ops/vmem.py:gdn_chunk_plan``). The grid is
+(rows, groups of value heads, chunks) with the chunks last and in order, and
+the ``(dk, dv)`` float32 state of the step's heads lives in VMEM scratch
+across a row's chunks, zeroed at the row's first. A grid step reads q, k and
+v straight from the ``(B, T, H * d)`` layout — each value head's key head
+chosen by the slice, so nothing is repeated in HBM — builds ``ratio``,
+``A``, ``T``, ``u``, ``w`` and the rest of a chunk in VMEM, takes the
+chunk's step on the state and writes the output alone, float32, as ``(B,
+T, H, dv)``: neither the five operands of the step nor a (C, C) array ever
+exists in HBM. Three kernels of one body: ``gdn_chunks_fwd``, the primal,
+keeps nothing (under a layer's ``jax.checkpoint`` it is the first forward);
+``gdn_chunks_fwd_res``, the forward rule, also writes each chunk's incoming
+state ``(N, B, H, dk, dv)``; ``gdn_chunks_bwd`` walks the same grid from a
+row's last chunk to its first with the state's cotangent in the scratch,
+rebuilds a chunk from the inputs and its saved state, and writes ``dq``,
+``dk`` (summed over the value heads a key head serves), ``dv`` and the
+gradients for the cumulative decay and beta. Elsewhere (toy widths, a chunk
+off the sublane count) the ``jax.numpy`` form (:func:`_chunk_local_xla`) and
+an XLA ``scan`` over the chunks (:func:`_scan_chunks`) run, which are also
+the kernels' oracle.
 """
 
 from __future__ import annotations
 
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
@@ -210,15 +220,16 @@ def _chunk_local_xla(q, k, v, gamma, beta, dtype):
 
 
 # ---------------------------------------------------------------------------
-# the chunk-local part as a Mosaic kernel pair
+# chunk-local part and carry as one Mosaic kernel a pass
 
 
 def supports_chunk_kernel(chunk: int, dk: int, dv: int, hv: int, hk: int,
                           itemsize: int = 2) -> dict | None:
-    """The planner's plan where the chunk-local kernels run — ``dk`` and
-    ``dv`` multiples of the lane width, the chunk a multiple of the sublane
-    count, the blocks inside the budget — else None, and the ``jax.numpy``
-    form runs. Off the TPU the kernels run interpreted."""
+    """The planner's plan where the fused kernels run — ``dk`` and ``dv``
+    multiples of the lane width, the chunk a multiple of the sublane count,
+    blocks and the state's scratch inside the budget — else None, and the
+    ``jax.numpy`` form with XLA's scan runs. Off the TPU the kernels run
+    interpreted."""
     plan = vmem.gdn_chunk_plan(chunk, dk, dv, hv, hk, itemsize)
     return plan if plan is not None and plan["fits"] else None
 
@@ -256,54 +267,93 @@ def _decays(gam_ref, beta_ref, eye, lower):
     return b_col, jnp.exp(g_col), jnp.exp(g_row[:, :, -1:] - g_col), ratio
 
 
-def _local_fwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref,
-                      qg_ref, w_ref, u_ref, local_ref, kdec_ref, *, tiles, rep, dk, dv, dtype):
-    """All of the step's tiles at once, batched over the leading axis: a
-    tile's ten dependent 64-wide products then lie beside the other tiles'
-    and do not wait on the MXU's latency one after the other. ``k k^T`` and
-    ``q k^T`` once a key head, for the value heads it serves."""
+def _tiles(q_ref, k_ref, v_ref, gam_ref, beta_ref, *, tiles, rep, dk, dv, dtype):
+    """What the step's tiles are before any state, all at once and batched
+    over the leading axis — a tile's ten dependent 64-wide products then lie
+    beside the other tiles' and do not wait on the MXU's latency one after
+    the other: ``_chunk_local_xla``'s arithmetic in VMEM. ``k k^T`` and ``q
+    k^T`` once a key head (``q1``, ``k1``), for the value heads it serves."""
     eye, lower, strict = _masks(q_ref.shape[1])
     per = lambda x: jnp.repeat(x, rep, axis=0) if rep > 1 else x  # noqa: E731  key head -> value heads
-    q, k = _heads(q_ref, tiles // rep, dk), _heads(k_ref, tiles // rep, dk)
-    kk, qk = per(_bmm(k, k, dtype, 2, 2)), per(_bmm(q, k, dtype, 2, 2))
-    q, k = per(q), per(k)
-    b_col, e_col, x_col, ratio = _decays(gam_ref, beta_ref, eye, lower)
-    tri = _blocked_inverse(jnp.where(strict, kk * b_col * ratio, 0.0), dtype, _bmm)
-    u_ref[0, 0] = _bmm(tri, _heads(v_ref, tiles, dv) * b_col, dtype)
-    w_ref[0, 0] = _bmm(tri, k * (b_col * e_col), dtype).astype(w_ref.dtype)
-    local_ref[0, 0] = (qk * ratio).astype(local_ref.dtype)
-    qg_ref[0, 0] = (q * e_col).astype(qg_ref.dtype)
-    kdec_ref[0, 0] = (k * x_col).astype(kdec_ref.dtype)
+    t = types.SimpleNamespace(eye=eye, strict=strict)
+    t.q1, t.k1 = _heads(q_ref, tiles // rep, dk), _heads(k_ref, tiles // rep, dk)
+    t.kk, t.qk = per(_bmm(t.k1, t.k1, dtype, 2, 2)), per(_bmm(t.q1, t.k1, dtype, 2, 2))
+    t.q, t.k, t.v = per(t.q1), per(t.k1), _heads(v_ref, tiles, dv)
+    t.b_col, t.e_col, t.x_col, t.ratio = _decays(gam_ref, beta_ref, eye, lower)
+    t.decay = jnp.exp(gam_ref[0, 0][:, :, -1:])                    # (G, 1, 1): the chunk's whole decay
+    t.tri = _blocked_inverse(jnp.where(strict, t.kk * t.b_col * t.ratio, 0.0), dtype, _bmm).astype(dtype)
+    t.u = _bmm(t.tri, t.v * t.b_col, dtype)
+    t.w = _bmm(t.tri, t.k * (t.b_col * t.e_col), dtype).astype(dtype)
+    t.local, t.qg, t.kdec = ((t.qk * t.ratio).astype(dtype), (t.q * t.e_col).astype(dtype),
+                             (t.k * t.x_col).astype(dtype))
+    return t
 
 
-def _local_bwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref,
-                      dqg_ref, dw_ref, du_ref, dlocal_ref, dkdec_ref,
-                      dq_ref, dk_ref, dv_ref, dgam_ref, dbeta_ref, *, tiles, rep, dk, dv, dtype):
-    """The tiles' forward rebuilt, and the five cotangents pulled through
-    it: ``dT = du (beta v)^T + dw (beta k e^gamma)^T``, ``dA = -T^T dT T^T``
+def _fwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref, out_ref, *rest, tiles, rep, dk, dv, dtype):
+    """One chunk of the step's (row, value heads): ``_chunk_step`` on the
+    state the scratch carries from the row's chunk before. ``rest`` is the
+    scratch alone (the primal) or, before it, the block that takes the
+    chunk's incoming state for the backward."""
+    *saved, s_ref = rest
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    t = _tiles(q_ref, k_ref, v_ref, gam_ref, beta_ref, tiles=tiles, rep=rep, dk=dk, dv=dv, dtype=dtype)
+    s = s_ref[...]
+    for ref in saved:
+        ref[0, 0] = s
+    sd = s.astype(dtype)
+    v_new = (t.u - _bmm(t.w, sd, dtype)).astype(dtype)
+    out = _bmm(t.qg, sd, dtype) + _bmm(t.local, v_new, dtype)
+    s_ref[...] = s * t.decay + _bmm(t.kdec, v_new, dtype, 1, 1)
+    for i in range(tiles):
+        out_ref[0, :, i, :] = out[i]
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref, s_in_ref, dout_ref,
+                dq_ref, dk_ref, dv_ref, dgam_ref, dbeta_ref, ds_ref, *, tiles, rep, dk, dv, dtype):
+    """The same grid from a row's last chunk to its first, the scratch
+    carrying the state's cotangent. A step rebuilds its tiles and, from the
+    chunk's saved incoming state, ``v'``; pulls ``dout`` and the carried
+    cotangent through ``_chunk_step`` into the five cotangents of the
+    chunk-local part — which never leave VMEM — and those through the tiles:
+    ``dT = du (beta v)^T + dw (beta k e^gamma)^T``, ``dA = -T^T dT T^T``
     under the strict mask, then the products' own rules. ``ratio_ij =
     exp(gamma_i - gamma_j)`` gives ``+ds`` to row i's gamma and ``-ds`` to
-    column j's, ``ds = d(ratio) * ratio``. ``dq`` and ``dk`` are summed over
-    the value heads a key head serves."""
-    f32 = jnp.float32
+    column j's, ``ds = d(ratio) * ratio``; the chunk's whole decay gives
+    ``sum(S * dS)`` times itself to the last column's. ``dq`` and ``dk`` are
+    summed over the value heads a key head serves."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
     c = q_ref.shape[1]
-    eye, lower, strict = _masks(c)
+    t = _tiles(q_ref, k_ref, v_ref, gam_ref, beta_ref, tiles=tiles, rep=rep, dk=dk, dv=dv, dtype=dtype)
+    q, k, v, kk, qk, tri, ratio = t.q, t.k, t.v, t.kk, t.qk, t.tri, t.ratio
+    b_col, e_col, x_col = t.b_col, t.e_col, t.x_col
     rowsum = lambda x: jnp.sum(x, axis=2, keepdims=True)                              # noqa: E731
-    as_row = lambda col: jnp.sum(jnp.where(eye, col, 0.0), axis=1, keepdims=True)     # noqa: E731
-    per = lambda x: jnp.repeat(x, rep, axis=0) if rep > 1 else x                      # noqa: E731
+    as_row = lambda col: jnp.sum(jnp.where(t.eye, col, 0.0), axis=1, keepdims=True)   # noqa: E731
     over = lambda x: x.reshape(tiles // rep, rep, *x.shape[1:]).sum(axis=1) if rep > 1 else x  # noqa: E731
-    q1, k1 = _heads(q_ref, tiles // rep, dk), _heads(k_ref, tiles // rep, dk)         # a key head each
-    kk, qk = per(_bmm(k1, k1, dtype, 2, 2)), per(_bmm(q1, k1, dtype, 2, 2))
-    q, k, v = per(q1), per(k1), _heads(v_ref, tiles, dv)
-    b_col, e_col, x_col, ratio = _decays(gam_ref, beta_ref, eye, lower)
-    tri = _blocked_inverse(jnp.where(strict, kk * b_col * ratio, 0.0), dtype, _bmm).astype(dtype)
-    du, dw = du_ref[0, 0], dw_ref[0, 0].astype(f32)
-    dqg, dkdec = dqg_ref[0, 0].astype(f32), dkdec_ref[0, 0].astype(f32)
-    dlocal = dlocal_ref[0, 0].astype(f32) * ratio
+
+    # the carry's pullback: out = qg S + local v', S' = S decay + kdec^T v', v' = u - w S
+    s, dstate = s_in_ref[0, 0], ds_ref[...]
+    sd, dsd = s.astype(dtype), dstate.astype(dtype)
+    dout = jnp.stack([dout_ref[0, :, i, :] for i in range(tiles)]).astype(dtype)
+    v_new = (t.u - _bmm(t.w, sd, dtype)).astype(dtype)
+    du = _bmm(t.local, dout, dtype, 1, 1) + _bmm(t.kdec, dsd, dtype)                  # dv'
+    dqg, dw = _bmm(dout, sd, dtype, 2, 2), -_bmm(du, sd, dtype, 2, 2)
+    dlocal = _bmm(dout, v_new, dtype, 2, 2) * ratio
+    dkdec = _bmm(v_new, dsd, dtype, 2, 2)
+    ddecay = jnp.sum(rowsum(s * dstate), axis=1, keepdims=True) * t.decay             # (G, 1, 1)
+    ds_ref[...] = dstate * t.decay + _bmm(t.qg, dout, dtype, 1, 1) - _bmm(t.w, du, dtype, 1, 1)
+
+    # the tiles' pullback
     be = b_col * e_col
     dtri = _bmm(du, v * b_col, dtype, 2, 2) + _bmm(dw, k * be, dtype, 2, 2)
     dvb, dkbe = _bmm(tri, du, dtype, 1, 1), _bmm(tri, dw, dtype, 1, 1)
-    da = jnp.where(strict, -_bmm(_bmm(tri, dtri, dtype, 1, 1), tri, dtype, 2, 2), 0.0) * ratio   # dA * ratio
+    da = jnp.where(t.strict, -_bmm(_bmm(tri, dtri, dtype, 1, 1), tri, dtype, 2, 2), 0.0) * ratio   # dA * ratio
     ds = da * (b_col * kk) + dlocal * qk
     z = rowsum(dkdec * k) * x_col                              # d(decay to the chunk's end), times it
     dkbe_k = rowsum(dkbe * k)
@@ -311,12 +361,12 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref,
     dbeta = rowsum(da * kk) + rowsum(dvb * v) + dkbe_k * e_col
     last = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1) == c - 1
     dgam_ref[0, 0] = (as_row(dgam) - jnp.sum(ds, axis=1, keepdims=True)
-                      + jnp.where(last, jnp.sum(z, axis=1, keepdims=True), 0.0))
+                      + jnp.where(last, jnp.sum(z, axis=1, keepdims=True) + ddecay, 0.0))
     dbeta_ref[0, 0] = as_row(dbeta)
     dkk, dqk = over(da * b_col), over(dlocal)
-    dq = over(dqg * e_col) + _bmm(dqk, k1, dtype)
-    dk_ = (over(dkbe * be + dkdec * x_col) + _bmm(dqk, q1, dtype, 1, 1)
-           + _bmm(dkk, k1, dtype) + _bmm(dkk, k1, dtype, 1, 1))
+    dq = over(dqg * e_col) + _bmm(dqk, t.k1, dtype)
+    dk_ = (over(dkbe * be + dkdec * x_col) + _bmm(dqk, t.q1, dtype, 1, 1)
+           + _bmm(dkk, t.k1, dtype) + _bmm(dkk, t.k1, dtype, 1, 1))
     dv_ = dvb * b_col
     for i in range(tiles // rep):
         dq_ref[0, :, i * dk:(i + 1) * dk] = dq[i].astype(dq_ref.dtype)
@@ -325,66 +375,95 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref,
         dv_ref[0, :, i * dv:(i + 1) * dv] = dv_[i].astype(dv_ref.dtype)
 
 
-def _launch(kernel, leg, q, k, v, gamma, beta, cts, dtype):
-    """One kernel of the pair over the grid (rows, chunks, groups of value
-    heads). q / k and v are blocks of ``(B, T, H * d)``: a chunk's positions
-    by the lanes of the step's heads; a scan operand is a block of ``(N, B,
-    H, C, width)``, the decay and beta rows among them with a ``C`` of 1 (a
-    head's row a tile of its own). The forward writes the five operands;
-    the backward reads their cotangents ``cts`` too and writes a gradient
+def _launch(leg, q, k, v, gamma, beta, dtype, states=None, dout=None):
+    """One kernel over the grid (rows, groups of value heads, chunks), the
+    chunks last and in order — from the row's last for the backward — and
+    the state, or its cotangent, in a float32 scratch that is zeroed at a
+    row's first step. q / k and v are blocks of ``(B, T, H * d)``: a chunk's
+    positions by the lanes of the step's heads. The output and its cotangent
+    are float32 blocks of ``(B, T, Hv, dv)``, the step's heads along the
+    sublanes — the layout the mixer's RMSNorm over ``dv`` reduces in, so XLA
+    moves nothing between the kernel and it (as ``(B, T, Hv * dv)`` it did,
+    twice a pass: 10 ms a step of the benchmark's cell); a head's rows go in
+    and out by strided sublane accesses. The decay and beta rows are blocks
+    of ``(N, B, H, 1, C)`` (a head's row a tile of its own), a chunk's
+    incoming state of ``(N, B, H, dk, dv)``. ``fwd`` writes the output,
+    ``fwd_res`` each chunk's incoming state beside it, ``bwd`` a gradient
     for every input, in the input's shape."""
     (b, t, hk, dk), (hv, dv), c = q.shape, v.shape[2:], gamma.shape[-1]
+    n = t // c
     plan = supports_chunk_kernel(c, dk, dv, hv, hk, q.dtype.itemsize)
     tiles, kheads = plan["tiles"], plan["key_heads_per_step"]
-    qk = pl.BlockSpec((1, c, kheads * dk), lambda bi, ni, hi: (bi, ni, hi))
-    vs = pl.BlockSpec((1, c, tiles * dv), lambda bi, ni, hi: (bi, ni, hi))
-    operand = lambda width, rows=c: pl.BlockSpec(  # noqa: E731
-        (1, 1, tiles, rows, width), lambda bi, ni, hi: (ni, bi, hi, 0, 0))
+    at = (lambda ni: n - 1 - ni) if leg == "bwd" else (lambda ni: ni)
+    qk = pl.BlockSpec((1, c, kheads * dk), lambda bi, hi, ni: (bi, at(ni), hi))
+    vs = pl.BlockSpec((1, c, tiles * dv), lambda bi, hi, ni: (bi, at(ni), hi))
+    per_chunk = lambda *tail: pl.BlockSpec(  # noqa: E731
+        (1, 1, tiles, *tail), lambda bi, hi, ni: (at(ni), bi, hi, 0, 0))
     inputs = (q.reshape(b, t, hk * dk), k.reshape(b, t, hk * dk), v.reshape(b, t, hv * dv),
               gamma[..., None, :], beta[..., None, :])
-    in_specs = [qk, qk, vs, operand(c, 1), operand(c, 1)]
-    scan_specs = [operand(dk), operand(dk), operand(dv), operand(c), operand(dk)]
-    if leg == "fwd":
-        out = lambda width, dt: jax.ShapeDtypeStruct((t // c, b, hv, c, width), dt)  # noqa: E731
-        out_specs = scan_specs
-        out_shape = [out(dk, dtype), out(dk, dtype), out(dv, jnp.float32), out(c, dtype), out(dk, dtype)]
+    in_specs = [qk, qk, vs, per_chunk(1, c), per_chunk(1, c)]
+    outs = pl.BlockSpec((1, c, tiles, dv), lambda bi, hi, ni: (bi, at(ni), hi, 0))
+    out_f32 = jax.ShapeDtypeStruct((b, t, hv, dv), jnp.float32)
+    saved = jax.ShapeDtypeStruct((n, b, hv, dk, dv), jnp.float32)
+    if leg == "bwd":
+        kernel = _bwd_kernel
+        out_specs, out_shape = in_specs, [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in inputs]
+        in_specs, inputs = [*in_specs, per_chunk(dk, dv), outs], (*inputs, states, dout)
     else:
-        out_specs, in_specs = in_specs, in_specs + scan_specs
-        out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in inputs]
+        kernel = _fwd_kernel
+        out_specs, out_shape = [outs], [out_f32]
+        if leg == "fwd_res":
+            out_specs, out_shape = [outs, per_chunk(dk, dv)], [out_f32, saved]
     return pl.pallas_call(
         functools.partial(kernel, tiles=tiles, rep=tiles // kheads, dk=dk, dv=dv, dtype=dtype),
-        name=f"gdn_chunk_local_{leg}",
-        grid=(b, t // c, hv // tiles), in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        name=f"gdn_chunks_{leg}",
+        grid=(b, hv // tiles, n), in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((tiles, dk, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"),
-            vmem_limit_bytes=plan[leg]["vmem_limit_bytes"],
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=plan["bwd" if leg == "bwd" else "fwd"]["vmem_limit_bytes"],
         ),
         interpret=_interpret(),
-    )(*inputs, *cts)
+    )(*inputs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _chunk_local(q, k, v, gamma, beta, dtype, mosaic):
-    """The scan's operands ``(qg, w, u, local, kdec)``, chunk-major, from q,
-    k ``(B, T, Hk, dk)``, v ``(B, T, Hv, dv)`` and the chunks' cumulative
-    decay and beta ``(N, B, Hv, C)``. The residuals are the inputs: the
-    backward rebuilds what it needs (inside the kernel, or as the
-    ``jax.numpy`` form's own pullback), so no (C, C) array is kept."""
-    if mosaic:
-        return tuple(_launch(_local_fwd_kernel, "fwd", q, k, v, gamma, beta, (), dtype))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _chunks_fused(q, k, v, gamma, beta, dtype):
+    """The whole recurrence ``(B, T, Hv, dv)`` float32 from q, k ``(B, T,
+    Hk, dk)``, v ``(B, T, Hv, dv)`` and the chunks' cumulative decay and beta
+    ``(N, B, Hv, C)``, one kernel a pass. This, the primal, keeps nothing:
+    under a layer's ``jax.checkpoint`` the first forward runs it
+    (``optimize_remat``), and only the forward that a backward follows writes
+    each chunk's incoming state."""
+    return _launch("fwd", q, k, v, gamma, beta, dtype)[0]
+
+
+def _chunks_fused_fwd(q, k, v, gamma, beta, dtype):
+    out, states = _launch("fwd_res", q, k, v, gamma, beta, dtype)
+    return out, (q, k, v, gamma, beta, states)
+
+
+def _chunks_fused_bwd(dtype, res, dout):
+    *inputs, states = res
+    grads = _launch("bwd", *inputs, dtype, states, dout)
+    return tuple(g.reshape(x.shape) for g, x in zip(grads, inputs))
+
+
+_chunks_fused.defvjp(_chunks_fused_fwd, _chunks_fused_bwd, optimize_remat=True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _chunk_local(q, k, v, gamma, beta, dtype):
+    """``_chunk_local_xla`` with the inputs as its residuals: the backward
+    rebuilds what it needs, so no (C, C) array is kept."""
     return _chunk_local_xla(q, k, v, gamma, beta, dtype)
 
 
-def _chunk_local_fwd(q, k, v, gamma, beta, dtype, mosaic):
-    return _chunk_local(q, k, v, gamma, beta, dtype, mosaic), (q, k, v, gamma, beta)
+def _chunk_local_fwd(q, k, v, gamma, beta, dtype):
+    return _chunk_local_xla(q, k, v, gamma, beta, dtype), (q, k, v, gamma, beta)
 
 
-def _chunk_local_bwd(dtype, mosaic, res, cts):
-    if mosaic:
-        q, k, v, gamma, beta = res
-        dq, dk, dv, dgamma, dbeta = _launch(_local_bwd_kernel, "bwd", *res, cts, dtype)
-        return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
-                dgamma.reshape(gamma.shape), dbeta.reshape(beta.shape))
+def _chunk_local_bwd(dtype, res, cts):
     return jax.vjp(lambda *a: _chunk_local_xla(*a, dtype), *res)[1](cts)
 
 
@@ -407,11 +486,14 @@ def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64, dtype=jnp.float32)
     if h % hk:
         raise ValueError(f"{h} value heads are not a multiple of {hk} key heads")
     f32 = jnp.float32
-    mosaic = supports_chunk_kernel(chunk, dk, dv, h, hk, q.dtype.itemsize) is not None
-    with jax.named_scope("chunk_local"):
+    with jax.named_scope("chunk_gates"):
         gamma = jnp.cumsum(_chunk_major(g.astype(f32), chunk), axis=-1)    # (N, B, H, C)
-        qg, w, u, local, kdec = _chunk_local(
-            q, k, v, gamma, _chunk_major(beta.astype(f32), chunk), dtype, mosaic)
+        beta = _chunk_major(beta.astype(f32), chunk)
+    if supports_chunk_kernel(chunk, dk, dv, h, hk, q.dtype.itemsize) is not None:
+        with jax.named_scope("chunk_kernel"):
+            return _chunks_fused(q, k, v, gamma, beta, dtype)
+    with jax.named_scope("chunk_local"):
+        qg, w, u, local, kdec = _chunk_local(q, k, v, gamma, beta, dtype)
         decay = jnp.exp(gamma[..., -1])
     with jax.named_scope("chunk_carry"):
         out = _scan_chunks(qg, w, u, local, kdec, decay, dtype)        # (N, B, H, C, dv)

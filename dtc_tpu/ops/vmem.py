@@ -515,55 +515,71 @@ def flash_grid_tile_fits(block_q: int, block_kv: int, itemsize: int = 4) -> bool
 
 
 # ---------------------------------------------------------------------------
-# gated deltanet, the chunk-local part (ops/gated_delta.py)
+# gated deltanet: chunk-local part and carry, one kernel a pass (ops/gated_delta.py)
 # ---------------------------------------------------------------------------
 
 #: Sublane count of a float32 tile: the chunk's positions lie along it.
 SUBLANE = 8
 
-#: (chunk, head) tiles one grid step of the chunk-local kernels takes where
-#: the value heads allow it: enough that a step's fixed cost (~0.35 us) and
-#: the latency of a tile's dependent 64-wide products are shared, few enough
-#: that the unrolled body stays small.
+#: (chunk, head) tiles one grid step of the Gated DeltaNet kernels takes
+#: where the value heads allow it: enough that a step's fixed cost (~0.35 us)
+#: and the latency of a tile's dependent 64-wide products are shared, few
+#: enough that the unrolled body stays small.
 GDN_TILES_PER_STEP = 8
 
 
 def gdn_chunk_plan(
     chunk: int, dk: int, dv: int, hv: int, hk: int, itemsize: int = 2,
 ) -> dict[str, Any] | None:
-    """The chunk-local kernels' plan (``ops/gated_delta.py``) for a chunk
+    """The Gated DeltaNet kernels' plan (``ops/gated_delta.py``) for a chunk
     of ``chunk`` positions, ``hk`` key heads of ``dk`` serving ``hv`` value
     heads of ``dv``, q / k / v in ``itemsize`` bytes: the value heads one
     grid step takes, per pass the bytes of its blocks (as the BlockSpecs
-    state them: q and k lane blocks of the step's key heads, v and the
-    operands of the scan a block a value head, the chunk's decay and beta
-    rows in float32), the float32 (C, C) and (C, d) temporaries of the
-    tiles in flight, and the ``vmem_limit_bytes`` the kernel states.
+    state them: q and k lane blocks of the step's key heads, v a block a
+    value head, the output or its cotangent the same in float32 with the
+    step's heads along the sublanes of its ``(C, heads, dv)`` block, the
+    chunk's decay and beta rows in float32, a chunk's incoming ``(dk, dv)``
+    state a value head — written by the forward that a backward follows,
+    read by the backward), the float32 scratch that carries the state (or
+    its cotangent) across a row's chunks, the float32 (C, C), (C, d) and
+    (dk, dv) temporaries of the tiles in flight, and the
+    ``vmem_limit_bytes`` the kernel states.
 
     None where a tile is not legal: ``dk`` and ``dv`` multiples of the
     lane width, the chunk a multiple of the sublane count and no wider
-    than a lane tile, the key heads dividing the value heads."""
+    than a lane tile, the key heads dividing the value heads. ``fits``
+    False where no legal group of heads is inside the budget."""
     if dk % LANE or dv % LANE or chunk % SUBLANE or chunk > LANE or hk < 1 or hv % hk:
         return None
     rep = hv // hk
-    # a step takes whole key heads: as many value heads as divide hv, up to 8
-    tiles = max((g for g in range(rep, max(GDN_TILES_PER_STEP, rep) + 1, rep) if hv % g == 0))
+    # a step takes whole key heads, and its heads lie along the sublanes of
+    # the output's block: a multiple of the sublane count, or all of them —
+    # the most such value heads up to 8, else the fewest there are
+    legal = [g for g in range(rep, hv + 1, rep) if hv % g == 0 and (g % SUBLANE == 0 or g == hv)]
+    tiles = max((g for g in legal if g <= GDN_TILES_PER_STEP), default=legal[0])
     cc = chunk * max(chunk, LANE)                 # a (C, C) block pads to the lane width
-    qk = 2 * (tiles // rep) * chunk * dk          # elements
+    cd = chunk * max(dk, dv)
+    state = tiles * dk * dv * 4
     rows = 2 * tiles * SUBLANE * LANE * 4         # a head's row a float32 tile of its own
-    operands = tiles * (3 * chunk * dk * itemsize + chunk * dv * 4 + cc * itemsize)  # qg w kdec, u, local
-    fwd_blocks = (qk + tiles * chunk * dv) * itemsize + rows + operands
-    bwd_blocks = 2 * (qk + tiles * chunk * dv) * itemsize + 2 * rows + operands
-    # per tile in flight a dozen float32 (C, C) arrays and as many (C, d)
-    transient = tiles * 12 * (cc + chunk * max(dk, dv)) * 4
+    inputs = (2 * (tiles // rep) * chunk * dk + tiles * chunk * dv) * itemsize + rows
+    out = tiles * chunk * dv * 4                  # the output, or its cotangent
+    legs = {
+        # the chunk-local part's dozen (C, C) and (C, d) arrays a tile, then
+        # the carry's: v', the output, the state in dtype and its update
+        "fwd": (inputs + out + state, tiles * (12 * (cc + cd) + 2 * cd + 3 * dk * dv) * 4),
+        # + the carry's forward again and its pullback: five cotangents, the
+        # output's, the state and its cotangent in dtype, the new cotangent
+        "bwd": (2 * inputs + out + state, tiles * (12 * (cc + cd) + 8 * cd + cc + 5 * dk * dv) * 4),
+    }
     plan: dict[str, Any] = {
-        "kernel": "gdn_chunk_local", "chunk": chunk, "key_dim": dk, "value_dim": dv,
+        "kernel": "gdn_chunks", "chunk": chunk, "key_dim": dk, "value_dim": dv,
         "tiles": tiles, "key_heads_per_step": tiles // rep, "budget_bytes": VMEM_BUDGET_BYTES,
     }
-    for name, blocks in (("fwd", fwd_blocks), ("bwd", bwd_blocks)):
-        total = 2 * blocks                        # blocks double-buffered
+    for name, (blocks, transient) in legs.items():
+        total = 2 * blocks + state                # blocks double-buffered, the scratch not
         plan[name] = {
             "bytes": total,
+            "scratch_bytes": state,
             "modeled_transient_bytes": transient,
             "fits": total <= VMEM_BUDGET_BYTES,
             "vmem_limit_bytes": total + transient + VMEM_COMPILER_ALLOWANCE_BYTES,

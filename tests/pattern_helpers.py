@@ -1,5 +1,5 @@
-"""What the three files of pattern-model tests share (``test_pattern_model.py``,
-``test_pattern_ops.py``, ``test_pattern_parallel.py``).
+"""What the files of pattern-model tests share (``test_pattern_model.py``,
+``test_pattern_ops.py``, ``test_pattern_scan_kernels.py``, ``test_pattern_parallel.py``).
 
 Everything is compared with ``benchmark/reference_qwen3_next.py`` (float32
 ``jax.numpy``, Gated DeltaNet as the token recurrence, dense attention,
@@ -118,3 +118,21 @@ def one_device_steps(cfg, opt_cfg, batches, w=None):
             state, loss, counters = step(state, Batch(x=batch[:, :-1], y=batch[:, 1:]), jax.random.PRNGKey(0))
             losses.append(float(loss))
     return state, losses, counters
+
+
+def scan_inputs(b, t, hk, h, dk, dv, seed=0):
+    """Inputs of ``gated_delta_chunked`` as the mixer makes them (unit keys, scaled unit
+    queries, decays that forget in a few tokens) and a cotangent for its output."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (b, t, hk, dk))) / np.sqrt(dk)
+    k = unit(jax.random.normal(ks[1], (b, t, hk, dk)))
+    v = jax.random.normal(ks[2], (b, t, h, dv))
+    g = -2.0 * jax.nn.softplus(jax.random.normal(ks[3], (b, t, h)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
+    return (q, k, v, g, beta), jax.random.normal(ks[5], (b, t, h, dv))
+
+
+def out_and_grads(fn, args, co):
+    """``fn``'s value and its five gradients under the cotangent ``co``."""
+    return (fn(*args), *jax.grad(lambda *a: jnp.sum(fn(*a) * co), argnums=range(5))(*args))

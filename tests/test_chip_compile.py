@@ -202,12 +202,15 @@ def test_zigzag_ring_block_kernels(one_chip):
 # gated deltanet
 
 
-def test_gdn_chunk_local_fwd_bwd(one_chip):
-    """The chunk-local kernel pair of the Gated DeltaNet scan at the
+def test_gdn_chunks_fwd_bwd(one_chip):
+    """The fused kernels of the Gated DeltaNet scan (chunk-local part and
+    carry, the state in VMEM scratch across a row's chunks) at the
     benchmark cell's shape — 2 rows x 8192, 16 key heads serving 32 value
     heads of 128, chunks of 64, bf16 — through ``gated_delta_chunked``:
     (64, 64) blocks, transposed 64-wide operands and the stated
-    ``vmem_limit_bytes`` are what interpret mode cannot refuse."""
+    ``vmem_limit_bytes`` are what interpret mode cannot refuse. Under a
+    ``jax.checkpoint``, as in the layer, so that all three kernels compile:
+    the primal, the forward that writes the states, the backward."""
     from dtc_tpu.ops.gated_delta import gated_delta_chunked, supports_chunk_kernel
 
     b, t, hk, hv, d = 2, 8192, 16, 32, 128
@@ -219,8 +222,8 @@ def test_gdn_chunk_local_fwd_bwd(one_chip):
     def loss(q, k, v, g, beta):
         return gated_delta_chunked(q, k, v, g, beta, chunk=64, dtype=jnp.bfloat16).sum()
 
-    text = _compile(jax.grad(loss, argnums=range(5)), qk, qk, v, g, g).as_text()
-    assert "gdn_chunk_local_fwd" in text and "gdn_chunk_local_bwd" in text
+    text = _compile(jax.value_and_grad(jax.checkpoint(loss), argnums=range(5)), qk, qk, v, g, g).as_text()
+    assert all(f"gdn_chunks_{leg}" in text for leg in ("fwd.", "fwd_res", "bwd"))
 
 
 def test_gdn_layer_on_a_four_chip_mesh(topo):
@@ -251,7 +254,7 @@ def test_gdn_layer_on_a_four_chip_mesh(topo):
 
     with mesh, nn.logical_axis_rules(DEFAULT_RULES):
         text = _compile(jax.grad(loss, argnums=(0, 1)), params, x).as_text()
-    assert "gdn_chunk_local_fwd" in text and "gdn_chunk_local_bwd" in text
+    assert "gdn_chunks_fwd_res" in text and "gdn_chunks_bwd" in text
     assert "all-gather" not in text  # each chip's rows stay home
 
 
@@ -405,7 +408,7 @@ def test_pattern_cell_train_step_fits_one_chip(topo):
     """The layer-pattern cell of the benchmark (Qwen3-Next's one period, 32
     of 512 experts held, 2 rows x 8192) through the trainer's own step
     builder: grouped KV heads at head size 256 through the flash kernels,
-    the scan's chunk-local kernel pair, the experts' loop over tiles, and the whole under the chip's memory —
+    the scan's fused kernels (the state carried in VMEM), the experts' loop over tiles, and the whole under the chip's memory —
     it is the tight resource of that cell."""
     import json
 
@@ -442,10 +445,10 @@ def test_pattern_cell_train_step_fits_one_chip(topo):
     with mesh, nn.logical_axis_rules(DEFAULT_RULES):
         step = create_gspmd_train_step(mesh, DEFAULT_RULES, counters=True)
         compiled = step.lower(state, Batch(x=xy, y=xy), rng).compile()
-    # flash forward, dq and dk/dv; the scan's chunk-local forward and backward
+    # flash forward, dq and dk/dv; the scan's primal, residual-writing forward and backward
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 5
-    assert "gdn_chunk_local_fwd" in text and "gdn_chunk_local_bwd" in text
+    assert all(f"gdn_chunks_{leg}" in text for leg in ("fwd", "fwd_res", "bwd"))
     peak = compiled.memory_analysis().peak_memory_in_bytes
     print("peak_memory_in_bytes", peak)
     assert 0 < peak < V5E_HBM_BYTES

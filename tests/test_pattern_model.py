@@ -22,18 +22,23 @@ from tests.pattern_helpers import (  # noqa: F401  (cfg is a fixture)
 )
 
 
-def test_layer_plan_names_the_chunk_local_implementation(cfg):
-    """``layer_plan``'s ``gdn`` entry: the kernel pair with its tile and
-    ``vmem_limit_bytes`` at the benchmark cell's widths, the ``jax.numpy``
-    form at the toy's."""
+@pytest.mark.parametrize("which", ["cell", "toy"])
+def test_layer_plan_names_the_scan_implementation(cfg, which):
+    """``layer_plan``'s ``gdn`` entry: at the benchmark cell's widths one
+    Mosaic kernel a pass with the state carried in VMEM, its tile and
+    ``vmem_limit_bytes``; at the toy's the ``jax.numpy`` form with XLA's
+    scan carrying the state."""
+    if which == "toy":
+        toy = pattern.layer_plan(cfg)["gdn"]
+        assert (toy["kernel"], toy["carry"]) == ("xla", "scan")
+        assert "tile" not in toy and "vmem_limit_bytes" not in toy
+        return
     with open(os.path.join(REPO, "benchmark", "configs", "qwen3-next-80b-a3b.json")) as f:
         cell = ModelConfig(**json.load(f)["model"])
     gdn = pattern.layer_plan(cell)["gdn"]
-    assert gdn["kernel"] == "mosaic" and gdn["tile"] == [8, cell.gdn_chunk]
+    assert (gdn["kernel"], gdn["carry"]) == ("mosaic", "vmem") and gdn["tile"] == [8, cell.gdn_chunk]
     assert set(gdn["vmem_limit_bytes"]) == {"fwd", "bwd"}
     assert all(16 * 2**20 <= v < 32 * 2**20 for v in gdn["vmem_limit_bytes"].values())
-    toy = pattern.layer_plan(cfg)["gdn"]
-    assert toy["kernel"] == "xla" and "tile" not in toy and "vmem_limit_bytes" not in toy
 
 
 # ---------------------------------------------------------------------------

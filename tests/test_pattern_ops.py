@@ -1,7 +1,9 @@
 """The operators under a layer-pattern model, each against the plain reference
-or a dense form: the Gated DeltaNet scan and its chunk-local kernel pair, the
+or a dense form: the Gated DeltaNet scan and its fused kernels, the
 held experts' loop over tiles, and grouped KV heads in the flash kernels.
-Sizes and tolerances: ``tests/pattern_helpers.py``.
+Sizes and tolerances: ``tests/pattern_helpers.py``; what only the fused
+kernels have (the carried state, their three variants) is in
+``tests/test_pattern_scan_kernels.py``.
 """
 
 import jax
@@ -14,25 +16,11 @@ from dtc_tpu.ops.gated_delta import gated_delta_chunked
 from tests.pattern_helpers import (  # noqa: F401  (cfg is a fixture)
     LOOSE, TIGHT, as_model, cfg, close, layer_of, normed_input, ref, weights,
 )
+from tests.pattern_helpers import out_and_grads as _out_and_grads, scan_inputs as _scan_inputs
 
 
 # ---------------------------------------------------------------------------
 # the scan: chunked against the token recurrence
-
-
-def _scan_inputs(b, t, hk, h, dk, dv, seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
-    q = unit(jax.random.normal(ks[0], (b, t, hk, dk))) / np.sqrt(dk)
-    k = unit(jax.random.normal(ks[1], (b, t, hk, dk)))
-    v = jax.random.normal(ks[2], (b, t, h, dv))
-    g = -2.0 * jax.nn.softplus(jax.random.normal(ks[3], (b, t, h)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
-    return (q, k, v, g, beta), jax.random.normal(ks[5], (b, t, h, dv))
-
-
-def _out_and_grads(fn, args, co):
-    return (fn(*args), *jax.grad(lambda *a: jnp.sum(fn(*a) * co), argnums=range(5))(*args))
 
 
 @pytest.mark.parametrize("dtype,tol", [("float32", TIGHT), ("bfloat16", LOOSE)])
@@ -49,8 +37,8 @@ def test_gdn_chunked_equals_recurrence(chunk, dtype, tol):
 @pytest.mark.parametrize("dtype,tol", [("float32", TIGHT), ("bfloat16", LOOSE)])
 @pytest.mark.parametrize("key_heads", [1, 2])  # a key head serving two value heads, and one each
 def test_gdn_chunk_kernels_equal_recurrence_and_xla_form(monkeypatch, key_heads, dtype, tol):
-    """The chunk-local kernel pair (interpret mode, at a shape the gate
-    takes): output and all five gradients against the token recurrence at
+    """The fused kernels (interpret mode, at a shape the gate takes, two
+    chunks so the carry counts): output and all five gradients against the token recurrence at
     ``highest`` and against the ``jax.numpy`` form from the same inputs."""
     from dtc_tpu.ops import gated_delta as gd
 
@@ -142,6 +130,9 @@ def test_gdn_aligned_keys_slow_decay_stay_with_the_recurrence(monkeypatch, form)
     (12, 128, 128, 4, 2, False),     # a chunk off the sublane count
     (64, 128, 128, 12, 8, False),    # key heads that do not divide the value heads
     (64, 128, 128, 100, 2, False),   # no 8 heads a step, and all 100 are over the budget
+    (64, 256, 128, 8, 4, True),      # a (256, 128) state a head: 1 MiB of scratch
+    (64, 128, 128, 24, 8, True),     # key heads serve three: no eight are whole key heads, so all 24 a step
+    (64, 512, 512, 8, 8, False),     # 8 MiB of state a step, as scratch and as a block: over the budget
 ])
 def test_gdn_chunk_kernel_gate(chunk, dk, dv, hv, hk, takes):
     """The gate asks the planner; where it refuses, the ``jax.numpy`` form
@@ -153,8 +144,12 @@ def test_gdn_chunk_kernel_gate(chunk, dk, dv, hv, hk, takes):
     assert (plan is not None) == takes
     if takes:
         assert plan == vmem.gdn_chunk_plan(chunk, dk, dv, hv, hk) and hv % plan["tiles"] == 0
+        # whole key heads, and along the sublanes of the output's block a multiple of 8 or every head
+        assert plan["tiles"] % (hv // hk) == 0 and (plan["tiles"] % 8 == 0 or plan["tiles"] == hv)
+        state = plan["tiles"] * dk * dv * 4       # float32, carried in scratch across a row's chunks
         for leg in ("fwd", "bwd"):
-            assert plan[leg]["bytes"] <= vmem.VMEM_BUDGET_BYTES
+            # the scratch once, the saved-state block double-buffered
+            assert plan[leg]["scratch_bytes"] == state and 3 * state < plan[leg]["bytes"] <= vmem.VMEM_BUDGET_BYTES
             assert plan[leg]["vmem_limit_bytes"] > plan[leg]["bytes"] + plan[leg]["modeled_transient_bytes"]
     elif hv <= 4:
         args, _ = _scan_inputs(1, 2 * chunk, hk, hv, dk, dv)
