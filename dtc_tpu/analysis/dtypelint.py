@@ -54,9 +54,10 @@ ALLOWLIST: dict[str, frozenset[str]] = {
     "models/pattern.py": frozenset({
         "RMSNorm",           # zero-centred / plain RMS norm: fp32-mandated
         "rotary",            # cos / sin tables built in fp32
-        "GatedAttention",    # the output gate's sigmoid in fp32
+        "Attention",         # the output gate's sigmoid in fp32
         "GatedDeltaNet",     # decay, beta, L2 norms: fp32-mandated
-        "SharedExpertMoE",   # router, its softmax and the shared gate: fp32
+        "ShortConv",         # the taps and both gates in fp32 inside one fusion
+        "ExpertLayer",       # router, its scores, the selection bias, the shared gate: fp32
     }),
     "ops/gated_delta.py": frozenset({
         # The delta rule's decays, cumulative sums, carried state and every
@@ -97,6 +98,7 @@ ALLOWLIST: dict[str, frozenset[str]] = {
         # Held experts: fp32 gates, counters, matmul accumulators, the
         # combine's scatter-add target and the weights' gradient sums.
         "held_experts", "_held_tiles_fwd", "_held_tiles_bwd", "_mm",
+        "bias_swapped",  # a float32 counter, as held_experts' are
     }),
     "ops/overlap_collectives.py": frozenset({
         # fp32 MXU accumulation (preferred_element_type) in both ring

@@ -86,8 +86,14 @@ class AdapterConfig:
 
 #: The layer kinds a ``layer_pattern`` entry may name; ``models/pattern.py``
 #: maps each to its module.
-PATTERN_MIXERS = ("gdn", "gated_attn")
-PATTERN_FFNS = ("moe_shared",)
+PATTERN_MIXERS = ("gdn", "gated_attn", "shortconv", "attn")
+PATTERN_FFNS = ("moe_shared", "swiglu", "moe")
+
+
+def pattern_kinds(entry: str) -> tuple[str, str]:
+    """(mixer kind, ffn kind) of one ``"<mixer>+<ffn>"`` entry."""
+    mixer, _, ffn = str(entry).partition("+")
+    return mixer, ffn
 
 
 @dataclass(frozen=True)
@@ -196,14 +202,25 @@ class ModelConfig:
     adapter: AdapterConfig = field(default_factory=AdapterConfig)
     # --- Layer pattern (models/pattern.py). Empty: the GPT-2 block of
     # models/gpt.py in every layer. Otherwise one period of the stack, one
-    # "<mixer>+<ffn>" entry per position (mixers: gdn | gated_attn; ffns:
-    # moe_shared); n_layers is a whole number of periods and the layer scan
-    # runs over periods. The keys below are read by pattern layers only.
+    # "<mixer>+<ffn>" entry per position (mixers: gdn | gated_attn |
+    # shortconv | attn; ffns: moe_shared | swiglu | moe); the layer scan
+    # runs over periods. leading_pattern: layers of the same kinds that come
+    # ONCE, before the scanned periods (a model's leading dense layers);
+    # n_layers = len(leading_pattern) + periods x len(layer_pattern). The
+    # keys below are read by pattern layers only.
     layer_pattern: tuple = ()
-    norm_eps: float = 1e-6           # zero-centred RMSNorm epsilon
-    # gated_attn: n_heads query heads of attn_head_dim (0 = d_model /
+    leading_pattern: tuple = ()
+    norm_eps: float = 1e-6           # RMSNorm epsilon
+    # The gain of every RMS norm over the residual stream or a head:
+    # "zero_centred" (1 + w, w from 0) or "plain" (w from 1).
+    norm_gain: str = "zero_centred"
+    # The head's weight is the embedding, transposed: no lm_head leaf, and
+    # embed/wte gets both gradients.
+    tie_embeddings: bool = False
+    # gated_attn / attn: n_heads query heads of attn_head_dim (0 = d_model /
     # n_heads) on n_kv_heads KV heads (0 = n_heads); rotary positions on
-    # the first rope_fraction of each head, half-split pairing.
+    # the first rope_fraction of each head, half-split pairing. gated_attn's
+    # query projection also yields a per-head output gate; attn has none.
     n_kv_heads: int = 0
     attn_head_dim: int = 0
     rope_theta: float = 10000.0
@@ -217,17 +234,30 @@ class ModelConfig:
     gdn_key_dim: int = 0
     gdn_value_dim: int = 0
     gdn_conv_width: int = 4
-    # moe_shared: the router scores moe_experts and keeps moe_top_k, gates
-    # renormalised; this process holds experts [rank * held, (rank + 1) *
-    # held) (held 0 = all) of width moe_d_ff and computes only the
-    # assignments that fall on them, plus one shared SwiGLU expert of
-    # moe_shared_d_ff behind a sigmoid gate. Nothing is dropped and there
-    # is no bound: the held assignments run a tile of one expert's rows at
-    # a time, as many tiles as a step's routing fills.
+    # shortconv: one projection to (B, C, u) of d_model each, a depthwise
+    # causal convolution of shortconv_width taps over B * u, gated by C,
+    # then the output projection. No bias, no activation.
+    shortconv_width: int = 3
+    # swiglu: a dense SwiGLU of width d_ff.
+    # moe_shared / moe: the router scores moe_experts and keeps moe_top_k,
+    # gates renormalised; this process holds experts [rank * held, (rank +
+    # 1) * held) (held 0 = all) of width moe_d_ff and computes only the
+    # assignments that fall on them; moe_shared adds one shared SwiGLU
+    # expert of moe_shared_d_ff behind a sigmoid gate. Nothing is dropped
+    # and there is no bound: the held assignments run a tile of one
+    # expert's rows at a time, as many tiles as a step's routing fills.
+    # The router's form is the model's: moe_score "softmax" (the gate is
+    # the renormalised probability) or "sigmoid" (per-expert scores, the
+    # gate score / (sum of the chosen + 1e-6) * moe_routed_scale);
+    # moe_selection_bias adds a float32 leaf per expert to the scores for
+    # the CHOICE only (the gate is the unbiased score).
     moe_experts_held: int = 0
     moe_expert_rank: int = 0
     moe_d_ff: int = 0
     moe_shared_d_ff: int = 0
+    moe_score: str = "softmax"
+    moe_selection_bias: bool = False
+    moe_routed_scale: float = 1.0
 
     def __post_init__(self) -> None:
         if self.d_model % self.n_heads != 0:
@@ -310,13 +340,19 @@ class ModelConfig:
                 f"forward), got {self.attention_block_q_bwd}/"
                 f"{self.attention_block_kv_bwd}"
             )
-        if isinstance(self.layer_pattern, list):
-            object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        for name in ("layer_pattern", "leading_pattern"):
+            if isinstance(getattr(self, name), list):
+                object.__setattr__(self, name, tuple(getattr(self, name)))
         # YAML 1.1 reads "1e-06" (what json.dump writes) as a string.
-        for name in ("norm_eps", "rope_theta", "rope_fraction"):
+        for name in ("norm_eps", "rope_theta", "rope_fraction", "moe_routed_scale"):
             object.__setattr__(self, name, float(getattr(self, name)))
         if self.layer_pattern:
             self._check_pattern()
+        elif self.leading_pattern or self.tie_embeddings:
+            raise ValueError(
+                "leading_pattern and tie_embeddings belong to a layer-pattern "
+                "model (layer_pattern set); the GPT-2 block has neither"
+            )
         if self.remat_mode not in ("none", "block", "block_save_flash", "mlp"):
             raise ValueError(
                 f"unknown remat {self.remat!r}; expected bool, 'none', 'block', "
@@ -325,18 +361,27 @@ class ModelConfig:
 
     def _check_pattern(self) -> None:
         """Cross-field rules of a pattern model (``layer_pattern`` set)."""
-        kinds = [self.layer_kinds(i) for i in range(len(self.layer_pattern))]
-        for entry, (mixer, ffn) in zip(self.layer_pattern, kinds):
+        kinds = [(mixer, ffn) for mixer, ffn, _ in self.layer_census()]
+        for entry, (mixer, ffn) in zip(self.leading_pattern + self.layer_pattern, kinds):
             if mixer not in PATTERN_MIXERS or ffn not in PATTERN_FFNS:
                 raise ValueError(
                     f"layer_pattern entry {entry!r}: expected '<mixer>+<ffn>' "
                     f"with mixer in {sorted(PATTERN_MIXERS)} and ffn in {sorted(PATTERN_FFNS)}"
                 )
-        if self.n_layers % len(self.layer_pattern):
+        scanned = self.n_layers - len(self.leading_pattern)
+        if scanned <= 0 or scanned % len(self.layer_pattern):
             raise ValueError(
-                f"n_layers={self.n_layers} is not a whole number of periods "
-                f"of {len(self.layer_pattern)} layers"
+                f"n_layers={self.n_layers} is not {len(self.leading_pattern)} leading "
+                f"layer(s) and a whole number of periods of {len(self.layer_pattern)} layers"
             )
+        if self.norm_gain not in ("zero_centred", "plain"):
+            raise ValueError(f"unknown norm_gain {self.norm_gain!r}; expected "
+                             "'zero_centred' or 'plain'")
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_score {self.moe_score!r}; expected "
+                             "'softmax' or 'sigmoid'")
+        if self.shortconv_width < 1:
+            raise ValueError("shortconv_width must be >= 1")
         if self.dropout or self.adapter.rank:
             raise ValueError("pattern layers have no dropout and no adapters")
         if self.n_heads % self.kv_heads:
@@ -355,11 +400,12 @@ class ModelConfig:
                     f"max_seq_len={self.max_seq_len} is not a multiple of "
                     f"the scan's chunk of {self.gdn_chunk}"
                 )
-        if any(f == "moe_shared" for _, f in kinds):
+        if any(f in ("moe_shared", "moe") for _, f in kinds):
             held = self.experts_held
-            if self.moe_experts <= 0 or self.moe_d_ff <= 0 or self.moe_shared_d_ff <= 0:
-                raise ValueError("moe_shared layers need moe_experts, moe_d_ff "
-                                 "and moe_shared_d_ff")
+            if self.moe_experts <= 0 or self.moe_d_ff <= 0:
+                raise ValueError("expert layers need moe_experts and moe_d_ff")
+            if any(f == "moe_shared" for _, f in kinds) and self.moe_shared_d_ff <= 0:
+                raise ValueError("moe_shared layers need moe_shared_d_ff")
             if self.moe_experts % held or not 0 <= self.moe_expert_rank < self.moe_experts // held:
                 raise ValueError(
                     f"moe_experts_held={held} must divide moe_experts="
@@ -367,10 +413,18 @@ class ModelConfig:
                     f"{self.moe_expert_rank} name one of the shares"
                 )
 
-    def layer_kinds(self, position: int) -> tuple[str, str]:
-        """(mixer kind, ffn kind) of one position of the period."""
-        mixer, _, ffn = str(self.layer_pattern[position]).partition("+")
-        return mixer, ffn
+    @property
+    def pattern_periods(self) -> int:
+        """Periods of ``layer_pattern`` the layer scan runs over."""
+        return (self.n_layers - len(self.leading_pattern)) // len(self.layer_pattern)
+
+    def layer_census(self) -> list[tuple[str, str, int]]:
+        """(mixer kind, ffn kind, how many such layers) in the stack's
+        order: each leading layer once, each position of the period once
+        a period."""
+        entries = [(e, 1) for e in self.leading_pattern]
+        entries += [(e, self.pattern_periods) for e in self.layer_pattern]
+        return [(*pattern_kinds(e), n) for e, n in entries]
 
     @property
     def head_dim(self) -> int:

@@ -1,30 +1,39 @@
 """Models described by a layer pattern.
 
 ``ModelConfig.layer_pattern`` states ONE period of the stack: per position
-a mixer kind and an FFN kind (``"gdn+moe_shared"``). The schema lists the
-kinds' names and this file maps them to modules (:data:`MIXERS`,
-:data:`FFNS`); :class:`PatternLM` scans over periods, each layer under its
-own ``nn.remat`` as ``GPTStage`` does, and :func:`build_model` gives
-``trainer.train`` this model or ``GPT`` from the configuration alone. An
-empty pattern is the GPT-2 block of ``models/gpt.py``, untouched.
+a mixer kind and an FFN kind (``"gdn+moe_shared"``);
+``ModelConfig.leading_pattern`` the layers that come once before the
+periods (a model's leading dense layers). The schema lists the kinds' names
+and this file maps them to modules (:data:`MIXERS`, :data:`FFNS`);
+:class:`PatternLM` runs the leading layers, then scans over periods, each
+layer under its own ``nn.remat`` as ``GPTStage`` does, and
+:func:`build_model` gives ``trainer.train`` this model or ``GPT`` from the
+configuration alone. An empty pattern is the GPT-2 block of
+``models/gpt.py``, untouched.
 
 Every pattern layer is pre-norm and residual::
 
     x += mixer(rms(x; w1));  x += ffn(rms(x; w2))
-    rms(x; w) = x * rsqrt(mean(x^2) + eps) * (1 + w)       (zero-centred gain)
+    rms(x; w) = x * rsqrt(mean(x^2) + eps) * (1 + w)       (norm_gain zero_centred)
+              = x * rsqrt(mean(x^2) + eps) * w             (norm_gain plain)
 
 with a token embedding only (positions are the mixers' business), a final
-``rms`` and an untied head without bias through the fused head+CE op that
-``GPTHead`` uses. No biases, no dropout.
+``rms`` and a head without bias — its own leaf, or with ``tie_embeddings``
+the embedding transposed — through the fused head+CE op that ``GPTHead``
+uses. No biases, no dropout.
 
 Mixers:
 
-- ``gated_attn`` — softmax attention whose query projection also yields a
-  per-head output gate; ``n_heads`` query heads on ``n_kv_heads`` KV heads
-  of ``head_dim``; q and k RMS-normed over the head; rotary positions on the
-  first ``rope_fraction`` of the head, half-split pairing. Through
+- ``gated_attn`` / ``attn`` — softmax attention, ``n_heads`` query heads on
+  ``n_kv_heads`` KV heads of ``head_dim``; q and k RMS-normed over the
+  head; rotary positions on the first ``rope_fraction`` of the head,
+  half-split pairing. ``gated_attn``'s query projection also yields a
+  per-head output gate; ``attn`` has none. Through
   ``ops/attention.causal_attention`` (flash on the chip, KV groups picked by
   the kernels' index maps).
+- ``shortconv`` — a gated short convolution: one projection to (B, C, u),
+  ``out_proj(C * conv(B * u))`` with a depthwise causal convolution of
+  ``shortconv_width`` taps, no bias and no activation.
 - ``gdn`` — Gated DeltaNet (``ops/gated_delta.py``): one fused projection
   to (q, k, v, z), a second to (b, a); a depthwise causal convolution and
   SiLU over (q, k, v); ``beta = sigmoid(b)``, ``g = -exp(A_log) *
@@ -33,18 +42,24 @@ Mixers:
 
 FFN:
 
-- ``moe_shared`` — the router scores ``moe_experts``, keeps ``moe_top_k``
-  (gates renormalised), and this process computes the part of the sum that
-  its held experts give (``ops/moe_dispatch.held_experts``: nothing
-  dropped) plus a shared SwiGLU expert behind a sigmoid gate.
+- ``moe_shared`` / ``moe`` — the router scores ``moe_experts``, keeps
+  ``moe_top_k`` (gates renormalised), and this process computes the part of
+  the sum that its held experts give (``ops/moe_dispatch.held_experts``:
+  nothing dropped); ``moe_shared`` adds a shared SwiGLU expert behind a
+  sigmoid gate. The router's form is the model's (``moe_score``,
+  ``moe_selection_bias``, ``moe_routed_scale``): softmax probabilities, or
+  sigmoid scores chosen with a per-expert bias that never enters the gate.
+- ``swiglu`` — a dense SwiGLU of width ``d_ff``.
 
-The float32 islands are the norms, the router and its softmax, the decay
-and the scan's carried state; the matmuls run in ``compute_dtype``.
+The float32 islands are the norms, the router and its scores, the short
+convolutions' taps, the decay and the scan's carried state; the matmuls run
+in ``compute_dtype``.
 
 Scopes on the device path (``benchmark/spans.py`` reads the op-name path):
-``gdn`` with ``proj`` / ``conv`` / ``scan`` / ``out``; ``attn_full`` with
-``attn_kernel`` around the kernel call; ``moe`` with ``router`` /
-``dispatch`` / ``experts`` / ``combine`` / ``shared``; ``head``.
+``gdn`` with ``proj`` / ``conv`` / ``scan`` / ``out``; ``shortconv`` with
+``proj`` / ``conv`` / ``out``; ``attn_full`` with ``attn_kernel`` around the
+kernel call; ``moe`` with ``router`` / ``dispatch`` / ``experts`` /
+``combine`` / ``shared``; ``mlp``; ``head``.
 """
 
 from __future__ import annotations
@@ -56,19 +71,25 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from dtc_tpu.config.schema import ModelConfig
+from dtc_tpu.config.schema import ModelConfig, pattern_kinds
 from dtc_tpu.models.gpt import _dtype
 from dtc_tpu.ops import moe_dispatch as md
 from dtc_tpu.ops.attention import causal_attention
 from dtc_tpu.ops.gated_delta import gated_delta_chunked, supports_chunk_kernel
 
 #: The per-step counters a pattern model sows (collection ``counters``),
-#: one row a layer; the train step returns them beside the loss.
-COUNTERS = md.HELD_COUNTERS
+#: one row an expert layer; the train step returns them beside the loss.
+#: The last only where the router chooses with a selection bias: the
+#: choices plain top-k of the scores would not have made.
+COUNTERS = (*md.HELD_COUNTERS, "moe_bias_swapped")
+
+#: ``moe_score: sigmoid``: the chosen scores are normalised over their sum
+#: plus this.
+SIGMOID_GATE_EPS = 1e-6
 
 NOT_SERVED = (
     "a layer-pattern model trains only: there is no cache for recurrent "
-    "state yet, so generate / ServingEngine cannot run it"
+    "state yet, nor a convolution's, so generate / ServingEngine cannot run it"
 )
 
 
@@ -93,6 +114,10 @@ class RMSNorm(nn.Module):
         return x * (1.0 + w if self.zero_centred else w)
 
 
+def _norm(cfg: ModelConfig, name: str) -> RMSNorm:
+    return RMSNorm(cfg.norm_eps, zero_centred=cfg.norm_gain == "zero_centred", name=name)
+
+
 def rotary(x: jax.Array, theta: float, fraction: float) -> jax.Array:
     """Rotary positions on the first ``fraction`` of the last axis of
     ``x`` (B, T, H, D), half-split pairing; the rest passes."""
@@ -110,8 +135,12 @@ def rotary(x: jax.Array, theta: float, fraction: float) -> jax.Array:
     return jnp.concatenate([xr, rest], -1)
 
 
-class GatedAttention(nn.Module):
+class Attention(nn.Module):
+    """``gated``: the query projection is twice as wide and its second half
+    gates the heads' output through a sigmoid."""
+
     cfg: ModelConfig
+    gated: bool
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -120,12 +149,15 @@ class GatedAttention(nn.Module):
         h, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
         cdtype = _dtype(cfg.compute_dtype)
         with jax.named_scope("attn_qkv"):
-            qg = _dense(h * 2 * hd, "q_proj", cfg)(x).reshape(b, t, h, 2 * hd)
-            q, gate = qg[..., :hd], qg[..., hd:]
+            if self.gated:
+                qg = _dense(h * 2 * hd, "q_proj", cfg)(x).reshape(b, t, h, 2 * hd)
+                q, gate = qg[..., :hd], qg[..., hd:]
+            else:
+                q = _dense(h * hd, "q_proj", cfg)(x).reshape(b, t, h, hd)
             k = _dense(hk * hd, "k_proj", cfg)(x).reshape(b, t, hk, hd)
             v = _dense(hk * hd, "v_proj", cfg)(x).reshape(b, t, hk, hd)
-            q = rotary(RMSNorm(cfg.norm_eps, name="q_norm")(q), cfg.rope_theta, cfg.rope_fraction)
-            k = rotary(RMSNorm(cfg.norm_eps, name="k_norm")(k), cfg.rope_theta, cfg.rope_fraction)
+            q = rotary(_norm(cfg, "q_norm")(q), cfg.rope_theta, cfg.rope_fraction)
+            k = rotary(_norm(cfg, "k_norm")(k), cfg.rope_theta, cfg.rope_fraction)
             q, k = q.astype(cdtype), k.astype(cdtype)
         with jax.named_scope("attn_kernel"):
             out = causal_attention(
@@ -133,7 +165,8 @@ class GatedAttention(nn.Module):
                 block_q=cfg.attention_block_q, block_kv=cfg.attention_block_kv,
             )
         with jax.named_scope("attn_proj"):
-            out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(cdtype)
+            if self.gated:
+                out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(cdtype)
             return _dense(cfg.d_model, "out_proj", cfg)(out.reshape(b, t, h * hd))
 
 
@@ -190,6 +223,26 @@ class GatedDeltaNet(nn.Module):
             o = RMSNorm(cfg.norm_eps, zero_centred=False, name="norm")(o)
             o = (o * jax.nn.silu(z.astype(f32))).astype(cdtype)
             return _dense(cfg.d_model, "out_proj", cfg)(o.reshape(b, t, nv))
+
+
+class ShortConv(nn.Module):
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        d = cfg.d_model
+        cdtype, f32 = _dtype(cfg.compute_dtype), jnp.float32
+        with jax.named_scope("proj"):
+            bcu = _dense(3 * d, "in_proj", cfg)(x)
+        with jax.named_scope("conv"):
+            w = self.param("conv", nn.initializers.lecun_normal(), (d, cfg.shortconv_width),
+                           _dtype(cfg.param_dtype))
+            # float32 inside the fusion, compute dtype in HBM
+            gate_in, gate_out, u = (bcu[..., i * d: (i + 1) * d].astype(f32) for i in range(3))
+            y = (gate_out * causal_depthwise_conv(gate_in * u, w.astype(f32))).astype(cdtype)
+        with jax.named_scope("out"):
+            return _dense(d, "out_proj", cfg)(y)
 
 
 class SwiGLU(nn.Module):
@@ -281,8 +334,12 @@ def _per_data_shard(fn, where, x, *rest):
     )(x, *rest)
 
 
-class SharedExpertMoE(nn.Module):
+class ExpertLayer(nn.Module):
+    """``shared``: one SwiGLU expert of ``moe_shared_d_ff`` behind a sigmoid
+    gate is added to the held experts' part of the routed sum."""
+
     cfg: ModelConfig
+    shared: bool
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -296,54 +353,74 @@ class SharedExpertMoE(nn.Module):
         w_down = self.param("w_down", init, (held, f, d), pdtype)
         with jax.named_scope("router"):
             logits = nn.Dense(e, name="router", use_bias=False, dtype=jnp.float32,
-                              param_dtype=jnp.float32)(x.astype(jnp.float32))
-            gates, idx = md.top_k_gates(jax.nn.softmax(logits.reshape(b * t, e), axis=-1), k)
+                              param_dtype=jnp.float32)(x.astype(jnp.float32)).reshape(b * t, e)
+            sigmoid = cfg.moe_score == "sigmoid"
+            scores = jax.nn.sigmoid(logits) if sigmoid else jax.nn.softmax(logits, axis=-1)
+            bias = (self.param("expert_bias", nn.initializers.zeros_init(), (e,), jnp.float32)
+                    if cfg.moe_selection_bias else None)
+            gates, idx = md.top_k_gates(
+                scores, k, bias=bias, eps=SIGMOID_GATE_EPS if sigmoid else 0.0,
+                scale=cfg.moe_routed_scale)
         y, counters = _per_data_shard(
             functools.partial(md.held_experts, first=cfg.moe_expert_rank * held),
             _data_axis(b), x.reshape(b * t, d), gates, idx,
             w_gate.astype(cdtype), w_up.astype(cdtype), w_down.astype(cdtype),
         )
+        if bias is not None:
+            with jax.named_scope("router"):
+                counters = jnp.append(counters, md.bias_swapped(scores, idx))
         self.sow("counters", "moe", counters)
-        with jax.named_scope("shared"):
-            gate = nn.Dense(1, name="shared_gate", use_bias=False, dtype=jnp.float32,
-                            param_dtype=pdtype)(x.astype(jnp.float32))
-            shared = SwiGLU(cfg, cfg.moe_shared_d_ff, name="shared")(x)
-            y = y.reshape(b, t, d) + jax.nn.sigmoid(gate) * shared.astype(jnp.float32)
+        y = y.reshape(b, t, d)
+        if self.shared:
+            with jax.named_scope("shared"):
+                gate = nn.Dense(1, name="shared_gate", use_bias=False, dtype=jnp.float32,
+                                param_dtype=pdtype)(x.astype(jnp.float32))
+                shared = SwiGLU(cfg, cfg.moe_shared_d_ff, name="shared")(x)
+                y = y + jax.nn.sigmoid(gate) * shared.astype(jnp.float32)
         return y.astype(cdtype)
 
 
-#: mixer kind -> (module, its scope on the device path)
-MIXERS = {"gdn": (GatedDeltaNet, "gdn"), "gated_attn": (GatedAttention, "attn_full")}
+#: mixer kind -> (module of a configuration, its scope on the device path)
+MIXERS = {
+    "gdn": (GatedDeltaNet, "gdn"),
+    "gated_attn": (functools.partial(Attention, gated=True), "attn_full"),
+    "attn": (functools.partial(Attention, gated=False), "attn_full"),
+    "shortconv": (ShortConv, "shortconv"),
+}
 #: ffn kind -> (module, scope)
-FFNS = {"moe_shared": (SharedExpertMoE, "moe")}
+FFNS = {
+    "moe_shared": (functools.partial(ExpertLayer, shared=True), "moe"),
+    "moe": (functools.partial(ExpertLayer, shared=False), "moe"),
+    "swiglu": (lambda cfg, name: SwiGLU(cfg, cfg.d_ff, name=name), "mlp"),
+}
 
 
 class PatternBlock(nn.Module):
     cfg: ModelConfig
-    position: int
+    kinds: tuple[str, str]
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         cfg = self.cfg
         cdtype = _dtype(cfg.compute_dtype)
-        mixer, ffn = cfg.layer_kinds(self.position)
-        (mixer_cls, mixer_name), (ffn_cls, ffn_name) = MIXERS[mixer], FFNS[ffn]
-        h = RMSNorm(cfg.norm_eps, name="norm_1")(x).astype(cdtype)
+        (mixer_cls, mixer_name), (ffn_cls, ffn_name) = MIXERS[self.kinds[0]], FFNS[self.kinds[1]]
+        h = _norm(cfg, "norm_1")(x).astype(cdtype)
         x = x + mixer_cls(cfg, name=mixer_name)(h)
-        h = RMSNorm(cfg.norm_eps, name="norm_2")(x).astype(cdtype)
+        h = _norm(cfg, "norm_2")(x).astype(cdtype)
         x = x + ffn_cls(cfg, name=ffn_name)(h)
         return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
 
-class _Period(nn.Module):
-    """One period of the pattern under ``nn.scan``: its layers in order,
-    each under its own remat."""
+class _Layers(nn.Module):
+    """The layers of ``entries`` in order, each under its own remat: one
+    period of the pattern under ``nn.scan``, or the leading layers once."""
 
     cfg: ModelConfig
     train: bool
+    entries: tuple
 
     @nn.compact
-    def __call__(self, h, _):
+    def __call__(self, h, _=None):
         cls = PatternBlock
         mode = self.cfg.remat_mode
         if mode != "none" and self.train:
@@ -356,21 +433,25 @@ class _Period(nn.Module):
                 kwargs["policy"] = jax.checkpoint_policies.save_only_these_names(
                     "flash_out", "flash_lse", "flash_q", "flash_k", "flash_v")
             cls = nn.remat(cls, **kwargs)
-        for position in range(len(self.cfg.layer_pattern)):
-            h = cls(self.cfg, position, name=f"layer_{position}")(h)
+        for position, entry in enumerate(self.entries):
+            h = cls(self.cfg, pattern_kinds(entry), name=f"layer_{position}")(h)
         return h, None
 
 
 class PatternEmbed(nn.Module):
     cfg: ModelConfig
 
-    @nn.compact
+    def setup(self):
+        self.wte = nn.Embed(self.cfg.padded_vocab_size, self.cfg.d_model,
+                            param_dtype=_dtype(self.cfg.param_dtype))
+
     def __call__(self, x: jax.Array) -> jax.Array:
-        cfg = self.cfg
-        wte = nn.Embed(cfg.padded_vocab_size, cfg.d_model, name="wte",
-                       param_dtype=_dtype(cfg.param_dtype))
         return nn.with_logical_constraint(
-            wte(x).astype(_dtype(cfg.compute_dtype)), ("batch", "seq", "embed"))
+            self.wte(x).astype(_dtype(self.cfg.compute_dtype)), ("batch", "seq", "embed"))
+
+    def table(self) -> jax.Array:
+        """The embedding (vocab, d): a tied head's weight, transposed."""
+        return self.wte.embedding
 
 
 class PatternStage(nn.Module):
@@ -379,34 +460,43 @@ class PatternStage(nn.Module):
     @nn.compact
     def __call__(self, h: jax.Array, *, train: bool) -> jax.Array:
         cfg = self.cfg
+        if cfg.leading_pattern:
+            h, _ = _Layers(cfg, train, cfg.leading_pattern, name="leading")(h)
         scanned = nn.scan(
-            _Period,
+            _Layers,
             variable_axes={"params": 0, "counters": 0},
             split_rngs={"params": True},
-            length=cfg.n_layers // len(cfg.layer_pattern),
+            length=cfg.pattern_periods,
             metadata_params={nn.PARTITION_NAME: "layers"},
-        )(cfg, train, name="periods")
+        )(cfg, train, cfg.layer_pattern, name="periods")
         h, _ = scanned(h, None)
         return h
 
 
 class PatternHead(nn.Module):
-    """Final RMS norm and the untied head without bias, through the fused
-    head + cross-entropy op (``ops/fused_ce.py``) when ``targets`` are
-    given. The op folds a bias gradient into its dW matmul; the zero bias
-    passed here is a constant, its gradient discarded."""
+    """Final RMS norm and the head without bias, through the fused head +
+    cross-entropy op (``ops/fused_ce.py``) when ``targets`` are given. The
+    head's weight is this module's ``lm_head`` leaf, or with
+    ``tie_embeddings`` the embedding ``tied`` (vocab, d) that the caller
+    hands in, transposed: that leaf then gets both gradients. The op folds a
+    bias gradient into its dW matmul; the zero bias passed here is a
+    constant, its gradient discarded."""
 
     cfg: ModelConfig
 
     @nn.compact
-    def __call__(self, h: jax.Array, targets: jax.Array | None = None) -> jax.Array:
+    def __call__(self, h: jax.Array, targets: jax.Array | None = None,
+                 tied: jax.Array | None = None) -> jax.Array:
         from dtc_tpu.ops.fused_ce import fused_head_ce, head_logits
 
         cfg = self.cfg
         pdtype = _dtype(cfg.param_dtype)
-        h = RMSNorm(cfg.norm_eps, name="norm_f")(h).astype(_dtype(cfg.compute_dtype))
-        kernel = self.param("lm_head", nn.initializers.lecun_normal(),
-                            (cfg.d_model, cfg.padded_vocab_size), pdtype)
+        h = _norm(cfg, "norm_f")(h).astype(_dtype(cfg.compute_dtype))
+        if tied is not None:
+            kernel = tied.T
+        else:
+            kernel = self.param("lm_head", nn.initializers.lecun_normal(),
+                                (cfg.d_model, cfg.padded_vocab_size), pdtype)
         bias = jnp.zeros((cfg.padded_vocab_size,), pdtype)
         if targets is not None:
             return fused_head_ce(h, kernel, bias, targets, cfg.vocab_size)
@@ -415,8 +505,10 @@ class PatternHead(nn.Module):
 
 class PatternLM(nn.Module):
     """Decoder-only model of a layer pattern. Param tree ``{"embed",
-    "stage": {"periods": {"layer_<i>": ...}}, "head"}``, every layer leaf
-    stacked over periods. Training and evaluation only."""
+    "stage": {"leading": {"layer_<i>": ...}, "periods": {"layer_<i>":
+    ...}}, "head"}``: a period's leaves stacked over periods, the leading
+    layers' (where the configuration has any) plain; no ``head/lm_head``
+    with ``tie_embeddings``. Training and evaluation only."""
 
     cfg: ModelConfig
 
@@ -425,9 +517,10 @@ class PatternLM(nn.Module):
                  targets: jax.Array | None = None) -> jax.Array:
         if decode:
             raise NotImplementedError(NOT_SERVED)
-        h = PatternEmbed(self.cfg, name="embed")(x)
-        h = PatternStage(self.cfg, name="stage")(h, train=train)
-        return PatternHead(self.cfg, name="head")(h, targets=targets)
+        embed = PatternEmbed(self.cfg, name="embed")
+        h = PatternStage(self.cfg, name="stage")(embed(x), train=train)
+        tied = embed.table() if self.cfg.tie_embeddings else None
+        return PatternHead(self.cfg, name="head")(h, targets=targets, tied=tied)
 
 
 def build_model(cfg: ModelConfig) -> nn.Module:
@@ -446,41 +539,55 @@ def pattern_param_count(cfg: ModelConfig) -> int:
     d = cfg.d_model
     nk, nv = cfg.gdn_key_heads * cfg.gdn_key_dim, cfg.gdn_value_heads * cfg.gdn_value_dim
     hd = cfg.head_dim
+    attn = d * cfg.n_heads * hd + 2 * d * cfg.kv_heads * hd + 2 * hd + cfg.n_heads * hd * d
+    routed = (d * cfg.moe_experts + cfg.experts_held * 3 * d * cfg.moe_d_ff
+              + cfg.moe_selection_bias * cfg.moe_experts)
     per = {
         "gdn": d * (2 * nk + 2 * nv) + d * 2 * cfg.gdn_value_heads
         + (2 * nk + nv) * cfg.gdn_conv_width + 2 * cfg.gdn_value_heads + cfg.gdn_value_dim + nv * d,
-        "gated_attn": d * 2 * cfg.n_heads * hd + 2 * d * cfg.kv_heads * hd + 2 * hd
-        + cfg.n_heads * hd * d,
-        "moe_shared": d * cfg.moe_experts + cfg.experts_held * 3 * d * cfg.moe_d_ff
-        + 3 * d * cfg.moe_shared_d_ff + d,
+        "gated_attn": attn + d * cfg.n_heads * hd,
+        "attn": attn,
+        "shortconv": d * 3 * d + d * cfg.shortconv_width + d * d,
+        "moe_shared": routed + 3 * d * cfg.moe_shared_d_ff + d,
+        "moe": routed,
+        "swiglu": 3 * d * cfg.d_ff,
     }
-    kinds = [cfg.layer_kinds(i) for i in range(len(cfg.layer_pattern))]
-    period = sum(per[m] + per[f] + 2 * d for m, f in kinds)
-    periods = cfg.n_layers // len(cfg.layer_pattern)
-    return periods * period + 2 * cfg.padded_vocab_size * d + d
+    layers = sum(n * (per[m] + per[f] + 2 * d) for m, f, n in cfg.layer_census())
+    return layers + (1 if cfg.tie_embeddings else 2) * cfg.padded_vocab_size * d + d
+
+
+def _attention_plan(cfg: ModelConfig) -> dict:
+    from dtc_tpu.ops.attention import resolve_impl
+
+    return {
+        "kernel": resolve_impl(cfg.attention, cfg.max_seq_len, cfg.head_dim,
+                               cfg.attention_block_q, cfg.attention_block_kv),
+        "heads": cfg.n_heads, "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim,
+        "block_q": min(cfg.attention_block_q, cfg.max_seq_len),
+        "block_kv": min(cfg.attention_block_kv, cfg.max_seq_len),
+        "rotary_dims": int(cfg.head_dim * cfg.rope_fraction),
+    }
 
 
 def layer_plan(cfg: ModelConfig) -> dict:
     """Fields of the trainer's one ``layer_plan`` start-up event: the
     pattern, and per mixer kind the kernel and tiles it will run."""
-    from dtc_tpu.ops.attention import resolve_impl
-
-    kinds = [cfg.layer_kinds(i) for i in range(len(cfg.layer_pattern))]
+    mixers = {m for m, _, _ in cfg.layer_census()}
     plan: dict = {
         "pattern": list(cfg.layer_pattern),
-        "periods": cfg.n_layers // len(cfg.layer_pattern),
+        "periods": cfg.pattern_periods,
         "remat": cfg.remat_mode,
     }
-    if any(m == "gated_attn" for m, _ in kinds):
-        plan["gated_attn"] = {
-            "kernel": resolve_impl(cfg.attention, cfg.max_seq_len, cfg.head_dim,
-                                   cfg.attention_block_q, cfg.attention_block_kv),
-            "heads": cfg.n_heads, "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim,
-            "block_q": min(cfg.attention_block_q, cfg.max_seq_len),
-            "block_kv": min(cfg.attention_block_kv, cfg.max_seq_len),
-            "rotary_dims": int(cfg.head_dim * cfg.rope_fraction),
-        }
-    if any(m == "gdn" for m, _ in kinds):
+    if cfg.leading_pattern:
+        plan["leading"] = list(cfg.leading_pattern)
+    for kind in ("gated_attn", "attn"):
+        if kind in mixers:
+            plan[kind] = _attention_plan(cfg)
+    if "shortconv" in mixers:
+        # the taps as shifted multiply-adds that XLA fuses with the two gates
+        plan["shortconv"] = {"width": cfg.shortconv_width, "channels": cfg.d_model,
+                             "implementation": "xla"}
+    if "gdn" in mixers:
         from dtc_tpu.config.schema import DTYPE_BYTES
 
         local = supports_chunk_kernel(
@@ -506,13 +613,16 @@ def layer_plan(cfg: ModelConfig) -> dict:
 def moe_plan(cfg: ModelConfig, tokens_per_device: int) -> dict | None:
     """Fields of the ``moe_plan`` start-up event, or None without an
     expert layer."""
-    if not any(cfg.layer_kinds(i)[1] == "moe_shared" for i in range(len(cfg.layer_pattern))):
+    ffns = {f for _, f, _ in cfg.layer_census()}
+    if not ffns & {"moe_shared", "moe"}:
         return None
     held, k = cfg.experts_held, cfg.moe_top_k
     return {
         "experts_published": cfg.moe_experts, "experts_held": held,
         "rank": cfg.moe_expert_rank, "first_expert": cfg.moe_expert_rank * held,
-        "top_k": k, "expert_width": cfg.moe_d_ff, "shared_width": cfg.moe_shared_d_ff,
+        "top_k": k, "expert_width": cfg.moe_d_ff,
+        "shared_width": cfg.moe_shared_d_ff if "moe_shared" in ffns else 0,
+        "score": cfg.moe_score, "selection_bias": cfg.moe_selection_bias,
         "tokens_per_device": tokens_per_device, "tile_rows": md.HELD_TILE_ROWS,
         "expected_held": tokens_per_device * k * held / cfg.moe_experts,
     }

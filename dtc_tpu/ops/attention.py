@@ -118,6 +118,8 @@ def flash_plan_event(cfg) -> dict | None:
     t, d = cfg.max_seq_len, cfg.head_dim
     if resolve_impl(cfg.attention, t, d, *blocks[:2]) != "flash":
         return None
+    if cfg.kv_heads != cfg.n_heads:
+        return None  # KV groups: the transposed family, tiled as configured
     plan = flash_attention.schedule(
         t, cfg.n_heads, d, DTYPE_BYTES.get(cfg.compute_dtype, 4), *blocks
     )

@@ -270,11 +270,32 @@ HELD_TILE_ROWS = 512
 HELD_GROUP_TILES = 16
 
 
-def top_k_gates(probs: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
-    """The ``k`` largest of ``probs`` (N, E) per row, renormalised to sum 1:
-    (gates (N, k) float32, expert ids (N, k) int32)."""
-    top, idx = jax.lax.top_k(probs, k)
-    return top / jnp.sum(top, axis=-1, keepdims=True), idx.astype(jnp.int32)
+def top_k_gates(scores: jax.Array, k: int, *, bias: jax.Array | None = None,
+                eps: float = 0.0, scale: float = 1.0) -> tuple[jax.Array, jax.Array]:
+    """``k`` experts per row of ``scores`` (N, E) and their gates,
+    renormalised: (gates (N, k) float32, expert ids (N, k) int32). The
+    choice is by the largest ``scores`` — softmax probabilities, or
+    per-expert sigmoid scores — plus, where given, a selection ``bias``
+    (E,) that enters the choice ONLY: the gate is the chosen experts' own
+    score over ``sum + eps``, times ``scale``, so no gradient reaches the
+    bias. Without ``bias``, ``eps`` and ``scale`` the arithmetic is the
+    softmax form's, operation for operation."""
+    if bias is None:
+        top, idx = jax.lax.top_k(scores, k)
+    else:
+        _, idx = jax.lax.top_k(scores + bias, k)
+        top = jnp.take_along_axis(scores, idx, axis=-1)
+    total = jnp.sum(top, axis=-1, keepdims=True)
+    gates = top / (total + eps if eps else total)
+    return (gates * scale if scale != 1.0 else gates), idx.astype(jnp.int32)
+
+
+def bias_swapped(scores: jax.Array, idx: jax.Array) -> jax.Array:
+    """How many of the choices ``idx`` (N, k) plain top-``k`` of ``scores``
+    (N, E) would not have made: those whose own score is under the row's
+    ``k``-th largest. A float32 scalar."""
+    kth = jax.lax.top_k(scores, idx.shape[1])[0][:, -1:]
+    return jnp.sum(jnp.take_along_axis(scores, idx, axis=-1) < kth).astype(jnp.float32)
 
 
 class _Tiles(NamedTuple):

@@ -221,7 +221,9 @@ PARAM_AXES_TABLE: tuple[tuple[tuple[str, ...], tuple[str | None, ...]], ...] = (
     # --- layer-pattern models (models/pattern.py): leaves stack over
     # PERIODS (the scan's axis, "layers" again); q/k/v/out_proj kernels and
     # the router take the rows above. Norm gains over a head, the
-    # convolution taps and the per-head decay parameters are replicated.
+    # convolution taps, the per-head decay parameters and the router's
+    # selection bias are replicated. A leading layer's leaves (under
+    # "leading") are not stacked: they take the same rows less "layers".
     (("norm_1", "scale"), ("layers", "embed_p")),
     (("norm_2", "scale"), ("layers", "embed_p")),
     (("norm_f", "scale"), ("embed_p",)),
@@ -234,6 +236,9 @@ PARAM_AXES_TABLE: tuple[tuple[tuple[str, ...], tuple[str | None, ...]], ...] = (
     (("gdn", "A_log"), ("layers", None)),
     (("gdn", "dt_bias"), ("layers", None)),
     (("gdn", "norm", "scale"), ("layers", None)),
+    (("shortconv", "in_proj", "kernel"), ("layers", "embed_p", "qkv")),
+    (("shortconv", "conv"), ("layers", None, None)),
+    (("moe", "expert_bias"), ("layers", None)),
     (("moe", "w_gate"), ("layers", "experts_p", "embed_p", None)),
     (("moe", "w_up"), ("layers", "experts_p", "embed_p", None)),
     (("moe", "w_down"), ("layers", "experts_p", None, "embed_p")),
@@ -262,7 +267,7 @@ def logical_axes_for_path(path: tuple) -> tuple[str | None, ...]:
     names = _path_names(path)
     for suffix, axes in PARAM_AXES_TABLE:
         if names[-len(suffix):] == suffix:
-            return axes
+            return axes[1:] if "leading" in names and axes[:1] == ("layers",) else axes
     raise KeyError(
         f"param path {'/'.join(names)} has no entry in PARAM_AXES_TABLE — "
         "add one (sharding must be explicit for every param)"

@@ -203,14 +203,16 @@ def _emit_counters(tele, steps: list[int], counted: np.ndarray) -> None:
     from dtc_tpu.models.pattern import COUNTERS
 
     for step, rows in zip(steps, counted):
-        by_name = dict(zip(COUNTERS, rows.T))
-        tele.registry.emit(
-            "moe_counters", step=int(step),
+        by_name = dict(zip(COUNTERS, rows.T))  # as many names as the rows are wide
+        fields = dict(
             moe_assigned_held=[float(v) for v in by_name["moe_assigned_held"]],
             moe_load_max=float(by_name["moe_load_max"].max()),
             moe_load_mean=float(by_name["moe_load_mean"].mean()),
             moe_dropped=float(by_name["moe_dropped"].sum()),
         )
+        if "moe_bias_swapped" in by_name:  # a router with a selection bias
+            fields["moe_bias_swapped"] = [float(v) for v in by_name["moe_bias_swapped"]]
+        tele.registry.emit("moe_counters", step=int(step), **fields)
 
 
 def _guarded_optimizer(train_cfg: TrainConfig, opt_cfg: OptimConfig):

@@ -81,12 +81,17 @@ def pattern_matmul_params(cfg: ModelConfig) -> dict[str, float]:
     d = cfg.d_model
     nk, nv = cfg.gdn_key_heads * cfg.gdn_key_dim, cfg.gdn_value_heads * cfg.gdn_value_dim
     q_out, kv_out = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
-    routed = cfg.experts_held * cfg.moe_top_k / max(cfg.moe_experts, 1)
+    attn = d * q_out + 2 * d * kv_out + q_out * d
+    routed = (d * cfg.moe_experts
+              + cfg.experts_held * cfg.moe_top_k / max(cfg.moe_experts, 1) * 3 * d * cfg.moe_d_ff)
     return {
         "gdn": d * (2 * nk + 2 * nv) + d * 2 * cfg.gdn_value_heads + nv * d,
-        "gated_attn": d * 2 * q_out + 2 * d * kv_out + q_out * d,
-        "moe_shared": d * cfg.moe_experts + 3 * d * cfg.moe_shared_d_ff + d
-        + routed * 3 * d * cfg.moe_d_ff,
+        "gated_attn": attn + d * q_out,
+        "attn": attn,
+        "shortconv": d * 3 * d + d * d,
+        "moe_shared": routed + 3 * d * cfg.moe_shared_d_ff + d,
+        "moe": routed,
+        "swiglu": 3 * d * cfg.d_ff,
         "head": d * cfg.padded_vocab_size,
     }
 
@@ -104,14 +109,14 @@ def pattern_step_flops(cfg: ModelConfig, batch: int, seq_len: int) -> float:
     parameters x tokens (head counted), causal attention as
     :func:`gpt_step_flops` counts it (12 B T^2 H hd / 2 a layer), and the
     recurrence's least work. Recomputation is not counted.
-    ``benchmark/flops_qwen3_next.py`` holds a copy; a test keeps them equal."""
+    ``benchmark/flops_qwen3_next.py`` and ``flops_lfm2_moe.py`` hold copies; tests
+    keep them equal."""
     tokens = batch * seq_len
     per = pattern_matmul_params(cfg)
-    periods = cfg.n_layers // len(cfg.layer_pattern)
-    kinds = [cfg.layer_kinds(i) for i in range(len(cfg.layer_pattern))]
-    n_matmul = periods * sum(per[m] + per[f] for m, f in kinds) + per["head"]
-    n_attn = periods * sum(m == "gated_attn" for m, _ in kinds)
-    n_gdn = periods * sum(m == "gdn" for m, _ in kinds)
+    census = cfg.layer_census()
+    n_matmul = sum(n * (per[m] + per[f]) for m, f, n in census) + per["head"]
+    n_attn = sum(n for m, _, n in census if m in ("gated_attn", "attn"))
+    n_gdn = sum(n for m, _, n in census if m == "gdn")
     attn = 12.0 * n_attn * batch * seq_len**2 * cfg.n_heads * cfg.head_dim / 2.0
     return 6.0 * n_matmul * tokens + attn + n_gdn * gdn_scan_flops(cfg, tokens)
 

@@ -1,12 +1,17 @@
 """What the files of pattern-model tests share (``test_pattern_model.py``,
 ``test_pattern_ops.py``, ``test_pattern_scan_kernels.py``, ``test_pattern_parallel.py``).
 
-Everything is compared with ``benchmark/reference_qwen3_next.py`` (float32
-``jax.numpy``, Gated DeltaNet as the token recurrence, dense attention,
-experts as a loop), loaded by path — there is no second copy — on weights
-from its own ``make_weights``, at a toy size: d 64, 2 key / 4 value heads
-of 16, 2 query heads on 1 KV head of 32 with 8 rotary dims, 8 experts
-top-2 of width 32, vocabulary 256.
+Everything is compared with the benchmark's plain references (float32
+``jax.numpy``, dense attention, experts as a loop), loaded by path — there
+is no second copy — on weights from their own ``make_weights``, at a toy
+size. :data:`QWEN3` (the default of every helper):
+``benchmark/reference_qwen3_next.py``, Gated DeltaNet as the token
+recurrence; d 64, 2 key / 4 value heads of 16, 2 query heads on 1 KV head of
+32 with 8 rotary dims, 8 experts top-2 of width 32, vocabulary 256.
+:data:`LFM2`: ``benchmark/reference_lfm2_moe.py``, the short convolution as
+a sum of shifted copies; d 64, a leading dense layer of width 96, 4 query
+heads on 1 KV head of 16, 8 sigmoid-scored experts top-2 of width 32 chosen
+with a selection bias, the head tied, vocabulary 256.
 
 Tolerances. float32 ``tight``: 2e-4 of the largest element — the two sides
 differ in summation order only (chunked matmuls against a recurrence, a
@@ -32,7 +37,6 @@ from dtc_tpu.models import pattern
 from tests.conftest import make_train_cfg
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TOY_YAML = os.path.join(REPO, "configs", "model_config_pattern_dev.yaml")
 TIGHT, LOOSE = 2e-4, 4e-2
 
 
@@ -43,29 +47,62 @@ def load_by_path(path, name):
     return mod
 
 
-ref = load_by_path(os.path.join(REPO, "benchmark", "reference_qwen3_next.py"), "reference_qwen3_next")
-with open(os.path.join(REPO, "benchmark", "configs", "qwen3-next-80b-a3b.json")) as f:
-    LEAF_NAMES = json.load(f)["leaf_names"]
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """A block family of the benchmark: its plain reference, the names its
+    configuration file gives the program's leaves, its toy preset."""
+
+    ref: object
+    leaf_names: dict
+    yaml: str
+
+    @classmethod
+    def of(cls, reference: str, config: str, preset: str) -> "Family":
+        with open(os.path.join(REPO, "benchmark", "configs", f"{config}.json")) as f:
+            names = json.load(f)["leaf_names"]
+        return cls(load_by_path(os.path.join(REPO, "benchmark", f"{reference}.py"), reference),
+                   names, os.path.join(REPO, "configs", preset))
+
+    def cfg(self) -> ModelConfig:
+        return load_yaml_dataclass(self.yaml, ModelConfig)
+
+
+QWEN3 = Family.of("reference_qwen3_next", "qwen3-next-80b-a3b", "model_config_pattern_dev.yaml")
+LFM2 = Family.of("reference_lfm2_moe", "lfm2-8b-a1b", "model_config_pattern_lfm2_dev.yaml")
+ref, LEAF_NAMES, TOY_YAML = QWEN3.ref, QWEN3.leaf_names, QWEN3.yaml
 
 
 @pytest.fixture(scope="module")
 def cfg() -> ModelConfig:
-    return load_yaml_dataclass(TOY_YAML, ModelConfig)
+    return QWEN3.cfg()
+
+
+@pytest.fixture(scope="module")
+def lfm2_cfg() -> ModelConfig:
+    return LFM2.cfg()
+
+
+def cell_cfg(name: str) -> tuple[ModelConfig, dict]:
+    """(the program's configuration, the ``model`` group) of a configuration
+    file of the benchmark."""
+    with open(os.path.join(REPO, "benchmark", "configs", f"{name}.json")) as f:
+        model = json.load(f)["model"]
+    return ModelConfig(**model), model
 
 
 def as_model(cfg: ModelConfig) -> dict:
     return {k: (list(v) if isinstance(v, tuple) else v) for k, v in dataclasses.asdict(cfg).items()}
 
 
-def weights(cfg: ModelConfig, seed: int = 3) -> dict:
+def weights(cfg: ModelConfig, seed: int = 3, family: Family = QWEN3) -> dict:
     with jax.default_matmul_precision("highest"):
-        return ref.make_weights(as_model(cfg), jnp.asarray(ref.seed_words(seed)))
+        return family.ref.make_weights(as_model(cfg), jnp.asarray(family.ref.seed_words(seed)))
 
 
-def program_params(w: dict) -> dict:
+def program_params(w: dict, family: Family = QWEN3) -> dict:
     """The reference's leaves laid out as the program's parameter tree."""
     tree: dict = {}
-    for path, name in LEAF_NAMES.items():
+    for path, name in family.leaf_names.items():
         node = tree
         *parents, leaf = path.split("/")
         for p in parents:
@@ -74,11 +111,15 @@ def program_params(w: dict) -> dict:
     return tree
 
 
-def layer_of(w: dict, position: int) -> tuple[dict, dict]:
-    """(program subtree, reference dict) of one layer, periods axis taken off."""
-    tree = program_params(w)["stage"]["periods"][f"layer_{position}"]
-    return (jax.tree.map(lambda a: a[0], tree),
-            {k: v[0] for k, v in ref.layer_params(w, position).items()})
+def layer_of(w: dict, position: int, family: Family = QWEN3, leading: bool = False) -> tuple[dict, dict]:
+    """(program subtree, reference dict) of one layer: a position of the
+    period with its periods axis taken off, or a leading layer."""
+    stage = program_params(w, family)["stage"]
+    if leading:
+        return stage["leading"][f"layer_{position}"], family.ref.layer_params(w, f"lead.{position}.")
+    flat = family.ref.layer_params(w, position if family is QWEN3 else f"blocks.{position}.")
+    return (jax.tree.map(lambda a: a[0], stage["periods"][f"layer_{position}"]),
+            {k: v[0] for k, v in flat.items()})
 
 
 def close(got, want, tol):
@@ -93,7 +134,7 @@ def normed_input(cfg, seed=0, rows=2):
     return x / jnp.sqrt(jnp.mean(jnp.square(x), -1, keepdims=True))
 
 
-def one_device_steps(cfg, opt_cfg, batches, w=None):
+def one_device_steps(cfg, opt_cfg, batches, w=None, family: Family = QWEN3):
     """The program's own state and compiled step on a mesh of one device,
     over ``batches`` ((rows, T + 1) arrays); with ``w`` the reference's
     weights replace the program's draw. Returns the last step's outputs
@@ -110,7 +151,7 @@ def one_device_steps(cfg, opt_cfg, batches, w=None):
         state = init_state(model, cfg, make_train_cfg("dp", batch=batches[0].shape[0]), opt_cfg, mesh)
         if w is not None:
             state = state.replace(params=jax.tree.map(
-                lambda a, b: jnp.asarray(b, a.dtype), state.params, program_params(w)))
+                lambda a, b: jnp.asarray(b, a.dtype), state.params, program_params(w, family)))
         step = create_train_step(mesh, model=model, state=state)
         losses = []
         for batch in batches:

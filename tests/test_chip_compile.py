@@ -149,6 +149,35 @@ def test_flash_fwd_bwd(one_chip, flagship, name):
     _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
 
 
+#: Attention shapes with KV groups (B, T, H, KV heads, D, block) of the
+#: benchmark's layer-pattern cells: the transposed-layout kernels, each query
+#: head's KV head picked by the block index maps.
+GROUPED_FLASH_SHAPES = {
+    "qwen3_next_b2": (2, 8192, 16, 2, 256, 1024),
+    "lfm2_b4": (4, 8192, 32, 8, 64, 1024),   # groups 4 wide at head size 64: the packed family has none
+}
+
+
+@pytest.mark.parametrize("name", list(GROUPED_FLASH_SHAPES))
+def test_flash_kv_groups_fwd_bwd(one_chip, name):
+    """Flash attention with fewer KV heads than query heads, forward and
+    backward (dq, and dk / dv summed over the group in VMEM), bf16, at the
+    cells' shapes and tiles."""
+    from dtc_tpu.ops.attention import causal_attention, resolve_impl
+
+    b, t, h, hk, d, block = GROUPED_FLASH_SHAPES[name]
+    assert resolve_impl("auto", t, d, block, block) == "flash"
+    q = _sds((b, t, h, d), jnp.bfloat16, one_chip)
+    kv = _sds((b, t, hk, d), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return causal_attention(q, k, v, impl="auto", block_q=block,
+                                block_kv=block).astype(jnp.float32).sum()
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
 @pytest.mark.parametrize("axis,name", [
     ("data", "flagship"), ("model", "flagship"), ("data", "gpt2_large_fsdp4_b32"),
 ])
@@ -404,12 +433,27 @@ def test_flagship_dp_train_step_fits_one_chip(topo, shipped):
     assert 0 < need < V5E_HBM_BYTES, mem
 
 
-def test_pattern_cell_train_step_fits_one_chip(topo):
-    """The layer-pattern cell of the benchmark (Qwen3-Next's one period, 32
-    of 512 experts held, 2 rows x 8192) through the trainer's own step
-    builder: grouped KV heads at head size 256 through the flash kernels,
-    the scan's fused kernels (the state carried in VMEM), the experts' loop over tiles, and the whole under the chip's memory —
-    it is the tight resource of that cell."""
+#: The benchmark's layer-pattern cells: (configuration file, Mosaic calls at
+#: least, names the compiled step must hold).
+PATTERN_CELLS = {
+    # flash forward, dq and dk/dv; the scan's primal, residual-writing forward and backward
+    "qwen3-next-80b-a3b.train-ep16share-b2x8192": (
+        "qwen3-next-80b-a3b", 5, ("gdn_chunks_fwd", "gdn_chunks_fwd_res", "gdn_chunks_bwd")),
+    # flash forward, dq and dk/dv on the transposed layout: 32 query heads on 8 KV heads of 64
+    "lfm2-8b-a1b.train-ep4share-8k": ("lfm2-8b-a1b", 3, ()),
+}
+
+
+@pytest.mark.parametrize("cell", list(PATTERN_CELLS))
+def test_pattern_cell_train_step_fits_one_chip(topo, cell):
+    """A layer-pattern cell of the benchmark through the trainer's own step
+    builder, under the chip's memory — the tight resource of both. Qwen3-Next's
+    one period (32 of 512 experts held, 2 rows x 8192): grouped KV heads at
+    head size 256 through the flash kernels, the scan's fused kernels (the
+    state carried in VMEM), the experts' loop over tiles. LFM2-8B-A1B's leading
+    dense layer and one period (8 of 32 experts held, 4 rows x 8192): KV
+    groups 4 wide at head size 64 through the same kernels, the short
+    convolutions, the tied head."""
     import json
 
     from flax import linen as nn
@@ -422,8 +466,8 @@ def test_pattern_cell_train_step_fits_one_chip(topo):
     from dtc_tpu.train.optimizer import create_optimizer
     from dtc_tpu.train.train_step import Batch, create_gspmd_train_step
 
-    cell = "qwen3-next-80b-a3b.train-ep16share-b2x8192"
-    with open(os.path.join(REPO, "benchmark", "configs", "qwen3-next-80b-a3b.json")) as f:
+    config, calls, names = PATTERN_CELLS[cell]
+    with open(os.path.join(REPO, "benchmark", "configs", f"{config}.json")) as f:
         model = json.load(f)["model"]
     with open(os.path.join(REPO, "benchmark", "workloads", f"{cell}.json")) as f:
         workload = json.load(f)
@@ -445,10 +489,9 @@ def test_pattern_cell_train_step_fits_one_chip(topo):
     with mesh, nn.logical_axis_rules(DEFAULT_RULES):
         step = create_gspmd_train_step(mesh, DEFAULT_RULES, counters=True)
         compiled = step.lower(state, Batch(x=xy, y=xy), rng).compile()
-    # flash forward, dq and dk/dv; the scan's primal, residual-writing forward and backward
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 5
-    assert all(f"gdn_chunks_{leg}" in text for leg in ("fwd", "fwd_res", "bwd"))
+    assert text.count("tpu_custom_call") >= calls
+    assert all(name in text for name in names)
     peak = compiled.memory_analysis().peak_memory_in_bytes
     print("peak_memory_in_bytes", peak)
     assert 0 < peak < V5E_HBM_BYTES

@@ -6,7 +6,6 @@ model refuses, the config rules and the counts. The operators are in
 """
 
 import dataclasses
-import json
 import os
 
 import jax
@@ -17,9 +16,15 @@ import pytest
 from dtc_tpu.config.schema import ModelConfig
 from dtc_tpu.models import pattern
 from tests.pattern_helpers import (  # noqa: F401  (cfg is a fixture)
-    LEAF_NAMES, LOOSE, REPO, TIGHT, as_model, cfg, close, layer_of, load_by_path,
-    normed_input, one_device_steps, ref, weights,
+    LFM2, LOOSE, QWEN3, REPO, TIGHT, as_model, cell_cfg, cfg, close, layer_of,
+    load_by_path, normed_input, one_device_steps, ref, weights,
 )
+
+
+def test_plans_of_the_other_family_are_the_parent_s(cfg):
+    moe = pattern.moe_plan(cfg, 256)
+    assert (moe["score"], moe["selection_bias"], moe["shared_width"]) == ("softmax", False, 32)
+    assert "leading" not in pattern.layer_plan(cfg) and "shortconv" not in pattern.layer_plan(cfg)
 
 
 @pytest.mark.parametrize("which", ["cell", "toy"])
@@ -33,8 +38,7 @@ def test_layer_plan_names_the_scan_implementation(cfg, which):
         assert (toy["kernel"], toy["carry"]) == ("xla", "scan")
         assert "tile" not in toy and "vmem_limit_bytes" not in toy
         return
-    with open(os.path.join(REPO, "benchmark", "configs", "qwen3-next-80b-a3b.json")) as f:
-        cell = ModelConfig(**json.load(f)["model"])
+    cell, _ = cell_cfg("qwen3-next-80b-a3b")
     gdn = pattern.layer_plan(cell)["gdn"]
     assert (gdn["kernel"], gdn["carry"]) == ("mosaic", "vmem") and gdn["tile"] == [8, cell.gdn_chunk]
     assert set(gdn["vmem_limit_bytes"]) == {"fwd", "bwd"}
@@ -45,28 +49,36 @@ def test_layer_plan_names_the_scan_implementation(cfg, which):
 # each layer kind, forward and gradients
 
 
-def _program_layer(kind, cfg):
-    return {"gdn": pattern.GatedDeltaNet, "gated_attn": pattern.GatedAttention,
-            "moe_shared": pattern.SharedExpertMoE}[kind](cfg)
-
-
-_REF_LAYER = {"gdn": ("gdn", 0, ref.gdn_layer), "gated_attn": ("attn_full", 3, ref.attn_layer),
-              "moe_shared": ("moe", 0, ref.moe_layer)}
+#: kind -> (family, the module's scope, where the layer is: position of the period, or leading)
+_LAYERS = {
+    "gdn": (QWEN3, "gdn", 0, False), "gated_attn": (QWEN3, "attn_full", 3, False),
+    "moe_shared": (QWEN3, "moe", 0, False),
+    "shortconv": (LFM2, "shortconv", 1, False), "attn": (LFM2, "attn_full", 0, False),
+    "swiglu": (LFM2, "mlp", 0, True), "moe": (LFM2, "moe", 0, False),
+}
+_REF_LAYER = {"gdn": "gdn_layer", "gated_attn": "attn_layer", "moe_shared": "moe_layer",
+              "shortconv": "shortconv_layer", "attn": "attn_layer", "swiglu": "swiglu_layer",
+              "moe": "moe_layer"}
 
 
 @pytest.mark.parametrize("kind,dtype,tol", [
     ("gdn", "float32", TIGHT), ("gdn", "bfloat16", LOOSE),
     ("gated_attn", "float32", TIGHT), ("gated_attn", "bfloat16", LOOSE),
     ("moe_shared", "float32", TIGHT),
+    ("shortconv", "float32", TIGHT), ("shortconv", "bfloat16", LOOSE),
+    ("attn", "float32", TIGHT), ("attn", "bfloat16", LOOSE),
+    ("swiglu", "float32", TIGHT), ("swiglu", "bfloat16", LOOSE),
+    ("moe", "float32", TIGHT),
 ])
-def test_layer_kind_matches_reference(cfg, kind, dtype, tol):
-    cfg = dataclasses.replace(cfg, compute_dtype=dtype)
-    scope, position, ref_fn = _REF_LAYER[kind]
-    w = weights(cfg)
-    tree, flat = layer_of(w, position)
+def test_layer_kind_matches_reference(kind, dtype, tol):
+    family, scope, position, leading = _LAYERS[kind]
+    cfg = dataclasses.replace(family.cfg(), compute_dtype=dtype)
+    ref_fn = getattr(family.ref, _REF_LAYER[kind])
+    w = weights(cfg, family=family)
+    tree, flat = layer_of(w, position, family, leading)
     x = normed_input(cfg)
     co = jax.random.normal(jax.random.PRNGKey(9), x.shape)
-    module = _program_layer(kind, cfg)
+    module = {**pattern.MIXERS, **pattern.FFNS}[kind][0](cfg, name=None)
 
     def program(p, x):
         return module.apply({"params": p}, x.astype(jnp.dtype(dtype))).astype(jnp.float32)
@@ -78,10 +90,15 @@ def test_layer_kind_matches_reference(cfg, kind, dtype, tol):
     close(program(tree[scope], x), want, tol)
     got_gp, got_gx = jax.grad(lambda p, x: jnp.sum(program(p, x) * co), argnums=(0, 1))(tree[scope], x)
     close(got_gx, want_gx, tol)
-    got_flat = {LEAF_NAMES[f"stage/periods/layer_{position}/{scope}/" + "/".join(
+    where = f"stage/leading/layer_{position}" if leading else f"stage/periods/layer_{position}"
+    got_flat = {family.leaf_names[f"{where}/{scope}/" + "/".join(
         str(getattr(k, "key", k)) for k in path)].split(".", 2)[2]: leaf
         for path, leaf in jax.tree_util.tree_leaves_with_path(got_gp)}
+    assert got_flat
     for name, g in got_flat.items():
+        if name == "moe.bias":
+            assert not np.any(np.asarray(g)) and not np.any(np.asarray(want_gp[name]))
+            continue
         close(g, want_gp[name], tol * (3 if dtype == "bfloat16" else 1))
 
 
@@ -105,7 +122,7 @@ def test_shares_add_up_to_the_uncut_layer(cfg):
         p = dict(tree["moe"])
         for leaf in ("w_gate", "w_up", "w_down"):
             p[leaf] = p[leaf][rank * 4: rank * 4 + 4]
-        y = pattern.SharedExpertMoE(share).apply({"params": p}, x)
+        y = pattern.FFNS["moe_shared"][0](share).apply({"params": p}, x)
         if rank:  # what every chip computes alike counts once
             zero_gate = {**flat, "moe.shared_gate.w": flat["moe.shared_gate.w"]}
             with jax.default_matmul_precision("highest"):
@@ -119,23 +136,25 @@ def test_shares_add_up_to_the_uncut_layer(cfg):
 # ---------------------------------------------------------------------------
 # the whole model through the program's step
 
-def test_whole_model_loss_and_clipped_gradients(cfg, opt_cfg):
+@pytest.mark.parametrize("family", [QWEN3, LFM2], ids=["qwen3", "lfm2"])
+def test_whole_model_loss_and_clipped_gradients(opt_cfg, family):
     """One step through ``create_train_step``: the loss, and the clipped
     gradient read back from AdamW's first moment (mu / (1 - b1)), as the
-    benchmark's comparison reads it."""
-    w = weights(cfg, seed=5)
+    benchmark's comparison reads it. ``lfm2``: with a leading layer before
+    the scanned period, a tied head and five counters a layer."""
+    cfg, ref = family.cfg(), family.ref
+    w = weights(cfg, seed=5, family=family)
     batch = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, cfg.max_seq_len + 1), dtype=np.int32)
-    state, (loss,), counters = one_device_steps(cfg, opt_cfg, [batch], w)
-    assert counters.shape == (4, 4) and float(counters[:, 3].sum()) == 0.0
+    state, (loss,), counters = one_device_steps(cfg, opt_cfg, [batch], w, family)
+    assert counters.shape == (4, 5 if cfg.moe_selection_bias else 4) and float(counters[:, 3].sum()) == 0.0
     optim = {"lr": opt_cfg.lr, "weight_decay": opt_cfg.weight_decay, "grad_clip": opt_cfg.grad_clip}
     out = ref.run_steps(as_model(cfg), optim, 5, [batch])
     assert abs(float(loss) - out["losses"][0]) <= 1e-4 * out["losses"][0]
-    mu = state.opt_state[1][0].mu if hasattr(state.opt_state[1][0], "mu") else None
-    if mu is None:
-        mu = next(s.mu for s in jax.tree.leaves(state.opt_state, is_leaf=lambda s: hasattr(s, "mu"))
-                  if hasattr(s, "mu"))
-    flat = {LEAF_NAMES["/".join(str(getattr(k, "key", k)) for k in path)]: leaf
+    mu = next(s.mu for s in jax.tree.leaves(state.opt_state, is_leaf=lambda s: hasattr(s, "mu"))
+              if hasattr(s, "mu"))
+    flat = {family.leaf_names["/".join(str(getattr(k, "key", k)) for k in path)]: leaf
             for path, leaf in jax.tree_util.tree_leaves_with_path(mu)}
+    assert set(flat) == set(out["grad1"])
     got = jax.device_get(ref.leaf_norms(flat))
     for name, want in out["grad1"].items():
         np.testing.assert_allclose(np.asarray(got[name]) / (1 - ref.B1), want, rtol=2e-3, atol=1e-7,
@@ -149,14 +168,14 @@ def test_whole_model_loss_and_clipped_gradients(cfg, opt_cfg):
 def test_generate_refuses(cfg):
     from dtc_tpu.generate import generate
 
-    with pytest.raises(NotImplementedError, match="no cache for recurrent state"):
+    with pytest.raises(NotImplementedError, match="no cache for recurrent state yet, nor a convolution's"):
         generate(pattern.build_model(cfg), {}, jnp.zeros((1, 4), jnp.int32), 4)
 
 
 def test_serving_engine_refuses(cfg):
     from dtc_tpu.serve.engine import ServingEngine
 
-    with pytest.raises(NotImplementedError, match="no cache for recurrent state"):
+    with pytest.raises(NotImplementedError, match="no cache for recurrent state yet, nor a convolution's"):
         ServingEngine(pattern.build_model(cfg), {}, None)
 
 @pytest.mark.parametrize("change,message", [
@@ -183,9 +202,7 @@ def test_the_schema_s_kinds_are_the_modules_kinds():
 
 
 def _published_cfg() -> tuple[ModelConfig, dict]:
-    with open(os.path.join(REPO, "benchmark", "configs", "qwen3-next-80b-a3b.json")) as f:
-        model = json.load(f)["model"]
-    return ModelConfig(**model), model
+    return cell_cfg("qwen3-next-80b-a3b")
 
 
 def test_parameter_count_is_the_issue_s_sum():
