@@ -96,8 +96,8 @@ ALLOWLIST: dict[str, frozenset[str]] = {
         "sort_dispatch", "sort_combine", "einsum_dispatch",
         "slot_to_token",
         # Held experts: fp32 gates, counters, matmul accumulators, the
-        # combine's scatter-add target and the weights' gradient sums.
-        "held_experts", "_held_tiles_fwd", "_held_tiles_bwd", "_mm",
+        # combine's staging and scatter-add target, the weights' gradient sums.
+        "held_experts", "_held_tiles_fwd", "_held_tiles_bwd", "_mm", "_staging",
         "bias_swapped",  # a float32 counter, as held_experts' are
     }),
     "ops/overlap_collectives.py": frozenset({

@@ -324,8 +324,9 @@ def _per_data_shard(fn, where, x, *rest):
         y, c = fn(x, *rest)
         total, peak = jax.lax.psum(c, axis), jax.lax.pmax(c, axis)
         # assigned and dropped add up; the fullest expert anywhere; the
-        # mean load of a device's held experts, averaged
-        return y, jnp.stack([total[0], peak[1], total[2] / jax.lax.psum(1, axis), total[3]])
+        # mean load of a device's held experts, averaged; the most flushes
+        # a device ran
+        return y, jnp.stack([total[0], peak[1], total[2] / jax.lax.psum(1, axis), total[3], peak[4]])
 
     tok = P(axis)
     return shard_map(
@@ -362,7 +363,7 @@ class ExpertLayer(nn.Module):
                 scores, k, bias=bias, eps=SIGMOID_GATE_EPS if sigmoid else 0.0,
                 scale=cfg.moe_routed_scale)
         y, counters = _per_data_shard(
-            functools.partial(md.held_experts, first=cfg.moe_expert_rank * held),
+            functools.partial(md.held_experts, first=cfg.moe_expert_rank * held, published=e),
             _data_axis(b), x.reshape(b * t, d), gates, idx,
             w_gate.astype(cdtype), w_up.astype(cdtype), w_down.astype(cdtype),
         )
@@ -624,5 +625,7 @@ def moe_plan(cfg: ModelConfig, tokens_per_device: int) -> dict | None:
         "shared_width": cfg.moe_shared_d_ff if "moe_shared" in ffns else 0,
         "score": cfg.moe_score, "selection_bias": cfg.moe_selection_bias,
         "tokens_per_device": tokens_per_device, "tile_rows": md.HELD_TILE_ROWS,
+        "staged_rows": md.held_staging_rows(
+            tokens_per_device * k, held, cfg.moe_experts, md.HELD_TILE_ROWS),
         "expected_held": tokens_per_device * k * held / cfg.moe_experts,
     }

@@ -37,6 +37,7 @@ reference in ``tests/test_moe.py``.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -244,15 +245,19 @@ def sort_combine(y_e: jax.Array, r: Routing, cap: int) -> jax.Array:
 # and computes their part of the layer's sum. The held assignments are
 # sorted by expert and run a tile of :data:`HELD_TILE_ROWS` rows of ONE
 # expert at a time, in a loop whose trip count follows the tiles the step's
-# routing fills: gather, the expert's three matmuls and the scatter-add
-# (a group of tiles at a time) all grow with the assignments there are —
-# not with the published count, not with the room, and a layer the routers
-# have left runs no tile.
+# routing fills: gather and the expert's three matmuls grow with the
+# assignments there are — not with the published count, not with the room,
+# and a layer the routers have left runs no tile. The tiles' rows are staged
+# packed, in the sorted list's order, and added into the tokens' rows by one
+# scatter for as many tiles as the staging holds: once a layer at routing
+# near even, again for what an uneven step holds beyond that.
 # ---------------------------------------------------------------------------
 
 #: What :func:`held_experts` counts, in order (the trainer's per-step
-#: counters; README "Observability").
-HELD_COUNTERS = ("moe_assigned_held", "moe_load_max", "moe_load_mean", "moe_dropped")
+#: counters; README "Observability"). ``moe_flushes``: the scatters into the
+#: tokens' rows that the forward ran, 1 where the staging held the layer.
+HELD_COUNTERS = ("moe_assigned_held", "moe_load_max", "moe_load_mean", "moe_dropped",
+                 "moe_flushes")
 
 #: Rows of one expert that go through its matmuls at a time. An expert's
 #: last tile is part filled, so a step computes at most ``held`` tiles'
@@ -262,12 +267,26 @@ HELD_COUNTERS = ("moe_assigned_held", "moe_load_max", "moe_load_mean", "moe_drop
 #: fastest of 128 / 256 / 512, PERF.md section 6).
 HELD_TILE_ROWS = 512
 
-#: Tiles whose rows are added into the tokens' rows by ONE scatter. XLA's
-#: TPU scatter copies its whole (tokens, d) operand on every call, however
-#: few rows it adds (0.4-0.8 ms at 16384 x 2048 float32), so the loop
-#: stages its tiles' outputs and scatters them a group at a time: with a
-#: scatter a tile, three quarters of the layer's time were those copies.
-HELD_GROUP_TILES = 16
+#: The staging's room over the held assignments of even routing. XLA's TPU
+#: scatter-add updates its (tokens, d) operand in place, but every call sorts
+#: its staged indices, permutes ALL staged rows into that order (written by a
+#: tile or not) and then walks the operand: on a v5e at d 2048 a call costs
+#: 0.56 ms for every 8,192 staged rows and 0.97 ms for every 134 MB of
+#: operand, however few rows it adds (PERF.md section 6). So the loop flushes
+#: as seldom as the staging allows and stages no row that holds nothing: a
+#: quarter over even routing takes every layer of the benchmark's cells in one
+#: flush (their fullest layers hold 1.05 x), and a step that holds more runs a
+#: second. An eighth would save 0.3 ms a call there and a half would add 0.5.
+HELD_STAGING_SLACK = 1.25
+
+
+def held_staging_rows(assignments: int, held: int, published: int, tile: int) -> int:
+    """Rows the loop stages between two scatters, from the shapes alone:
+    the held share of ``assignments`` (token, choice) pairs at even routing
+    and :data:`HELD_STAGING_SLACK` of it, in whole tiles, and one tile more
+    for the last tile's unfilled rows."""
+    even = assignments * held / published
+    return (math.ceil(HELD_STAGING_SLACK * even / tile) + 1) * tile
 
 
 def top_k_gates(scores: jax.Array, k: int, *, bias: jax.Array | None = None,
@@ -360,89 +379,103 @@ def _swiglu(xs, wg, wu):
     return a, sig, b, (a * sig * b).astype(xs.dtype)
 
 
-def _over_groups(tiles: _Tiles, tile: int, stage, totals, one_tile, flush):
-    """The loop over the step's tiles, :data:`HELD_GROUP_TILES` at a time.
-    ``stage()`` makes a group's staging arrays; ``one_tile(t, at, staged,
-    totals)`` computes tile ``t`` and writes what it has for the tokens'
-    rows at row ``at`` of them; ``flush(staged, totals)`` adds a group's
-    staged rows into the totals, which it returns after the last group.
-    Tiles past the count are skipped: a part-filled group computes nothing
-    it has no assignment for."""
-    group = HELD_GROUP_TILES
+def _over_groups(tiles: _Tiles, tile: int, staged_rows: int, past, values, totals, one_tile, flush):
+    """The loop over the step's tiles, as many at a time as ``staged_rows``
+    hold. Tile ``t`` stages what it has for the tokens' rows at its place in
+    the sorted list less the group's first tile's: packed, so the unfilled
+    rows at an expert's end are overwritten by the next expert's first tile,
+    and only a group's last tile leaves any. ``past()`` makes a group's index
+    lists, every entry past the arrays' ends (dropped until a tile writes
+    it); ``values`` are the staging arrays, carried over the groups and never
+    cleared (a stale row's index drops it); ``one_tile(t, at, indices,
+    values, totals)`` computes tile ``t`` and stages at row ``at``;
+    ``flush(indices, values, totals)`` adds a group's rows into the totals.
+    Returns the totals after the last group and how many groups there were."""
 
-    def body(carry):
-        g, totals = carry
+    def group(carry):
+        first, groups, values, totals = carry
+        base = tiles.start[first]
 
-        def step(j, c):
-            t = g * group + j
-            return jax.lax.cond(t < tiles.count, lambda c: one_tile(t, j * tile, *c), lambda c: c, c)
+        def fits(c):
+            return (c[0] < tiles.count) & (tiles.start[c[0]] + tile - base <= staged_rows)
 
-        return g + 1, flush(*jax.lax.fori_loop(0, group, step, (stage(), totals)))
+        def step(c):
+            t, *staged = c
+            return t + 1, *one_tile(t, tiles.start[t] - base, *staged)
 
-    groups = (tiles.count + group - 1) // group
-    return jax.lax.while_loop(lambda c: c[0] < groups, body, (jnp.zeros((), jnp.int32), totals))[1]
+        t, indices, values, totals = jax.lax.while_loop(fits, step, (first, past(), values, totals))
+        return t, groups + 1, values, flush(indices, values, totals)
+
+    zero = jnp.zeros((), jnp.int32)
+    _, groups, _, totals = jax.lax.while_loop(
+        lambda c: c[0] < tiles.count, group, (zero, zero, values, totals))
+    return totals, groups
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _held_tiles(x, gates, w_gate, w_up, w_down, order, tiles, k, tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _held_tiles(x, gates, w_gate, w_up, w_down, order, tiles, k, tile, staged_rows):
     """``y[token] += down_e(silu(gate_e x) * up_e x) * gate`` over the tiles
-    that hold assignments; also the assignments it computed. Its own
-    backward: the loop's trip count is the step's, which reverse-mode
-    differentiation of a loop cannot follow; the backward walks the same
-    tiles, recomputes each one's hidden and pulls ``dy`` through it, so
-    nothing is kept per tile; the weights' gradients add up in float32."""
-    return _held_tiles_fwd(x, gates, w_gate, w_up, w_down, order, tiles, k, tile)[0]
+    that hold assignments; also the assignments it computed and the scatters
+    it took. Its own backward: the loop's trip count is the step's, which
+    reverse-mode differentiation of a loop cannot follow; the backward walks
+    the same tiles, recomputes each one's hidden and pulls ``dy`` through it,
+    so nothing is kept per tile; the weights' gradients add up in float32."""
+    return _held_tiles_fwd(x, gates, w_gate, w_up, w_down, order, tiles, k, tile, staged_rows)[0]
 
 
 def _put(staged, value, at):
     return jax.lax.dynamic_update_slice_in_dim(staged, value, at, 0)
 
 
-def _held_tiles_fwd(x, gates, w_gate, w_up, w_down, order, tiles, k, tile):
+def _staging(*shape):
+    """A staging array of float32 values, not cleared: a row counts only
+    once a tile has written it and its index (on the TPU the buffer is
+    allocated and nothing more; other backends hand out zeros)."""
+    return jax.lax.empty(shape, jnp.float32)
+
+
+def _held_tiles_fwd(x, gates, w_gate, w_up, w_down, order, tiles, k, tile, staged_rows):
     flat = gates.reshape(-1)
-    staged_rows = HELD_GROUP_TILES * tile
 
-    def stage():  # indices past the end until a tile writes them: dropped
-        return (jnp.full((staged_rows,), order.shape[0], jnp.int32),
-                jnp.zeros((staged_rows, x.shape[1]), jnp.float32))
+    def past():
+        return jnp.full((staged_rows,), order.shape[0], jnp.int32)
 
-    def one_tile(t, at, staged, totals):
-        (rows_all, ys_all), (y, done) = staged, totals
+    def one_tile(t, at, rows_all, ys_all, totals):
+        y, done = totals
         with jax.named_scope("dispatch"):
             e, slots, rows, filled = _tile_rows(t, tiles, order, k, tile)
             xs, weight = _take(x, rows), _take(flat, slots)[:, None]
         with jax.named_scope("experts"):
             *_, h = _swiglu(xs, _expert(w_gate, e), _expert(w_up, e))
             ys = _mm(h, _expert(w_down, e), (1, 0)) * weight
-        return (_put(rows_all, rows, at), _put(ys_all, ys, at)), (y, done + filled)
+        return _put(rows_all, rows, at), _put(ys_all, ys, at), (y, done + filled)
 
-    def flush(staged, totals):
-        (rows_all, ys_all), (y, done) = staged, totals
+    def flush(rows_all, ys_all, totals):
+        y, done = totals
         with jax.named_scope("combine"):
             return y.at[rows_all].add(ys_all, mode="drop"), done
 
     totals = (jnp.zeros(x.shape, jnp.float32), jnp.zeros((), jnp.int32))
-    return (_over_groups(tiles, tile, stage, totals, one_tile, flush),
-            (x, gates, w_gate, w_up, w_down, order, tiles))
+    (y, done), flushes = _over_groups(
+        tiles, tile, staged_rows, past, _staging(staged_rows, x.shape[1]), totals, one_tile, flush)
+    return (y, done, flushes), (x, gates, w_gate, w_up, w_down, order, tiles)
 
 
-def _held_tiles_bwd(k, tile, res, cotangents):
+def _held_tiles_bwd(k, tile, staged_rows, res, cotangents):
     x, gates, w_gate, w_up, w_down, order, tiles = res
-    dy, _ = cotangents
+    dy, *_ = cotangents
     flat = gates.reshape(-1)
     dtype = x.dtype
-    staged_rows = HELD_GROUP_TILES * tile
 
-    def stage():
-        past = jnp.full((staged_rows,), order.shape[0], jnp.int32)
-        return (past, jnp.zeros((staged_rows, x.shape[1]), jnp.float32),
-                past, jnp.zeros((staged_rows,), jnp.float32))
+    def past():
+        index = jnp.full((staged_rows,), order.shape[0], jnp.int32)
+        return index, index
 
     def add_at(acc, e, g):  # one expert's slice, in place
         return jax.lax.dynamic_update_index_in_dim(acc, _expert(acc, e) + g, e, 0)
 
-    def one_tile(t, at, staged, totals):
-        (rows_all, dxs_all, slots_all, dweight_all), (dx, dflat, dwg, dwu, dwd) = staged, totals
+    def one_tile(t, at, indices, values, totals):
+        (rows_all, slots_all), (dxs_all, dweight_all), (dx, dflat, dwg, dwu, dwd) = indices, values, totals
         with jax.named_scope("dispatch"):
             e, slots, rows, _ = _tile_rows(t, tiles, order, k, tile)
             xs, weight, dys = _take(x, rows), _take(flat, slots)[:, None], _take(dy, rows)
@@ -457,19 +490,21 @@ def _held_tiles_bwd(k, tile, res, cotangents):
             dxs = _mm(da, wg_e, (1, 1)) + _mm(db, wu_e, (1, 1))
             dwg, dwu, dwd = (add_at(acc, e, _mm(lhs, rhs, (0, 0))) for acc, lhs, rhs in
                              ((dwg, xs, da), (dwu, xs, db), (dwd, h, do)))
-        staged = (_put(rows_all, rows, at), _put(dxs_all, dxs, at),
-                  _put(slots_all, slots, at), _put(dweight_all, dweight, at))
-        return staged, (dx, dflat, dwg, dwu, dwd)
+        return ((_put(rows_all, rows, at), _put(slots_all, slots, at)),
+                (_put(dxs_all, dxs, at), _put(dweight_all, dweight, at)),
+                (dx, dflat, dwg, dwu, dwd))
 
-    def flush(staged, totals):
-        (rows_all, dxs_all, slots_all, dweight_all), (dx, dflat, *dws) = staged, totals
+    def flush(indices, values, totals):
+        (rows_all, slots_all), (dxs_all, dweight_all), (dx, dflat, *dws) = indices, values, totals
         with jax.named_scope("combine"):
             return (dx.at[rows_all].add(dxs_all, mode="drop"),
                     dflat.at[slots_all].add(dweight_all, mode="drop"), *dws)
 
+    values = (_staging(staged_rows, x.shape[1]), _staging(staged_rows))
     totals = (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(flat),
               *(jnp.zeros(w.shape, jnp.float32) for w in (w_gate, w_up, w_down)))
-    dx, dflat, dwg, dwu, dwd = _over_groups(tiles, tile, stage, totals, one_tile, flush)
+    (dx, dflat, dwg, dwu, dwd), _ = _over_groups(
+        tiles, tile, staged_rows, past, values, totals, one_tile, flush)
     no = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731  (integer inputs)
     return (dx.astype(dtype), dflat.reshape(gates.shape),
             dwg.astype(w_gate.dtype), dwu.astype(w_up.dtype), dwd.astype(w_down.dtype),
@@ -479,15 +514,18 @@ def _held_tiles_bwd(k, tile, res, cotangents):
 _held_tiles.defvjp(_held_tiles_fwd, _held_tiles_bwd)
 
 
-def held_experts(x, gates, idx, w_gate, w_up, w_down, *, first: int):
+def held_experts(x, gates, idx, w_gate, w_up, w_down, *, first: int, published: int):
     """``sum_e gate_e * down_e(silu(gate_e x) * up_e x)`` over the held
-    experts ``[first, first + held)``, for tokens ``x`` (N, d) with their
-    ``(N, k)`` gates and expert ids; the matmuls take their operands in
-    ``x``'s type. Returns ``(y (N, d) float32, counters (4,) float32)`` in
-    :data:`HELD_COUNTERS` order; ``moe_dropped`` is the held assignments
-    less those the loop over tiles computed."""
+    experts ``[first, first + held)`` of the ``published`` ones the router
+    scores, for tokens ``x`` (N, d) with their ``(N, k)`` gates and expert
+    ids; the matmuls take their operands in ``x``'s type. Returns ``(y (N,
+    d) float32, counters (5,) float32)`` in :data:`HELD_COUNTERS` order;
+    ``moe_dropped`` is the held assignments less those the loop over tiles
+    computed."""
     k = idx.shape[1]
     held = w_gate.shape[0]
+    tile = HELD_TILE_ROWS
+    staged_rows = held_staging_rows(idx.size, held, published, tile)
     with jax.named_scope("dispatch"):
         local = idx.reshape(-1) - first
         key = jnp.where((local >= 0) & (local < held), local, held)
@@ -496,12 +534,12 @@ def held_experts(x, gates, idx, w_gate, w_up, w_down, *, first: int):
         # first key > e stands.
         ends = jnp.searchsorted(
             key[order], jnp.arange(1, held + 1, dtype=key.dtype), side="left").astype(jnp.int32)
-        tiles = _plan_tiles(ends, key.shape[0], HELD_TILE_ROWS)
+        tiles = _plan_tiles(ends, key.shape[0], tile)
         # room for the last tile's slice to stay inside the list
-        order = jnp.pad(order, (0, HELD_TILE_ROWS))
-    y, done = _held_tiles(x, gates, w_gate, w_up, w_down, order, tiles, k, HELD_TILE_ROWS)
+        order = jnp.pad(order, (0, tile))
+    y, done, flushes = _held_tiles(x, gates, w_gate, w_up, w_down, order, tiles, k, tile, staged_rows)
     loads = jnp.diff(ends, prepend=0).astype(jnp.float32)
     n_held = ends[-1]
     counters = jnp.stack([n_held.astype(jnp.float32), jnp.max(loads), jnp.mean(loads),
-                          (n_held - done).astype(jnp.float32)])
+                          (n_held - done).astype(jnp.float32), flushes.astype(jnp.float32)])
     return y, counters

@@ -209,6 +209,7 @@ def _emit_counters(tele, steps: list[int], counted: np.ndarray) -> None:
             moe_load_max=float(by_name["moe_load_max"].max()),
             moe_load_mean=float(by_name["moe_load_mean"].mean()),
             moe_dropped=float(by_name["moe_dropped"].sum()),
+            moe_flushes=[float(v) for v in by_name["moe_flushes"]],
         )
         if "moe_bias_swapped" in by_name:  # a router with a selection bias
             fields["moe_bias_swapped"] = [float(v) for v in by_name["moe_bias_swapped"]]

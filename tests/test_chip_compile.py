@@ -460,7 +460,7 @@ def test_pattern_cell_train_step_fits_one_chip(topo, cell):
     from flax.training.train_state import TrainState
 
     from dtc_tpu.config.schema import ModelConfig, OptimConfig
-    from dtc_tpu.models.pattern import build_model
+    from dtc_tpu.models.pattern import build_model, moe_plan
     from dtc_tpu.parallel.mesh import build_mesh
     from dtc_tpu.parallel.sharding import DEFAULT_RULES
     from dtc_tpu.train.optimizer import create_optimizer
@@ -495,3 +495,29 @@ def test_pattern_cell_train_step_fits_one_chip(topo, cell):
     peak = compiled.memory_analysis().peak_memory_in_bytes
     print("peak_memory_in_bytes", peak)
     assert 0 < peak < V5E_HBM_BYTES
+    _expert_loop_adds_in_place(text, rows * cfg.max_seq_len, moe_plan(cfg, rows * cfg.max_seq_len)["staged_rows"],
+                               cfg.d_model)
+
+
+def _expert_loop_adds_in_place(text: str, tokens: int, staged_rows: int, d: int):
+    """What interpret mode cannot say of the experts' loop (``ops/moe_dispatch``):
+    in the optimized step no ``copy`` of the tokens' ``(tokens, d)`` float32
+    rows nor of the ``(staged_rows, d)`` staging stands under the ``moe`` scope
+    — the loops carry both in place — and every fusion that scatter-adds a
+    group's rows into the tokens' rows gives its result its first operand's
+    buffer. (A layer's forward and its backward each hold one; the layer
+    remat's forward needs no output, so XLA drops its loop.)"""
+    import re
+
+    large = tuple(f"f32[{n},{d}]" for n in (tokens, staged_rows))
+    moe = [line.strip() for line in text.splitlines() if "/moe/" in line]
+    made = [re.match(r"(?:ROOT )?%\S+ = (.*?) (copy|copy-start|fusion)\(", line) for line in moe]
+    copies = [line for line, m in zip(moe, made)
+              if m and m.group(2) != "fusion" and any(shape in m.group(1) for shape in large)]
+    assert not copies, copies[:3]
+    scatters = [line for line, m in zip(moe, made)
+                if m and m.group(2) == "fusion" and m.group(1).startswith(large[0])
+                and "combine/scatter-add" in line and "kind=kCustom" in line and not line.startswith("ROOT")]
+    assert len(scatters) >= 2, len(scatters)
+    for line in scatters:
+        assert re.search(r'"aliasing_operands":\{"lists":\[\{"indices":\["0"', line), line[:200]

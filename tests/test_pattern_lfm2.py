@@ -40,6 +40,7 @@ def test_plans_name_what_an_lfm2_model_runs(lfm2_cfg):
     assert (moe["score"], moe["selection_bias"], moe["shared_width"]) == ("sigmoid", True, 0)
     assert (moe["experts_published"], moe["experts_held"], moe["top_k"]) == (32, 8, 4)
     assert moe["expected_held"] == 32768 and moe["tile_rows"] == 512
+    assert moe["staged_rows"] == 41472  # a quarter over the expected, and a tile: 340 MB of float32 rows
     toy = pattern.moe_plan(lfm2_cfg, 128)
     assert toy["expected_held"] == 64 and toy["first_expert"] == 0
 
@@ -62,7 +63,7 @@ def test_moe_shares_add_up_to_the_uncut_layer(lfm2_cfg):
         for leaf in ("w_gate", "w_up", "w_down"):
             p[leaf] = p[leaf][rank * 2: rank * 2 + 2]
         y, mut = pattern.FFNS["moe"][0](share).apply({"params": p}, x, mutable=["counters"])
-        assert mut["counters"]["moe"][0].shape == (5,)
+        assert mut["counters"]["moe"][0].shape == (6,)
         total = total + y
     close(total, want, TIGHT)
 
@@ -117,7 +118,7 @@ def test_expert_bias_changes_the_choice_and_has_a_zero_gradient(lfm2_cfg):
     without = {**with_bias, "expert_bias": jnp.zeros_like(with_bias["expert_bias"])}
     y1, c1 = module.apply({"params": with_bias}, x, mutable=["counters"])
     y0, c0 = module.apply({"params": without}, x, mutable=["counters"])
-    swapped1, swapped0 = (float(c["counters"]["moe"][0][4]) for c in (c1, c0))
+    swapped1, swapped0 = (float(c["counters"]["moe"][0][5]) for c in (c1, c0))
     choices = x.shape[0] * x.shape[1] * lfm2_cfg.moe_top_k
     assert swapped0 == 0.0 and 0.02 * choices < swapped1 < 0.5 * choices
     assert float(jnp.max(jnp.abs(y1 - y0))) > 1e-3

@@ -7,6 +7,7 @@ model refuses, the config rules and the counts. The operators are in
 
 import dataclasses
 import os
+import types
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,11 @@ def test_plans_of_the_other_family_are_the_parent_s(cfg):
     moe = pattern.moe_plan(cfg, 256)
     assert (moe["score"], moe["selection_bias"], moe["shared_width"]) == ("softmax", False, 32)
     assert "leading" not in pattern.layer_plan(cfg) and "shortconv" not in pattern.layer_plan(cfg)
+    # the staging: a quarter over the 256 of even routing in tiles of 512, and a tile; at the
+    # benchmark cell's shape 1.25 x 10,240 and a tile = 109 MB of float32 rows
+    assert (moe["expected_held"], moe["tile_rows"], moe["staged_rows"]) == (256, 512, 1024)
+    cell = pattern.moe_plan(cell_cfg("qwen3-next-80b-a3b")[0], 2 * 8192)
+    assert (cell["expected_held"], cell["staged_rows"]) == (10240, 13312)
 
 
 @pytest.mark.parametrize("which", ["cell", "toy"])
@@ -141,12 +147,13 @@ def test_whole_model_loss_and_clipped_gradients(opt_cfg, family):
     """One step through ``create_train_step``: the loss, and the clipped
     gradient read back from AdamW's first moment (mu / (1 - b1)), as the
     benchmark's comparison reads it. ``lfm2``: with a leading layer before
-    the scanned period, a tied head and five counters a layer."""
+    the scanned period, a tied head and six counters a layer."""
     cfg, ref = family.cfg(), family.ref
     w = weights(cfg, seed=5, family=family)
     batch = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, cfg.max_seq_len + 1), dtype=np.int32)
     state, (loss,), counters = one_device_steps(cfg, opt_cfg, [batch], w, family)
-    assert counters.shape == (4, 5 if cfg.moe_selection_bias else 4) and float(counters[:, 3].sum()) == 0.0
+    assert counters.shape == (4, 6 if cfg.moe_selection_bias else 5) and float(counters[:, 3].sum()) == 0.0
+    assert counters[:, 4].tolist() == [1.0] * 4  # one flush a layer
     optim = {"lr": opt_cfg.lr, "weight_decay": opt_cfg.weight_decay, "grad_clip": opt_cfg.grad_clip}
     out = ref.run_steps(as_model(cfg), optim, 5, [batch])
     assert abs(float(loss) - out["losses"][0]) <= 1e-4 * out["losses"][0]
@@ -248,3 +255,25 @@ def test_the_benchmark_counts_the_assignments_a_run_computed(held, steps):
                                3 * per * (sum(held) - 4 * 10240), rtol=1e-9, atol=1e6)
     if sum(held) == 4 * 10240:
         assert step == flops.train_step_flops(model, 2, 8192)
+
+
+@pytest.mark.parametrize("flushes,want", [([[1.0] * 4, [1.0] * 4], 1.0), ([[1.0, 2.0, 1.0, 1.0], [1.0] * 4], 1.125),
+                                          (None, None)])
+def test_the_benchmark_reads_the_flushes_a_layer_ran(flushes, want):
+    """``moe_flushes_per_layer.train``: the mean of the ``moe_counters``
+    events' ``moe_flushes`` over layers and steps; a program that does not
+    count them (the parent's) reads nothing and raises nothing. And the
+    trainer's event carries what the step counted, a list a layer."""
+    from dtc_tpu.train.trainer import _emit_counters
+
+    reader = load_by_path(os.path.join(REPO, "benchmark", "metrics", "moe_flushes_per_layer.train.py"), "flushes")
+    events = [{"etype": "step", "step": 1}]
+    if flushes is None:
+        events += [{"etype": "moe_counters", "step": 1, "moe_assigned_held": [10240.0] * 4}]
+    else:
+        emit = lambda etype, **fields: events.append({"etype": etype, **fields})  # noqa: E731
+        tele = types.SimpleNamespace(registry=types.SimpleNamespace(emit=emit))
+        counted = np.array([[[10240.0, 480.0, 320.0, 0.0, f] for f in row] for row in flushes])
+        _emit_counters(tele, [1, 2], counted)
+        assert [e["moe_flushes"] for e in events[1:]] == flushes and events[1]["moe_dropped"] == 0.0
+    assert reader.read({"events": events}) == want

@@ -174,26 +174,46 @@ def test_no_assignment_is_dropped_when_one_expert_takes_every_token(cfg):
     flat = {**flat, "moe.router.w": jnp.asarray(router)}
     p = {**tree["moe"], "router": {"kernel": flat["moe.router.w"]}}
     y, mut = pattern.FFNS["moe_shared"][0](cfg).apply({"params": p}, x, mutable=["counters"])
-    assigned, load_max, load_mean, dropped = np.asarray(mut["counters"]["moe"][0])
+    assigned, load_max, load_mean, dropped, flushes = np.asarray(mut["counters"]["moe"][0])
     tokens = x.shape[0] * x.shape[1]
-    assert load_max == tokens and dropped == 0
+    # 1.25 x the 256 of even routing, in tiles, and a tile: one flush holds them
+    assert load_max == tokens and dropped == 0 and flushes == 1
     assert tokens <= assigned <= 2 * tokens and load_mean == assigned / 4
     with jax.default_matmul_precision("highest"):
         close(y, ref.moe_layer(flat, x, as_model(cfg)), TIGHT)
 
 
-# several tiles an expert in two groups; in many groups; one tile an expert, part filled
-@pytest.mark.parametrize("tile,group", [(8, 16), (8, 3), (48, 16)])
-def test_expert_tiles_loop_equals_reference(cfg, monkeypatch, tile, group):
+def _flushes(loads, tile, staged):
+    """How many groups the loop makes of these experts' loads: a group ends
+    where the next tile's rows, at their place in the sorted list, would end
+    past the staging."""
+    starts = [lo + i * tile for lo, n in zip(np.cumsum([0, *loads[:-1]]), loads)
+              for i in range(-(-int(n) // tile))]
+    groups, base = 0, None
+    for start in starts:
+        if base is None or start + tile - base > staged:
+            groups, base = groups + 1, start
+    return groups
+
+
+# staging with room for the whole layer; of two tiles (the dropless overflow
+# path: many flushes); the program's own sizing at one or two tiles an expert
+@pytest.mark.parametrize("tile,staged", [(8, 1024), (8, 16), (48, None)])
+def test_expert_tiles_loop_equals_reference(cfg, monkeypatch, tile, staged):
     """The held assignments run a tile of one expert's rows at a time, as
-    many tiles as the routing fills, their rows scattered a group of tiles
-    at a time: with tiles far smaller than an expert's load, and a last
-    group part filled, the layer and its gradients still equal the
-    reference's loop over experts."""
+    many tiles as the routing fills, their rows staged packed and scattered
+    whenever the staging is full: with tiles far smaller than an expert's
+    load, and a staging far smaller than the layer, the layer and its
+    gradients still equal the reference's loop over experts, nothing is
+    dropped, and the counter says how many scatters it took."""
     from dtc_tpu.ops import moe_dispatch
 
     monkeypatch.setattr(moe_dispatch, "HELD_TILE_ROWS", tile)
-    monkeypatch.setattr(moe_dispatch, "HELD_GROUP_TILES", group)
+    if staged is None:
+        staged = moe_dispatch.held_staging_rows(2 * 128 * 2, 4, 8, tile)
+        assert staged == 384  # 1.25 x 256 in tiles of 48, and a tile
+    else:
+        monkeypatch.setattr(moe_dispatch, "held_staging_rows", lambda *a: staged)
     w = weights(cfg, seed=11)
     tree, flat = layer_of(w, 0)
     x = normed_input(cfg, seed=4)
@@ -203,12 +223,66 @@ def test_expert_tiles_loop_equals_reference(cfg, monkeypatch, tile, group):
     with jax.default_matmul_precision("highest"):
         want = jax.grad(lambda p, x: jnp.sum(ref.moe_layer(p, x, as_model(cfg)) * co),
                         argnums=(0, 1))(flat, x)
-        close(module.apply({"params": tree["moe"]}, x), ref.moe_layer(flat, x, as_model(cfg)), TIGHT)
+        y, mut = module.apply({"params": tree["moe"]}, x, mutable=["counters"])
+        close(y, ref.moe_layer(flat, x, as_model(cfg)), TIGHT)
+    assigned, load_max, _, dropped, flushes = np.asarray(mut["counters"]["moe"][0])
+    _, idx = moe_dispatch.top_k_gates(
+        jax.nn.softmax(x.reshape(-1, x.shape[-1]) @ flat["moe.router.w"], axis=-1), cfg.moe_top_k)
+    loads = np.bincount(np.asarray(idx).reshape(-1), minlength=8)[:4]
+    assert (assigned, load_max, dropped) == (loads.sum(), loads.max(), 0)
+    assert flushes == _flushes(loads, tile, staged)
+    assert flushes == 1 if staged > 16 else flushes > assigned / 16
     got_p, got_x = jax.grad(program, argnums=(0, 1))(tree["moe"], x)
     close(got_x, want[1], TIGHT)
     for leaf, name in (("w_gate", "gate"), ("w_up", "up"), ("w_down", "down")):
         close(got_p[leaf], want[0][f"moe.{name}.w"], TIGHT)
     close(got_p["router"]["kernel"], want[0]["moe.router.w"], TIGHT)
+
+
+# every tile in one group; a group's end right after a part-filled tile
+@pytest.mark.parametrize("stale", ["as allocated", "nan"])
+@pytest.mark.parametrize("staged,flushes", [(32, 1), (16, 3)])
+def test_packed_staging_overwrites_a_part_filled_tile_s_tail(monkeypatch, staged, flushes, stale):
+    """Planted choices: expert 0 holds 11 rows (a full tile of 8 and one of
+    3), expert 1 holds 5, expert 2 holds 9; expert 3 is held elsewhere. Packed, the
+    second tile's five unfilled rows lie where expert 1's tile then writes,
+    and so on: output, counters and all five gradients against a dense form.
+    ``nan``: the staging is never cleared, so whatever it holds where no tile
+    has written (on the chip: what the allocation held) must reach no result."""
+    from dtc_tpu.ops import moe_dispatch as md
+
+    monkeypatch.setattr(md, "HELD_TILE_ROWS", 8)
+    if stale == "nan":
+        monkeypatch.setattr(md, "_staging", lambda *shape: jnp.full(shape, jnp.nan, jnp.float32))
+    monkeypatch.setattr(md, "held_staging_rows", lambda *a: staged)
+    n, d, f, held = 16, 8, 4, 3
+    first = np.repeat([0, 1, 2, 3], [11, 5, 0, 0])          # every token's first choice
+    second = np.repeat([2, 3], [9, 7])                       # and its second, another expert
+    idx = jnp.asarray(np.stack([first, second], 1), jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    x = jax.random.normal(ks[0], (n, d))
+    gates = jax.nn.softmax(jax.random.normal(ks[1], (n, 2)), axis=-1)
+    wg, wu = (jax.random.normal(key, (held, d, f)) / np.sqrt(d) for key in ks[2:4])
+    wd = jax.random.normal(ks[4], (held, f, d)) / np.sqrt(f)
+    co = jax.random.normal(ks[5], (n, d))
+
+    def dense(x, gates, wg, wu, wd):
+        parts = [(jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e]
+                 * jnp.sum(jnp.where(idx == e, gates, 0.0), axis=1, keepdims=True) for e in range(held)]
+        return sum(parts)
+
+    def program(*a):
+        return md.held_experts(*a[:2], idx, *a[2:], first=0, published=4)
+
+    args = (x, gates, wg, wu, wd)
+    with jax.default_matmul_precision("highest"):
+        y, counters = program(*args)
+        close(y, dense(*args), TIGHT)
+        np.testing.assert_allclose(np.asarray(counters), [25, 11, 25 / 3, 0, flushes], rtol=1e-6)
+        got = jax.grad(lambda *a: jnp.sum(program(*a)[0] * co), argnums=range(5))(*args)
+        want = jax.grad(lambda *a: jnp.sum(dense(*a) * co), argnums=range(5))(*args)
+    for a, b_ in zip(got, want):
+        close(a, b_, TIGHT)
 
 
 @pytest.mark.parametrize("loads", [(5, 0, 17, 8), (0, 0, 0, 0), (0, 40, 0, 1), (16, 16, 16, 16)])
@@ -240,7 +314,7 @@ def test_a_layer_the_routers_have_left_runs_no_tile(cfg):
     flat = {**flat, "moe.router.w": jnp.asarray(router)}
     p = {**tree["moe"], "router": {"kernel": flat["moe.router.w"]}}
     y, mut = pattern.FFNS["moe_shared"][0](cfg).apply({"params": p}, x, mutable=["counters"])
-    assert np.asarray(mut["counters"]["moe"][0]).tolist() == [0, 0, 0, 0]
+    assert np.asarray(mut["counters"]["moe"][0]).tolist() == [0, 0, 0, 0, 0]
     with jax.default_matmul_precision("highest"):
         close(y, ref.moe_layer(flat, x, as_model(cfg)), TIGHT)
 

@@ -51,7 +51,7 @@ def test_trainer_runs_the_lfm2_preset_from_yaml_files(tmp_path, lfm2_cfg):
     counted = [e for e in events if e["etype"] == "moe_counters"]
     assert [e["step"] for e in counted] == [1, 2, 3]
     assert all(e["moe_dropped"] == 0 and len(e["moe_assigned_held"]) == 4
-               and len(e["moe_bias_swapped"]) == 4 for e in counted)
+               and len(e["moe_bias_swapped"]) == 4 and e["moe_flushes"] == [1.0] * 4 for e in counted)
     assert not [e for e in events if e["etype"] == "recompile"]
 
 
@@ -64,10 +64,11 @@ def test_trainer_runs_three_steps_from_yaml_files(tmp_path, cfg):
     assert by_type["layer_plan"]["gdn"]["chunks"] == 2 and by_type["layer_plan"]["gdn"]["chunk"] == 64
     assert by_type["layer_plan"]["gdn"]["kernel"] == "xla"  # key / value width 16: no lane tile
     assert by_type["moe_plan"]["experts_held"] == 4 and by_type["moe_plan"]["experts_published"] == 8
+    assert by_type["moe_plan"]["staged_rows"] == 1024
     counted = [e for e in events if e["etype"] == "moe_counters"]
     assert [e["step"] for e in counted] == [1, 2, 3]
     assert all(e["moe_dropped"] == 0 and len(e["moe_assigned_held"]) == 4
-               and "moe_bias_swapped" not in e for e in counted)
+               and e["moe_flushes"] == [1.0] * 4 and "moe_bias_swapped" not in e for e in counted)
     assert not [e for e in events if e["etype"] == "recompile"]
 
 
