@@ -58,6 +58,8 @@ ALLOWLIST: dict[str, frozenset[str]] = {
         "GatedDeltaNet",     # decay, beta, L2 norms: fp32-mandated
         "ShortConv",         # the taps and both gates in fp32 inside one fusion
         "ExpertLayer",       # router, its scores, the selection bias, the shared gate: fp32
+        "_exit_gate",        # the exit gate of a looped stack: fp32 (log p, the entropy and
+                             # the loss over them take their dtype from it, no literal)
     }),
     "ops/gated_delta.py": frozenset({
         # The delta rule's decays, cumulative sums, carried state and every
@@ -69,8 +71,8 @@ ALLOWLIST: dict[str, frozenset[str]] = {
     }),
     "ops/fused_ce.py": frozenset({
         # fp32 logsumexp/loss statistics, fwd + bwd.
-        "_stats_loss", "head_logits", "fused_head_ce", "_fhc_fwd",
-        "_fhc_bwd",
+        "_stats_tokens", "_stats_loss", "head_logits", "fused_head_ce", "_fhc_fwd",
+        "_fhc_bwd", "_head_grads", "fused_head_ce_tokens", "_fhct_fwd", "_fhct_bwd",
     }),
     # Pure Pallas kernel files: fp32 stats/accumulators throughout, by
     # design (flash online softmax, zigzag-ring merge stats).

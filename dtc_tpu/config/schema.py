@@ -217,6 +217,21 @@ class ModelConfig:
     # The head's weight is the embedding, transposed: no lm_head leaf, and
     # embed/wte gets both gradients.
     tie_embeddings: bool = False
+    # A looped stack: the scanned periods run stack_passes times on the SAME
+    # leaves (one outer scan, the parameters broadcast into it). After
+    # EVERY pass the final norm, whose output is what the next pass starts
+    # from, a head pass and an exit gate (a float32 Linear(d_model -> 1)
+    # with bias, head/exit_gate: the exit_gate property); the loss is taken
+    # over the exit distribution the gates give, less EXIT_BETA times its
+    # entropy (models/pattern.py: "Passes"). Not with leading_pattern nor a
+    # tied head.
+    stack_passes: int = 1
+    # Where a layer's norms sit: "pre" (x += f(rms(x))) or "sandwich"
+    # (x += rms(f(rms(x))): norm_1_post / norm_2_post, four norms a layer).
+    norm_placement: str = "pre"
+    # gated_attn / attn: q and k RMS-normed over the head (q_norm / k_norm
+    # leaves), or neither.
+    qk_norm: bool = True
     # gated_attn / attn: n_heads query heads of attn_head_dim (0 = d_model /
     # n_heads) on n_kv_heads KV heads (0 = n_heads); rotary positions on
     # the first rope_fraction of each head, half-split pairing. gated_attn's
@@ -348,10 +363,12 @@ class ModelConfig:
             object.__setattr__(self, name, float(getattr(self, name)))
         if self.layer_pattern:
             self._check_pattern()
-        elif self.leading_pattern or self.tie_embeddings:
+        elif (self.leading_pattern or self.tie_embeddings or self.stack_passes != 1
+              or self.norm_placement != "pre" or not self.qk_norm):
             raise ValueError(
-                "leading_pattern and tie_embeddings belong to a layer-pattern "
-                "model (layer_pattern set); the GPT-2 block has neither"
+                "leading_pattern, tie_embeddings, stack_passes, norm_placement "
+                "and qk_norm belong to a layer-pattern model (layer_pattern "
+                "set); the GPT-2 block has none of them"
             )
         if self.remat_mode not in ("none", "block", "block_save_flash", "mlp"):
             raise ValueError(
@@ -382,6 +399,16 @@ class ModelConfig:
                              "'softmax' or 'sigmoid'")
         if self.shortconv_width < 1:
             raise ValueError("shortconv_width must be >= 1")
+        if self.norm_placement not in ("pre", "sandwich"):
+            raise ValueError(f"unknown norm_placement {self.norm_placement!r}; expected "
+                             "'pre' or 'sandwich'")
+        if self.stack_passes < 1:
+            raise ValueError(f"stack_passes={self.stack_passes} must be >= 1")
+        if self.stack_passes > 1 and (self.leading_pattern or self.tie_embeddings):
+            raise ValueError(
+                "passes over a stack with leading layers, or with a tied head, are not "
+                "defined yet: stack_passes > 1 needs an empty leading_pattern and an "
+                "untied head")
         if self.dropout or self.adapter.rank:
             raise ValueError("pattern layers have no dropout and no adapters")
         if self.n_heads % self.kv_heads:
@@ -425,6 +452,11 @@ class ModelConfig:
         entries = [(e, 1) for e in self.leading_pattern]
         entries += [(e, self.pattern_periods) for e in self.layer_pattern]
         return [(*pattern_kinds(e), n) for e, n in entries]
+
+    @property
+    def exit_gate(self) -> bool:
+        """A looped stack reads out and scores an exit after every pass."""
+        return self.stack_passes > 1
 
     @property
     def head_dim(self) -> int:

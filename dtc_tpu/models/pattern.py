@@ -11,9 +11,11 @@ layer under its own ``nn.remat`` as ``GPTStage`` does, and
 configuration alone. An empty pattern is the GPT-2 block of
 ``models/gpt.py``, untouched.
 
-Every pattern layer is pre-norm and residual::
+Every pattern layer is residual, pre-normed or (``norm_placement:
+sandwich``: ``norm_1_post`` / ``norm_2_post``) normed on both sides::
 
-    x += mixer(rms(x; w1));  x += ffn(rms(x; w2))
+    x += mixer(rms(x; w1));  x += ffn(rms(x; w2))                          (pre)
+    x += rms(mixer(rms(x; w1)); w1');  x += rms(ffn(rms(x; w2)); w2')      (sandwich)
     rms(x; w) = x * rsqrt(mean(x^2) + eps) * (1 + w)       (norm_gain zero_centred)
               = x * rsqrt(mean(x^2) + eps) * w             (norm_gain plain)
 
@@ -22,11 +24,38 @@ with a token embedding only (positions are the mixers' business), a final
 the embedding transposed — through the fused head+CE op that ``GPTHead``
 uses. No biases, no dropout.
 
+Passes (``stack_passes`` T > 1: a looped stack). The scanned
+periods run T times on the SAME leaves: ONE outer scan whose body is the
+stack and the head's readout and into which the parameters are broadcast
+(``nn.scan`` over a function of this module, so the tree keeps its paths and
+the step holds one copy of the stack; every leaf's gradient is the sum over
+its T uses), the periods' scan inside it, each layer under its own remat.
+After EVERY pass the final norm, whose output the next pass starts from, the
+head (one ``head/lm_head`` leaf, per-token cross-entropy ``CE_t`` through
+``ops/fused_ce.fused_head_ce_tokens``) and the exit gate ``z_t = h_t .
+w + b`` (``head/exit_gate``, float32). After the last pass, per token::
+
+    log p_t = log_sigmoid(z_t) + sum_{j<t} log_sigmoid(-z_j)    (t < T)
+    log p_T =                    sum_{j<T} log_sigmoid(-z_j)    (what is left; z_T is unused)
+    loss    = mean_n [ sum_t p_t CE_t  +  EXIT_BETA * sum_t p_t log p_t ]
+
+Nothing is detached. Without ``targets`` the loop runs without readouts and
+the last pass's logits come back; the passes' logits never exist together.
+No pass's logits are kept for the backward: the op makes them again
+there (T passes' do not fit beside the state; PERF.md section 4).
+
+Counters (collection ``counters``, by the name sowed; ``train_step`` hands
+them on as ``{name: rows}`` beside the loss): ``"moe"`` — one row of
+:data:`COUNTERS` an expert layer, and a pass where the stack is looped;
+``"passes"`` — one row of :data:`PASS_COUNTERS` a pass, sowed once by the
+model after the last pass. The trainer emits a ``moe_counters`` and a
+``pass_counters`` event a step from them.
+
 Mixers:
 
 - ``gated_attn`` / ``attn`` — softmax attention, ``n_heads`` query heads on
   ``n_kv_heads`` KV heads of ``head_dim``; q and k RMS-normed over the
-  head; rotary positions on the first ``rope_fraction`` of the head,
+  head (``qk_norm``, else neither); rotary positions on the first ``rope_fraction`` of the head,
   half-split pairing. ``gated_attn``'s query projection also yields a
   per-head output gate; ``attn`` has none. Through
   ``ops/attention.causal_attention`` (flash on the chip, KV groups picked by
@@ -52,14 +81,17 @@ FFN:
 - ``swiglu`` — a dense SwiGLU of width ``d_ff``.
 
 The float32 islands are the norms, the router and its scores, the short
-convolutions' taps, the decay and the scan's carried state; the matmuls run
-in ``compute_dtype``.
+convolutions' taps, the decay and the scan's carried state, the exit gate,
+``log p``, the entropy and the loss over them; the matmuls run in
+``compute_dtype``.
 
 Scopes on the device path (``benchmark/spans.py`` reads the op-name path):
 ``gdn`` with ``proj`` / ``conv`` / ``scan`` / ``out``; ``shortconv`` with
 ``proj`` / ``conv`` / ``out``; ``attn_full`` with ``attn_kernel`` around the
 kernel call; ``moe`` with ``router`` / ``dispatch`` / ``experts`` /
-``combine`` / ``shared``; ``mlp``; ``head``.
+``combine`` / ``shared``; ``mlp``; ``post_norm`` (a sandwich layer's two
+outer norms); ``head`` (every pass's norm, head and CE); ``exit`` (the gate
+under ``head``, and the exit distribution and the loss after the last pass).
 """
 
 from __future__ import annotations
@@ -83,9 +115,18 @@ from dtc_tpu.ops.gated_delta import gated_delta_chunked, supports_chunk_kernel
 #: choices plain top-k of the scores would not have made.
 COUNTERS = (*md.HELD_COUNTERS, "moe_bias_swapped")
 
+#: A looped stack's readings, one row a pass (``"passes"`` of the counters):
+#: the tokens' mean exit probability and mean cross-entropy at that pass, and
+#: the mean entropy of the exit distribution (one number, in every row).
+PASS_COUNTERS = ("exit_p", "pass_ce", "exit_entropy")
+
 #: ``moe_score: sigmoid``: the chosen scores are normalised over their sum
 #: plus this.
 SIGMOID_GATE_EPS = 1e-6
+
+#: A looped stack's loss takes this times the exit distribution's entropy off
+#: the expected cross-entropy (a uniform prior over the passes).
+EXIT_BETA = 0.1
 
 NOT_SERVED = (
     "a layer-pattern model trains only: there is no cache for recurrent "
@@ -156,8 +197,10 @@ class Attention(nn.Module):
                 q = _dense(h * hd, "q_proj", cfg)(x).reshape(b, t, h, hd)
             k = _dense(hk * hd, "k_proj", cfg)(x).reshape(b, t, hk, hd)
             v = _dense(hk * hd, "v_proj", cfg)(x).reshape(b, t, hk, hd)
-            q = rotary(_norm(cfg, "q_norm")(q), cfg.rope_theta, cfg.rope_fraction)
-            k = rotary(_norm(cfg, "k_norm")(k), cfg.rope_theta, cfg.rope_fraction)
+            q_norm, k_norm = ((_norm(cfg, "q_norm"), _norm(cfg, "k_norm")) if cfg.qk_norm
+                              else (lambda a: a,) * 2)
+            q = rotary(q_norm(q), cfg.rope_theta, cfg.rope_fraction)
+            k = rotary(k_norm(k), cfg.rope_theta, cfg.rope_fraction)
             q, k = q.astype(cdtype), k.astype(cdtype)
         with jax.named_scope("attn_kernel"):
             out = causal_attention(
@@ -405,10 +448,16 @@ class PatternBlock(nn.Module):
         cfg = self.cfg
         cdtype = _dtype(cfg.compute_dtype)
         (mixer_cls, mixer_name), (ffn_cls, ffn_name) = MIXERS[self.kinds[0]], FFNS[self.kinds[1]]
+        def post(y, name):
+            if cfg.norm_placement != "sandwich":
+                return y
+            with jax.named_scope("post_norm"):
+                return _norm(cfg, name)(y).astype(cdtype)
+
         h = _norm(cfg, "norm_1")(x).astype(cdtype)
-        x = x + mixer_cls(cfg, name=mixer_name)(h)
+        x = x + post(mixer_cls(cfg, name=mixer_name)(h), "norm_1_post")
         h = _norm(cfg, "norm_2")(x).astype(cdtype)
-        x = x + ffn_cls(cfg, name=ffn_name)(h)
+        x = x + post(ffn_cls(cfg, name=ffn_name)(h), "norm_2_post")
         return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
 
@@ -474,6 +523,14 @@ class PatternStage(nn.Module):
         return h
 
 
+def _exit_gate(dense: type, pdtype) -> nn.Module:
+    """A looped stack's exit gate, of the ``dense`` class handed in: a
+    ``Linear(d -> 1)`` with bias that computes in float32 whatever the
+    compute dtype (``log p``, the entropy and the loss over them take their
+    dtype from its score)."""
+    return dense(1, dtype=jnp.float32, param_dtype=pdtype)
+
+
 class PatternHead(nn.Module):
     """Final RMS norm and the head without bias, through the fused head +
     cross-entropy op (``ops/fused_ce.py``) when ``targets`` are given. The
@@ -481,27 +538,86 @@ class PatternHead(nn.Module):
     ``tie_embeddings`` the embedding ``tied`` (vocab, d) that the caller
     hands in, transposed: that leaf then gets both gradients. The op folds a
     bias gradient into its dW matmul; the zero bias passed here is a
-    constant, its gradient discarded."""
+    constant, its gradient discarded. A looped stack calls the parts, once
+    a pass: :meth:`norm`, then :meth:`token_losses` and :meth:`gate`."""
 
     cfg: ModelConfig
 
-    @nn.compact
-    def __call__(self, h: jax.Array, targets: jax.Array | None = None,
-                 tied: jax.Array | None = None) -> jax.Array:
-        from dtc_tpu.ops.fused_ce import fused_head_ce, head_logits
-
+    def setup(self):
         cfg = self.cfg
         pdtype = _dtype(cfg.param_dtype)
-        h = _norm(cfg, "norm_f")(h).astype(_dtype(cfg.compute_dtype))
-        if tied is not None:
-            kernel = tied.T
-        else:
-            kernel = self.param("lm_head", nn.initializers.lecun_normal(),
-                                (cfg.d_model, cfg.padded_vocab_size), pdtype)
-        bias = jnp.zeros((cfg.padded_vocab_size,), pdtype)
+        # A looped stack keeps every pass's readout for the backward: the
+        # norm and the gate then save their compute-dtype inputs only and
+        # make their float32 intermediates again (else four (T, B, S, d)
+        # float32 arrays a step: 1 GiB in the Ouro cell).
+        norm, dense = ((nn.remat(RMSNorm), nn.remat(nn.Dense)) if cfg.stack_passes > 1
+                       else (RMSNorm, nn.Dense))
+        self.norm_f = norm(cfg.norm_eps, zero_centred=cfg.norm_gain == "zero_centred")
+        if not cfg.tie_embeddings:
+            self.lm_head = self.param("lm_head", nn.initializers.lecun_normal(),
+                                      (cfg.d_model, cfg.padded_vocab_size), pdtype)
+        if cfg.exit_gate:
+            self.exit_gate = _exit_gate(dense, pdtype)
+
+    def norm(self, h: jax.Array) -> jax.Array:
+        return self.norm_f(h).astype(_dtype(self.cfg.compute_dtype))
+
+    def _bias(self) -> jax.Array:
+        return jnp.zeros((self.cfg.padded_vocab_size,), _dtype(self.cfg.param_dtype))
+
+    def logits(self, h: jax.Array, kernel: jax.Array | None = None) -> jax.Array:
+        """Logits of normed ``h`` (``kernel``: a tied head's)."""
+        from dtc_tpu.ops.fused_ce import head_logits
+
+        return head_logits(h, self.lm_head if kernel is None else kernel, self._bias(),
+                           self.cfg.vocab_size)
+
+    def token_losses(self, h: jax.Array, targets: jax.Array) -> jax.Array:
+        """Every token's cross-entropy (float32) of normed ``h``."""
+        from dtc_tpu.ops.fused_ce import fused_head_ce_tokens
+
+        return fused_head_ce_tokens(h, self.lm_head, self._bias(), targets, self.cfg.vocab_size)
+
+    def gate(self, h: jax.Array) -> jax.Array:
+        """The exit gate's score of normed ``h``: (B, T) float32."""
+        with jax.named_scope("exit"):
+            return self.exit_gate(h)[..., 0]  # promoted to float32 inside, under its remat
+
+    def __call__(self, h: jax.Array, targets: jax.Array | None = None,
+                 tied: jax.Array | None = None) -> jax.Array:
+        from dtc_tpu.ops.fused_ce import fused_head_ce
+
+        h = self.norm(h)
+        kernel = self.lm_head if tied is None else tied.T
         if targets is not None:
-            return fused_head_ce(h, kernel, bias, targets, cfg.vocab_size)
-        return head_logits(h, kernel, bias, cfg.vocab_size)
+            return fused_head_ce(h, kernel, self._bias(), targets, self.cfg.vocab_size)
+        return self.logits(h, kernel)
+
+
+def exit_distribution(z: jax.Array) -> jax.Array:
+    """``log p`` (T, ...) of the pass a token exits at, from the gates'
+    scores ``z`` (T, ...) with the passes in front: ``lambda_t =
+    sigmoid(z_t)`` of what earlier passes left, the last pass taking the
+    remainder whatever ``z_T`` says (module docstring, "Passes")."""
+    stay = jax.nn.log_sigmoid(-z[:-1])
+    first = jnp.zeros_like(z[:1])
+    left = jnp.concatenate([first, jnp.cumsum(stay, axis=0)])       # sum_{j<t} log(1 - lambda_j)
+    return left + jnp.concatenate([jax.nn.log_sigmoid(z[:-1]), first])
+
+
+def exit_loss(ce: jax.Array, z: jax.Array, beta: float = EXIT_BETA) -> tuple[jax.Array, jax.Array]:
+    """(loss, one row of :data:`PASS_COUNTERS` a pass) from the passes'
+    per-token cross-entropies and gate scores, both (T, ...) float32: the
+    tokens' mean of the expected cross-entropy under the exit distribution
+    less ``beta`` times that distribution's entropy."""
+    logp = exit_distribution(z)
+    p = jnp.exp(logp)
+    entropy = -jnp.sum(p * logp, axis=0)
+    loss = jnp.mean(jnp.sum(p * ce, axis=0) - beta * entropy)
+    tokens = tuple(range(1, ce.ndim))
+    rows = jnp.stack([p.mean(tokens), ce.mean(tokens),
+                      jnp.broadcast_to(entropy.mean(), ce.shape[:1])], axis=-1)
+    return loss, jax.lax.stop_gradient(rows)
 
 
 class PatternLM(nn.Module):
@@ -509,19 +625,54 @@ class PatternLM(nn.Module):
     "stage": {"leading": {"layer_<i>": ...}, "periods": {"layer_<i>":
     ...}}, "head"}``: a period's leaves stacked over periods, the leading
     layers' (where the configuration has any) plain; no ``head/lm_head``
-    with ``tie_embeddings``. Training and evaluation only."""
+    with ``tie_embeddings``. A looped stack (``stack_passes`` > 1) has the
+    tree of one stack, its passes sharing every leaf, and ``head/exit_gate``.
+    Training and evaluation only."""
 
     cfg: ModelConfig
 
-    @nn.compact
+    def setup(self):
+        self.embed = PatternEmbed(self.cfg)
+        self.stage = PatternStage(self.cfg)
+        self.head = PatternHead(self.cfg)
+
     def __call__(self, x: jax.Array, *, train: bool = True, decode: bool = False,
                  targets: jax.Array | None = None) -> jax.Array:
         if decode:
             raise NotImplementedError(NOT_SERVED)
-        embed = PatternEmbed(self.cfg, name="embed")
-        h = PatternStage(self.cfg, name="stage")(embed(x), train=train)
-        tied = embed.table() if self.cfg.tie_embeddings else None
-        return PatternHead(self.cfg, name="head")(h, targets=targets, tied=tied)
+        h = self.embed(x)
+        if self.cfg.stack_passes > 1:
+            return self._looped(h, train, targets)
+        h = self.stage(h, train=train)
+        tied = self.embed.table() if self.cfg.tie_embeddings else None
+        return self.head(h, targets=targets, tied=tied)
+
+    def _looped(self, h: jax.Array, train: bool, targets: jax.Array | None) -> jax.Array:
+        """The passes as one scanned body (module docstring, "Passes")."""
+        cfg = self.cfg
+
+        def one_pass(mdl, h, _):
+            h = mdl.stage(h, train=train)
+            # the head's parts run under their own method names: the scope
+            # the traces are read by is set here
+            with jax.named_scope("head"):
+                h = mdl.head.norm(h)
+                if targets is not None:
+                    return h, (mdl.head.token_losses(h, targets), mdl.head.gate(h))
+                if mdl.is_initializing():
+                    mdl.head.gate(h)  # model.init passes no targets: the gate's leaves are made here
+                return h, None
+
+        h, read = nn.scan(
+            one_pass, variable_broadcast="params", variable_axes={"counters": 0},
+            split_rngs={"params": False}, length=cfg.stack_passes,
+        )(self, h, None)
+        if targets is None:
+            return self.head.logits(h)
+        with jax.named_scope("exit"):
+            loss, rows = exit_loss(*read)
+        self.sow("counters", "passes", rows)
+        return loss
 
 
 def build_model(cfg: ModelConfig) -> nn.Module:
@@ -540,7 +691,8 @@ def pattern_param_count(cfg: ModelConfig) -> int:
     d = cfg.d_model
     nk, nv = cfg.gdn_key_heads * cfg.gdn_key_dim, cfg.gdn_value_heads * cfg.gdn_value_dim
     hd = cfg.head_dim
-    attn = d * cfg.n_heads * hd + 2 * d * cfg.kv_heads * hd + 2 * hd + cfg.n_heads * hd * d
+    attn = (d * cfg.n_heads * hd + 2 * d * cfg.kv_heads * hd + 2 * hd * cfg.qk_norm
+            + cfg.n_heads * hd * d)
     routed = (d * cfg.moe_experts + cfg.experts_held * 3 * d * cfg.moe_d_ff
               + cfg.moe_selection_bias * cfg.moe_experts)
     per = {
@@ -553,8 +705,10 @@ def pattern_param_count(cfg: ModelConfig) -> int:
         "moe": routed,
         "swiglu": 3 * d * cfg.d_ff,
     }
-    layers = sum(n * (per[m] + per[f] + 2 * d) for m, f, n in cfg.layer_census())
-    return layers + (1 if cfg.tie_embeddings else 2) * cfg.padded_vocab_size * d + d
+    norms = (4 if cfg.norm_placement == "sandwich" else 2) * d
+    layers = sum(n * (per[m] + per[f] + norms) for m, f, n in cfg.layer_census())
+    head = (1 if cfg.tie_embeddings else 2) * cfg.padded_vocab_size * d + d
+    return layers + head + cfg.exit_gate * (d + 1)
 
 
 def _attention_plan(cfg: ModelConfig) -> dict:
@@ -566,7 +720,7 @@ def _attention_plan(cfg: ModelConfig) -> dict:
         "heads": cfg.n_heads, "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim,
         "block_q": min(cfg.attention_block_q, cfg.max_seq_len),
         "block_kv": min(cfg.attention_block_kv, cfg.max_seq_len),
-        "rotary_dims": int(cfg.head_dim * cfg.rope_fraction),
+        "rotary_dims": int(cfg.head_dim * cfg.rope_fraction), "qk_norm": cfg.qk_norm,
     }
 
 
@@ -578,7 +732,13 @@ def layer_plan(cfg: ModelConfig) -> dict:
         "pattern": list(cfg.layer_pattern),
         "periods": cfg.pattern_periods,
         "remat": cfg.remat_mode,
+        # the passes over the stack, each with its own head pass, and where
+        # a layer's norms sit
+        "passes": cfg.stack_passes,
+        "norm_placement": cfg.norm_placement,
     }
+    if cfg.exit_gate:
+        plan["exit"] = {"beta": EXIT_BETA, "pass_logits": "recomputed"}
     if cfg.leading_pattern:
         plan["leading"] = list(cfg.leading_pattern)
     for kind in ("gated_attn", "attn"):

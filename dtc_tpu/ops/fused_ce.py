@@ -15,6 +15,11 @@ one matmul instead of a matmul plus a separate logits pass. Forward numerics
 are bitwise identical to the unfused path (same op sequence as
 ``dtc_tpu.train.train_step.cross_entropy_loss``); backward differs only in
 reduction order (ulp-level).
+
+:func:`fused_head_ce_tokens` is the same op before the mean: every token's
+CE and a per-token cotangent, for a loss that weighs tokens or passes before
+it sums (a looped stack's exit distribution, ``models/pattern.py``). It keeps
+no logits between the passes and makes them again in its backward.
 """
 
 from __future__ import annotations
@@ -48,15 +53,21 @@ def head_logits(h: jax.Array, w: jax.Array, b: jax.Array, vocab_size: int) -> ja
     return nn.with_logical_constraint(logits, ("batch", "seq", "vocab_out"))
 
 
-def _stats_loss(logits: jax.Array, y: jax.Array):
-    """Mean CE + softmax stats. Same op sequence as cross_entropy_loss."""
+def _stats_tokens(logits: jax.Array, y: jax.Array):
+    """Per-token CE (float32, ``y``'s shape) + softmax stats."""
     l32 = logits.astype(jnp.float32)
     maxl = jax.lax.stop_gradient(jnp.max(l32, axis=-1, keepdims=True))
     shifted = l32 - maxl
     logz = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
     iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
     gold = jnp.sum(jnp.where(iota == y[..., None], shifted, 0.0), axis=-1)
-    return (logz - gold).mean(), (maxl, logz)
+    return logz - gold, (maxl, logz)
+
+
+def _stats_loss(logits: jax.Array, y: jax.Array):
+    """Mean CE + softmax stats. Same op sequence as cross_entropy_loss."""
+    ce, stats = _stats_tokens(logits, y)
+    return ce.mean(), stats
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -77,8 +88,9 @@ def _fhc_fwd(h, w, b, y, vocab_size):
     return loss, (h, w, y, logits, maxl, logz)
 
 
-def _fhc_bwd(vocab_size, res, g):
-    h, w, y, logits, maxl, logz = res
+def _head_grads(h, w, y, logits, maxl, logz, g, per_token: bool):
+    """(dh, dw, db) of the head under the cotangent ``g``: of the mean CE (a
+    scalar) or, ``per_token``, of every token's CE (``y``'s shape)."""
     *lead, v = logits.shape
     d = h.shape[-1]
     n = float(np.prod(lead))
@@ -90,7 +102,8 @@ def _fhc_bwd(vocab_size, res, g):
     p = jnp.exp(l32 - maxl - logz[..., None])
     iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
     onehot = jnp.where(iota == y[..., None], 1.0, 0.0)
-    dl = ((p - onehot) * (g / n)).astype(h.dtype)
+    diff = p - onehot
+    dl = (diff * (g[..., None] if per_token else g / n)).astype(h.dtype)
     dl = nn.with_logical_constraint(dl, ("batch", "seq", "vocab_out"))
     dl2 = dl.reshape(-1, v)
     # The augmented matmul: db rides along as row d of [h; 1]^T @ dlogits.
@@ -103,8 +116,44 @@ def _fhc_bwd(vocab_size, res, g):
         .reshape(h.shape)
         .astype(h.dtype)
     )
+    return dh, dw, db
+
+
+def _fhc_bwd(vocab_size, res, g):
+    h, w, y, logits, maxl, logz = res
+    dh, dw, db = _head_grads(h, w, y, logits, maxl, logz, g, per_token=False)
     dy = np.zeros(y.shape, dtype=jax.dtypes.float0)
     return dh, dw, db, dy
 
 
 fused_head_ce.defvjp(_fhc_fwd, _fhc_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def fused_head_ce_tokens(h, w, b, y, vocab_size):
+    """:func:`fused_head_ce` before the mean: every token's CE, float32 in
+    ``y``'s shape, and a backward that takes a cotangent of that shape — the
+    form a loss needs that weighs tokens (or passes over one head) before it
+    sums. ``dW`` and ``dh`` as there: no ``(N, V)`` float32 tensor in HBM.
+
+    Unlike the scalar form it keeps NO logits for the backward: it makes
+    them again there from ``h`` (one more head matmul) and keeps the softmax
+    statistics only. Its caller is a looped stack that reads out after every
+    pass: T passes' compute-dtype logits (3.0 GiB at 4 x 8192 x 49152) do
+    not fit beside that model's state, one pass's do (PERF.md section 4)."""
+    return _stats_tokens(head_logits(h, w, b, vocab_size), y)[0]
+
+
+def _fhct_fwd(h, w, b, y, vocab_size):
+    ce, (maxl, logz) = _stats_tokens(head_logits(h, w, b, vocab_size), y)
+    return ce, (h, w, b, y, maxl, logz)
+
+
+def _fhct_bwd(vocab_size, res, g):
+    h, w, b, y, maxl, logz = res
+    logits = head_logits(h, w, b, vocab_size)
+    dh, dw, db = _head_grads(h, w, y, logits, maxl, logz, g, per_token=True)
+    return dh, dw, db, np.zeros(y.shape, dtype=jax.dtypes.float0)
+
+
+fused_head_ce_tokens.defvjp(_fhct_fwd, _fhct_bwd)

@@ -222,12 +222,17 @@ PARAM_AXES_TABLE: tuple[tuple[tuple[str, ...], tuple[str | None, ...]], ...] = (
     # PERIODS (the scan's axis, "layers" again); q/k/v/out_proj kernels and
     # the router take the rows above. Norm gains over a head, the
     # convolution taps, the per-head decay parameters and the router's
-    # selection bias are replicated. A leading layer's leaves (under
-    # "leading") are not stacked: they take the same rows less "layers".
+    # selection bias are replicated; so is the exit gate's bias. A leading
+    # layer's leaves (under "leading") are not stacked: they take the same
+    # rows less "layers". A looped stack's passes share every leaf.
     (("norm_1", "scale"), ("layers", "embed_p")),
     (("norm_2", "scale"), ("layers", "embed_p")),
+    (("norm_1_post", "scale"), ("layers", "embed_p")),
+    (("norm_2_post", "scale"), ("layers", "embed_p")),
     (("norm_f", "scale"), ("embed_p",)),
     (("lm_head",), ("embed_p", "vocab_out")),
+    (("exit_gate", "kernel"), ("embed_p", None)),
+    (("exit_gate", "bias"), (None,)),
     (("q_norm", "scale"), ("layers", None)),
     (("k_norm", "scale"), ("layers", None)),
     (("in_proj_qkvz", "kernel"), ("layers", "embed_p", "qkv")),

@@ -212,13 +212,16 @@ def cross_entropy_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:
     return _stats_loss(logits, targets)[0]
 
 
-def stack_counters(mutated: dict) -> jax.Array:
-    """The sowed "counters" collection (pattern models: one row of
-    ``models.pattern.COUNTERS`` a layer that counts) as one ``(layers,
-    n)`` float32 array, in the tree's order."""
-    rows = [leaf.reshape(-1, leaf.shape[-1])
-            for leaf in jax.tree.leaves(mutated.get("counters", {}))]
-    return jnp.concatenate(rows, axis=0)
+def stack_counters(mutated: dict) -> dict[str, jax.Array]:
+    """The sowed "counters" collection of a pattern model by the name each
+    was sowed under (``models/pattern.py``, "Counters"): ``"moe"`` — one
+    row of ``COUNTERS`` a layer that counts, ``(layers, n)`` float32 in the
+    tree's order; ``"passes"`` — a looped stack's ``(passes, 3)``
+    ``PASS_COUNTERS``. A name nothing sowed is absent."""
+    rows: dict[str, list[jax.Array]] = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(mutated.get("counters", {})):
+        rows.setdefault(path[-2].key, []).append(leaf.reshape(-1, leaf.shape[-1]))
+    return {name: jnp.concatenate(r, axis=0) for name, r in rows.items()}
 
 
 def create_gspmd_train_step(
@@ -245,8 +248,9 @@ def create_gspmd_train_step(
     adapter checkpoints/rollback operate on the tiny subtree for free.
 
     ``counters`` (a model that sows the "counters" collection): the step
-    returns ``(state, loss, counters)``, the third a small device array the
-    caller fetches with the loss — never by a sync of its own.
+    returns ``(state, loss, counters)``, the third a dict of small device
+    arrays (:func:`stack_counters`) the caller fetches with the loss — never
+    by a sync of its own.
     """
     jit_kwargs: dict[str, Any] = {"donate_argnums": (0,)}
     if state is not None:

@@ -197,12 +197,14 @@ def _reshard_onto(tree: PyTree, mesh: Mesh) -> PyTree:
     return jax.tree.map(leaf, tree)
 
 
-def _emit_counters(tele, steps: list[int], counted: np.ndarray) -> None:
-    """One ``moe_counters`` event a step from the fetched ``(steps,
-    layers, n)`` counters."""
-    from dtc_tpu.models.pattern import COUNTERS
+def _emit_counters(tele, steps: list[int], counted: dict[str, np.ndarray]) -> None:
+    """A step's counter events from what was fetched (``train_step.
+    stack_counters``, each array with the steps in front): ``moe_counters``
+    from the expert layers' ``(steps, layers, n)`` rows, ``pass_counters``
+    from a looped stack's ``(steps, passes, 3)``."""
+    from dtc_tpu.models.pattern import COUNTERS, PASS_COUNTERS
 
-    for step, rows in zip(steps, counted):
+    for step, rows in zip(steps, counted.get("moe", ())):
         by_name = dict(zip(COUNTERS, rows.T))  # as many names as the rows are wide
         fields = dict(
             moe_assigned_held=[float(v) for v in by_name["moe_assigned_held"]],
@@ -214,6 +216,14 @@ def _emit_counters(tele, steps: list[int], counted: np.ndarray) -> None:
         if "moe_bias_swapped" in by_name:  # a router with a selection bias
             fields["moe_bias_swapped"] = [float(v) for v in by_name["moe_bias_swapped"]]
         tele.registry.emit("moe_counters", step=int(step), **fields)
+    for step, rows in zip(steps, counted.get("passes", ())):
+        by_name = dict(zip(PASS_COUNTERS, rows.T))
+        tele.registry.emit(
+            "pass_counters", step=int(step),
+            exit_p=[float(v) for v in by_name["exit_p"]],
+            pass_ce=[float(v) for v in by_name["pass_ce"]],
+            exit_entropy=float(by_name["exit_entropy"][0]),
+        )
 
 
 def _guarded_optimizer(train_cfg: TrainConfig, opt_cfg: OptimConfig):
@@ -822,8 +832,9 @@ def _train(
         if flash_plan is not None:
             tele.registry.emit("flash_plan", **flash_plan)
         if model_cfg.layer_pattern:
-            # What a pattern model will run, once: the pattern with each
-            # mixer kind's kernel and tiles, and the expert layer's share.
+            # What a pattern model will run, once: the pattern with its
+            # passes and norm placement, each mixer kind's kernel and tiles,
+            # and the expert layer's share.
             from dtc_tpu.models.pattern import layer_plan, moe_plan
 
             tele.registry.emit("layer_plan", **layer_plan(model_cfg))
@@ -1241,10 +1252,11 @@ def _train(
             if lead:
                 print("Start measuring")
             device_losses: list[jax.Array] = []
-            # A pattern model's per-step counters (models/pattern.COUNTERS,
-            # one row a layer): kept on the device beside the losses and
-            # fetched with them at the log boundary, never on their own.
-            device_counters: list[jax.Array] = []
+            # A pattern model's per-step counters (train_step.stack_counters:
+            # a dict of small arrays a step): kept on the device beside the
+            # losses and fetched with them at the log boundary, never on
+            # their own.
+            device_counters: list[dict[str, jax.Array]] = []
             pending_rows: list[tuple[int, float]] = []
             # The snapshot dispatch's per-leaf copy executables compile on
             # the FIRST begin() for a given mesh; attribute that one tick
@@ -1410,7 +1422,8 @@ def _train(
                     # One stacked transfer, not len(window) scalar fetches.
                     fetched, counted = jax.device_get((
                         jnp.stack(device_losses),
-                        jnp.stack(device_counters) if device_counters else None,
+                        jax.tree.map(lambda *a: jnp.stack(a), *device_counters)
+                        if device_counters else None,
                     ))
                     losses = [float(v) for v in fetched]
                     now = time.perf_counter()  # after the device sync

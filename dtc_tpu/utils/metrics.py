@@ -108,15 +108,19 @@ def pattern_step_flops(cfg: ModelConfig, batch: int, seq_len: int) -> float:
     """Training FLOPs of one step of a layer-pattern model: 6 x matmul
     parameters x tokens (head counted), causal attention as
     :func:`gpt_step_flops` counts it (12 B T^2 H hd / 2 a layer), and the
-    recurrence's least work. Recomputation is not counted.
-    ``benchmark/flops_qwen3_next.py`` and ``flops_lfm2_moe.py`` hold copies; tests
-    keep them equal."""
+    recurrence's least work. A looped stack (``stack_passes``) runs its
+    layers and its head once a pass: both count that many times, the
+    parameters once (the exit gate's d products a token and pass are left
+    out). Recomputation is not counted.
+    ``benchmark/flops_qwen3_next.py``, ``flops_lfm2_moe.py`` and
+    ``flops_ouro.py`` hold copies; tests keep them equal."""
     tokens = batch * seq_len
     per = pattern_matmul_params(cfg)
     census = cfg.layer_census()
-    n_matmul = sum(n * (per[m] + per[f]) for m, f, n in census) + per["head"]
-    n_attn = sum(n for m, _, n in census if m in ("gated_attn", "attn"))
-    n_gdn = sum(n for m, _, n in census if m == "gdn")
+    passes = cfg.stack_passes
+    n_matmul = passes * (sum(n * (per[m] + per[f]) for m, f, n in census) + per["head"])
+    n_attn = passes * sum(n for m, _, n in census if m in ("gated_attn", "attn"))
+    n_gdn = passes * sum(n for m, _, n in census if m == "gdn")
     attn = 12.0 * n_attn * batch * seq_len**2 * cfg.n_heads * cfg.head_dim / 2.0
     return 6.0 * n_matmul * tokens + attn + n_gdn * gdn_scan_flops(cfg, tokens)
 

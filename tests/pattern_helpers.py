@@ -12,6 +12,9 @@ recurrence; d 64, 2 key / 4 value heads of 16, 2 query heads on 1 KV head of
 a sum of shifted copies; d 64, a leading dense layer of width 96, 4 query
 heads on 1 KV head of 16, 8 sigmoid-scored experts top-2 of width 32 chosen
 with a selection bias, the head tied, vocabulary 256.
+:data:`OURO`: ``benchmark/reference_ouro.py``, the passes as a Python loop;
+d 64, three sandwich-normed layers of 4 heads of 16 and a SwiGLU of 96 run
+four times, an exit gate after every pass, vocabulary 256.
 
 Tolerances. float32 ``tight``: 2e-4 of the largest element — the two sides
 differ in summation order only (chunked matmuls against a recurrence, a
@@ -69,6 +72,7 @@ class Family:
 
 QWEN3 = Family.of("reference_qwen3_next", "qwen3-next-80b-a3b", "model_config_pattern_dev.yaml")
 LFM2 = Family.of("reference_lfm2_moe", "lfm2-8b-a1b", "model_config_pattern_lfm2_dev.yaml")
+OURO = Family.of("reference_ouro", "ouro-2.6b", "model_config_pattern_ouro_dev.yaml")
 ref, LEAF_NAMES, TOY_YAML = QWEN3.ref, QWEN3.leaf_names, QWEN3.yaml
 
 
@@ -80,6 +84,11 @@ def cfg() -> ModelConfig:
 @pytest.fixture(scope="module")
 def lfm2_cfg() -> ModelConfig:
     return LFM2.cfg()
+
+
+@pytest.fixture(scope="module")
+def ouro_cfg() -> ModelConfig:
+    return OURO.cfg()
 
 
 def cell_cfg(name: str) -> tuple[ModelConfig, dict]:

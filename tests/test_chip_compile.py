@@ -118,11 +118,13 @@ def _sds(shape, dtype, sharding):
 
 #: Per-chip attention shapes (B, T, H, D) of the main training paths: the
 #: flagship, and the benchmark's two cells (gpt2-medium on one chip,
-#: gpt2-large under FSDP: 8 rows a chip in both).
+#: gpt2-large under FSDP: 8 rows a chip in both), and Ouro-2.6B's cell: 16
+#: heads of 128 without KV groups, one head a lane group of the packed family.
 FLASH_SHAPES = {
     "flagship": (8, 512, 16, 32),
     "gpt2_medium_b8": (8, 1024, 16, 64),
     "gpt2_large_fsdp4_b32": (8, 1024, 20, 64),
+    "ouro_b2x4096": (2, 4096, 16, 128),
 }
 
 
@@ -441,6 +443,9 @@ PATTERN_CELLS = {
         "qwen3-next-80b-a3b", 5, ("gdn_chunks_fwd", "gdn_chunks_fwd_res", "gdn_chunks_bwd")),
     # flash forward, dq and dk/dv on the transposed layout: 32 query heads on 8 KV heads of 64
     "lfm2-8b-a1b.train-ep4share-8k": ("lfm2-8b-a1b", 3, ()),
+    # the packed flash kernels at 16 heads of 128 (forward, and the fused backward), in the
+    # ONE copy of the stack that the scan over the four passes holds
+    "ouro-2.6b.train-loop4-b2x4096": ("ouro-2.6b", 2, ()),
 }
 
 
@@ -453,7 +458,10 @@ def test_pattern_cell_train_step_fits_one_chip(topo, cell):
     state carried in VMEM), the experts' loop over tiles. LFM2-8B-A1B's leading
     dense layer and one period (8 of 32 experts held, 4 rows x 8192): KV
     groups 4 wide at head size 64 through the same kernels, the short
-    convolutions, the tied head."""
+    convolutions, the tied head. Ouro-2.6B's 8 layers run four times (2 rows x
+    4096): the passes are one scanned body, so the step holds one copy of the
+    stack, and no pass's logits are kept beside 9.8 GB of state (the per-token CE
+    recomputes them in its backward, +6 % operations)."""
     import json
 
     from flax import linen as nn
@@ -495,8 +503,13 @@ def test_pattern_cell_train_step_fits_one_chip(topo, cell):
     peak = compiled.memory_analysis().peak_memory_in_bytes
     print("peak_memory_in_bytes", peak)
     assert 0 < peak < V5E_HBM_BYTES
-    _expert_loop_adds_in_place(text, rows * cfg.max_seq_len, moe_plan(cfg, rows * cfg.max_seq_len)["staged_rows"],
-                               cfg.d_model)
+    if cfg.stack_passes > 1:
+        # one copy of the stack: the flash forward once in the primal and once in a
+        # layer's recomputation, one fused backward — not that times the passes
+        assert text.count("tpu_custom_call") <= 6
+    plan = moe_plan(cfg, rows * cfg.max_seq_len)
+    if plan is not None:
+        _expert_loop_adds_in_place(text, rows * cfg.max_seq_len, plan["staged_rows"], cfg.d_model)
 
 
 def _expert_loop_adds_in_place(text: str, tokens: int, staged_rows: int, d: int):

@@ -152,6 +152,8 @@ def test_whole_model_loss_and_clipped_gradients(opt_cfg, family):
     w = weights(cfg, seed=5, family=family)
     batch = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, cfg.max_seq_len + 1), dtype=np.int32)
     state, (loss,), counters = one_device_steps(cfg, opt_cfg, [batch], w, family)
+    assert list(counters) == ["moe"]
+    counters = counters["moe"]
     assert counters.shape == (4, 6 if cfg.moe_selection_bias else 5) and float(counters[:, 3].sum()) == 0.0
     assert counters[:, 4].tolist() == [1.0] * 4  # one flush a layer
     optim = {"lr": opt_cfg.lr, "weight_decay": opt_cfg.weight_decay, "grad_clip": opt_cfg.grad_clip}
@@ -274,6 +276,6 @@ def test_the_benchmark_reads_the_flushes_a_layer_ran(flushes, want):
         emit = lambda etype, **fields: events.append({"etype": etype, **fields})  # noqa: E731
         tele = types.SimpleNamespace(registry=types.SimpleNamespace(emit=emit))
         counted = np.array([[[10240.0, 480.0, 320.0, 0.0, f] for f in row] for row in flushes])
-        _emit_counters(tele, [1, 2], counted)
+        _emit_counters(tele, [1, 2], {"moe": counted})
         assert [e["moe_flushes"] for e in events[1:]] == flushes and events[1]["moe_dropped"] == 0.0
     assert reader.read({"events": events}) == want

@@ -13,7 +13,7 @@ from dtc_tpu.config.loader import load_config
 from dtc_tpu.models import pattern
 from tests.conftest import make_train_cfg
 from tests.pattern_helpers import (  # noqa: F401  (cfg, lfm2_cfg are fixtures)
-    LFM2, QWEN3, REPO, TOY_YAML, cfg, lfm2_cfg, one_device_steps, program_params, weights,
+    LFM2, OURO, QWEN3, REPO, TOY_YAML, cfg, lfm2_cfg, one_device_steps, program_params, weights,
 )
 
 
@@ -55,6 +55,23 @@ def test_trainer_runs_the_lfm2_preset_from_yaml_files(tmp_path, lfm2_cfg):
     assert not [e for e in events if e["etype"] == "recompile"]
 
 
+def test_trainer_runs_the_ouro_preset_from_yaml_files(tmp_path):
+    """The looped preset through the same path: the plan states the passes,
+    the head passes a step and the norm placement; every step carries the
+    passes' readings and no expert counters."""
+    events = _three_steps_from_yaml_files(tmp_path, OURO.yaml)
+    by_type = {e["etype"]: e for e in events}
+    plan = by_type["layer_plan"]
+    assert (plan["passes"], plan["norm_placement"], plan["periods"]) == (4, "sandwich", 3)
+    assert plan["exit"]["beta"] == 0.1 and plan["attn"]["qk_norm"] is False
+    assert "moe_plan" not in by_type and "moe_counters" not in by_type
+    counted = [e for e in events if e["etype"] == "pass_counters"]
+    assert [e["step"] for e in counted] == [1, 2, 3]
+    assert all(len(e["exit_p"]) == len(e["pass_ce"]) == 4 and abs(sum(e["exit_p"]) - 1.0) < 1e-5
+               and 0.0 < e["exit_entropy"] < np.log(4.0) for e in counted)
+    assert not [e for e in events if e["etype"] == "recompile"]
+
+
 def test_trainer_runs_three_steps_from_yaml_files(tmp_path, cfg):
     """``main.py``'s path: YAML files through ``load_config`` into
     ``trainer.train``; the events hold the two plans and the counters."""
@@ -72,7 +89,7 @@ def test_trainer_runs_three_steps_from_yaml_files(tmp_path, cfg):
     assert not [e for e in events if e["etype"] == "recompile"]
 
 
-@pytest.mark.parametrize("family", [QWEN3, LFM2], ids=["qwen3", "lfm2"])
+@pytest.mark.parametrize("family", [QWEN3, LFM2, OURO], ids=["qwen3", "lfm2", "ouro"])
 @pytest.mark.parametrize("parallel", ["dp", "fsdp"])
 def test_eight_devices_equal_one(opt_cfg, parallel, family):
     """The trainer on the virtual 8-device mesh (each device routes its own
