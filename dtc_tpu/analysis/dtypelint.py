@@ -78,6 +78,9 @@ ALLOWLIST: dict[str, frozenset[str]] = {
     # design (flash online softmax, zigzag-ring merge stats).
     "ops/flash_attention.py": frozenset({"*"}),
     "ops/ring_attention.py": frozenset({"*"}),
+    # The packed rotary kernel: the rotation in fp32 inside the pass, the
+    # tables built in fp64 and rounded once; q and k keep their dtype in HBM.
+    "ops/rotary.py": frozenset({"*"}),
     "ops/ulysses_attention.py": frozenset({
         "ulysses_causal_attention",
     }),

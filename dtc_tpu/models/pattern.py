@@ -59,7 +59,10 @@ Mixers:
   half-split pairing. ``gated_attn``'s query projection also yields a
   per-head output gate; ``attn`` has none. Through
   ``ops/attention.causal_attention`` (flash on the chip, KV groups picked by
-  the kernels' index maps).
+  the kernels' index maps). Where the packed flash family will run and q
+  and k come straight from their matmuls (:func:`packed_rotary_plan`), they are
+  rotated as the projections wrote them, ``(B, T, H * head_dim)``, by one
+  Mosaic pass (``ops/rotary.py``) and reach the kernel without a 4-D view.
 - ``shortconv`` — a gated short convolution: one projection to (B, C, u),
   ``out_proj(C * conv(B * u))`` with a depthwise causal convolution of
   ``shortconv_width`` taps, no bias and no activation.
@@ -87,8 +90,9 @@ convolutions' taps, the decay and the scan's carried state, the exit gate,
 
 Scopes on the device path (``benchmark/spans.py`` reads the op-name path):
 ``gdn`` with ``proj`` / ``conv`` / ``scan`` / ``out``; ``shortconv`` with
-``proj`` / ``conv`` / ``out``; ``attn_full`` with ``attn_kernel`` around the
-kernel call; ``moe`` with ``router`` / ``dispatch`` / ``experts`` /
+``proj`` / ``conv`` / ``out``; ``attn_full`` with ``attn_qkv`` (the
+projections, q / k norms and rotary), ``attn_kernel`` around the kernel call
+and ``attn_proj``; ``moe`` with ``router`` / ``dispatch`` / ``experts`` /
 ``combine`` / ``shared``; ``mlp``; ``post_norm`` (a sandwich layer's two
 outer norms); ``head`` (every pass's norm, head and CE); ``exit`` (the gate
 under ``head``, and the exit distribution and the loss after the last pass).
@@ -108,6 +112,7 @@ from dtc_tpu.models.gpt import _dtype
 from dtc_tpu.ops import moe_dispatch as md
 from dtc_tpu.ops.attention import causal_attention
 from dtc_tpu.ops.gated_delta import gated_delta_chunked, supports_chunk_kernel
+from dtc_tpu.ops.rotary import packed_rotary, supports_packed_rotary
 
 #: The per-step counters a pattern model sows (collection ``counters``),
 #: one row an expert layer; the train step returns them beside the loss.
@@ -176,6 +181,27 @@ def rotary(x: jax.Array, theta: float, fraction: float) -> jax.Array:
     return jnp.concatenate([xr, rest], -1)
 
 
+def packed_rotary_plan(cfg: ModelConfig, t: int, gated: bool) -> dict | None:
+    """The kernel's plan where an attention layer's q and k go from their
+    projections to the flash kernel as ``(B, T, H * head_dim)``, rotated there
+    by ``ops/rotary.packed_rotary``; None where :func:`rotary` runs on the
+    ``(B, T, H, head_dim)`` view. Packed takes all of: the attention resolves
+    to flash without KV groups (a head of one lane tile, which the kernel
+    asks for, is a lane group of the packed family), q and k come straight
+    from their own matmuls in the compute dtype (no q / k norm, no output
+    gate cut from the query projection), and the kernel holds the shape
+    (:func:`~dtc_tpu.ops.rotary.supports_packed_rotary`)."""
+    from dtc_tpu.config.schema import DTYPE_BYTES
+    from dtc_tpu.ops.attention import resolve_impl
+
+    hd, h = cfg.head_dim, cfg.n_heads
+    if (gated or cfg.qk_norm or cfg.kv_heads != h
+            or resolve_impl(cfg.attention, t, hd, cfg.attention_block_q,
+                            cfg.attention_block_kv) != "flash"):
+        return None
+    return supports_packed_rotary(hd, cfg.rope_fraction, h, t, DTYPE_BYTES[cfg.compute_dtype])
+
+
 class Attention(nn.Module):
     """``gated``: the query projection is twice as wide and its second half
     gates the heads' output through a sigmoid."""
@@ -189,19 +215,31 @@ class Attention(nn.Module):
         b, t, _ = x.shape
         h, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
         cdtype = _dtype(cfg.compute_dtype)
+        packed = packed_rotary_plan(cfg, t, self.gated) is not None
         with jax.named_scope("attn_qkv"):
+            def heads(a):
+                return a.reshape(b, t, -1, hd)
+
+            # packed: q and k stay as the projections wrote them until they are
+            # rotated; the views taken then and the flash entry's own reshape cancel
+            view = (lambda a: a) if packed else heads
             if self.gated:
                 qg = _dense(h * 2 * hd, "q_proj", cfg)(x).reshape(b, t, h, 2 * hd)
                 q, gate = qg[..., :hd], qg[..., hd:]
             else:
-                q = _dense(h * hd, "q_proj", cfg)(x).reshape(b, t, h, hd)
-            k = _dense(hk * hd, "k_proj", cfg)(x).reshape(b, t, hk, hd)
-            v = _dense(hk * hd, "v_proj", cfg)(x).reshape(b, t, hk, hd)
-            q_norm, k_norm = ((_norm(cfg, "q_norm"), _norm(cfg, "k_norm")) if cfg.qk_norm
-                              else (lambda a: a,) * 2)
-            q = rotary(q_norm(q), cfg.rope_theta, cfg.rope_fraction)
-            k = rotary(k_norm(k), cfg.rope_theta, cfg.rope_fraction)
-            q, k = q.astype(cdtype), k.astype(cdtype)
+                q = view(_dense(h * hd, "q_proj", cfg)(x))
+            k = view(_dense(hk * hd, "k_proj", cfg)(x))
+            v = heads(_dense(hk * hd, "v_proj", cfg)(x))
+            if packed:
+                q, k = _rows_per_data_shard(
+                    functools.partial(packed_rotary, theta=cfg.rope_theta, head_dim=hd), q, k)
+                q, k = heads(q), heads(k)
+            else:
+                q_norm, k_norm = ((_norm(cfg, "q_norm"), _norm(cfg, "k_norm")) if cfg.qk_norm
+                                  else (lambda a: a,) * 2)
+                q = rotary(q_norm(q), cfg.rope_theta, cfg.rope_fraction)
+                k = rotary(k_norm(k), cfg.rope_theta, cfg.rope_fraction)
+                q, k = q.astype(cdtype), k.astype(cdtype)
         with jax.named_scope("attn_kernel"):
             out = causal_attention(
                 q, k, v, impl=cfg.attention,
@@ -711,17 +749,24 @@ def pattern_param_count(cfg: ModelConfig) -> int:
     return layers + head + cfg.exit_gate * (d + 1)
 
 
-def _attention_plan(cfg: ModelConfig) -> dict:
+def _attention_plan(cfg: ModelConfig, gated: bool) -> dict:
     from dtc_tpu.ops.attention import resolve_impl
 
-    return {
+    tile = packed_rotary_plan(cfg, cfg.max_seq_len, gated)
+    plan = {
         "kernel": resolve_impl(cfg.attention, cfg.max_seq_len, cfg.head_dim,
                                cfg.attention_block_q, cfg.attention_block_kv),
         "heads": cfg.n_heads, "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim,
         "block_q": min(cfg.attention_block_q, cfg.max_seq_len),
         "block_kv": min(cfg.attention_block_kv, cfg.max_seq_len),
         "rotary_dims": int(cfg.head_dim * cfg.rope_fraction), "qk_norm": cfg.qk_norm,
+        # where q and k are rotated: in the projections' own (B, T, H d) layout by
+        # the Mosaic kernel, or as (B, T, H, d) by XLA
+        "rotary": "packed" if tile else "xla",
     }
+    if tile:
+        plan["rotary_tile"] = {"rows": tile["rows"], "vmem_limit_bytes": tile["vmem_limit_bytes"]}
+    return plan
 
 
 def layer_plan(cfg: ModelConfig) -> dict:
@@ -743,7 +788,7 @@ def layer_plan(cfg: ModelConfig) -> dict:
         plan["leading"] = list(cfg.leading_pattern)
     for kind in ("gated_attn", "attn"):
         if kind in mixers:
-            plan[kind] = _attention_plan(cfg)
+            plan[kind] = _attention_plan(cfg, gated=kind == "gated_attn")
     if "shortconv" in mixers:
         # the taps as shifted multiply-adds that XLA fuses with the two gates
         plan["shortconv"] = {"width": cfg.shortconv_width, "channels": cfg.d_model,

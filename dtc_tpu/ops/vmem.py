@@ -589,6 +589,44 @@ def gdn_chunk_plan(
 
 
 # ---------------------------------------------------------------------------
+# rotary positions in the packed layout (ops/rotary.py)
+# ---------------------------------------------------------------------------
+
+
+def rotary_plan(t: int, heads: int, d: int, itemsize: int = 2) -> dict[str, Any] | None:
+    """The packed rotary kernel's plan (``ops/rotary.py``) for q and k of
+    ``(B, T, heads * d)`` in ``itemsize`` bytes: the rows of a grid step —
+    the most that divide ``T`` in whole sublane tiles of the dtype and keep
+    the step's blocks, double-buffered, inside the budget (an elementwise
+    pass is paid by the bytes: the larger the block, the fewer steps) — the
+    bytes of those blocks (q and k in, q and k out, the ``(rows, d)``
+    float32 cos and sin), the float32 temporaries of the one head in flight
+    and the ``vmem_limit_bytes`` the kernel states.
+
+    None where a head is not one lane tile (the swap of a head's halves is
+    a roll of its 128 lanes by 64, which stays inside the head) or no such
+    row count divides ``T``. ``fits`` False where even the least rows are
+    over the budget."""
+    tile = SUBLANE * max(1, 4 // itemsize)        # rows of a packed sublane tile
+    if d != LANE or t % tile:
+        return None
+
+    def blocks(rows: int) -> int:
+        return 4 * rows * heads * d * itemsize + 2 * rows * d * 4
+
+    legal = [r for r in range(tile, t + 1, tile) if t % r == 0]
+    rows = max((r for r in legal if 2 * blocks(r) <= VMEM_BUDGET_BYTES), default=legal[0])
+    total = 2 * blocks(rows)                      # blocks double-buffered, no scratch
+    transient = 6 * rows * d * 4                  # x, its roll, two products, cos, sin
+    return {
+        "kernel": "rotary_packed", "t": t, "heads": heads, "head_dim": d, "rows": rows,
+        "bytes": total, "modeled_transient_bytes": transient,
+        "budget_bytes": VMEM_BUDGET_BYTES, "fits": total <= VMEM_BUDGET_BYTES,
+        "vmem_limit_bytes": total + transient + VMEM_COMPILER_ALLOWANCE_BYTES,
+    }
+
+
+# ---------------------------------------------------------------------------
 # per-layer decode kernels (ops/decode_attention.py)
 # ---------------------------------------------------------------------------
 

@@ -85,10 +85,11 @@ def _as_on_the_chip(monkeypatch):
 
     from dtc_tpu.ops import (
         attention, decode_attention, decode_fused, flash_attention, gated_delta,
-        overlap_collectives,
+        overlap_collectives, rotary,
     )
 
-    for mod in (flash_attention, decode_attention, decode_fused, overlap_collectives, gated_delta):
+    for mod in (flash_attention, decode_attention, decode_fused, overlap_collectives, gated_delta,
+                rotary):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     prev = jax.config.jax_enable_compilation_cache
@@ -149,6 +150,29 @@ def test_flash_fwd_bwd(one_chip, flagship, name):
         return causal_attention(q, k, v, impl=flagship.attention).astype(jnp.float32).sum()
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+
+
+def test_packed_rotary_fwd_bwd(one_chip):
+    """Rotary positions in the packed layout at the Ouro cell's shape (2 rows
+    x 4096, 16 heads of 128, bf16), forward and backward — the same kernel
+    with the sine's sign turned: the 128-lane roll, the per-head lane slices
+    of a ``(1, rows, H d)`` block and the stated ``vmem_limit_bytes`` are what
+    interpret mode cannot refuse."""
+    from dtc_tpu.ops.rotary import packed_rotary, supports_packed_rotary
+
+    b, t, h, d = FLASH_SHAPES["ouro_b2x4096"]
+    assert supports_packed_rotary(d, 1.0, h, t, 2)["rows"] == 256
+    x = _sds((b, t, h * d), jnp.bfloat16, one_chip)
+
+    def loss(q, k):
+        q, k = packed_rotary(q, k, 1e6, d)
+        return (q.astype(jnp.float32) * k.astype(jnp.float32)).sum()
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1)), x, x).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # in and out in the dtype: no float32 (B, T, H d) array is ever in HBM for the kernel
+    calls = [line for line in text.splitlines() if "rotary_packed" in line and "custom-call(" in line]
+    assert len(calls) == 2 and not any("f32[2,4096,2048]" in line for line in calls)
 
 
 #: Attention shapes with KV groups (B, T, H, KV heads, D, block) of the
@@ -286,6 +310,38 @@ def test_gdn_layer_on_a_four_chip_mesh(topo):
     with mesh, nn.logical_axis_rules(DEFAULT_RULES):
         text = _compile(jax.grad(loss, argnums=(0, 1)), params, x).as_text()
     assert "gdn_chunks_fwd_res" in text and "gdn_chunks_bwd" in text
+    assert "all-gather" not in text  # each chip's rows stay home
+
+
+def test_packed_rotary_layer_on_a_four_chip_mesh(topo):
+    """The Ouro cell's attention layer with its rows over data=4: the rotary
+    kernel, like the flash kernels after it, must sit in a manual region, each
+    chip on its own rows (a bare ``pallas_call`` is refused on a mesh)."""
+    import json
+
+    from flax import linen as nn
+
+    from dtc_tpu.config.schema import ModelConfig
+    from dtc_tpu.models.pattern import Attention, packed_rotary_plan
+    from dtc_tpu.parallel.mesh import build_mesh
+    from dtc_tpu.parallel.sharding import DEFAULT_RULES
+
+    with open(os.path.join(REPO, "benchmark", "configs", "ouro-2.6b.json")) as f:
+        cfg = replace(ModelConfig(**json.load(f)["model"]), max_seq_len=1024)
+    assert packed_rotary_plan(cfg, cfg.max_seq_len, gated=False) is not None
+    mesh = build_mesh((1, 4, 1), devices=list(topo.devices))
+    layer = Attention(cfg, gated=False)
+    x = _sds((4, cfg.max_seq_len, cfg.d_model), jnp.bfloat16, NamedSharding(mesh, P("data")))
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, NamedSharding(mesh, P())),
+        jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.ones((1, *x.shape[1:]), x.dtype))))
+
+    def loss(p, x):
+        return layer.apply(p, x).astype(jnp.float32).sum()
+
+    with mesh, nn.logical_axis_rules(DEFAULT_RULES):
+        text = _compile(jax.grad(loss, argnums=(0, 1)), params, x).as_text()
+    assert text.count("rotary_packed") >= 2
     assert "all-gather" not in text  # each chip's rows stay home
 
 
@@ -443,10 +499,15 @@ PATTERN_CELLS = {
         "qwen3-next-80b-a3b", 5, ("gdn_chunks_fwd", "gdn_chunks_fwd_res", "gdn_chunks_bwd")),
     # flash forward, dq and dk/dv on the transposed layout: 32 query heads on 8 KV heads of 64
     "lfm2-8b-a1b.train-ep4share-8k": ("lfm2-8b-a1b", 3, ()),
-    # the packed flash kernels at 16 heads of 128 (forward, and the fused backward), in the
-    # ONE copy of the stack that the scan over the four passes holds
-    "ouro-2.6b.train-loop4-b2x4096": ("ouro-2.6b", 2, ()),
+    # the packed flash kernels at 16 heads of 128 (forward, and the fused backward) and the
+    # packed rotary kernel before them, in the ONE copy of the stack that the scan over the
+    # four passes holds
+    "ouro-2.6b.train-loop4-b2x4096": ("ouro-2.6b", 2, ("rotary_packed",)),
 }
+
+#: The Ouro cell's compiled peak on the parent of the PR that packed its rotary (PR 39):
+#: the kernel keeps nothing for its backward, so the step may not ask for more.
+OURO_PEAK_BEFORE_PACKED_ROTARY = 14_489_447_424
 
 
 @pytest.mark.parametrize("cell", list(PATTERN_CELLS))
@@ -505,11 +566,38 @@ def test_pattern_cell_train_step_fits_one_chip(topo, cell):
     assert 0 < peak < V5E_HBM_BYTES
     if cfg.stack_passes > 1:
         # one copy of the stack: the flash forward once in the primal and once in a
-        # layer's recomputation, one fused backward — not that times the passes
-        assert text.count("tpu_custom_call") <= 6
+        # layer's recomputation, one fused backward — not that times the passes — and the
+        # rotary kernel before (in the backward: after) each of the three
+        assert text.count('custom_call_target="tpu_custom_call"') == 6
+        assert peak <= OURO_PEAK_BEFORE_PACKED_ROTARY
+        _q_and_k_stay_packed(text, rows, cfg)
     plan = moe_plan(cfg, rows * cfg.max_seq_len)
     if plan is not None:
         _expert_loop_adds_in_place(text, rows * cfg.max_seq_len, plan["staged_rows"], cfg.d_model)
+
+
+def _q_and_k_stay_packed(text: str, rows: int, cfg):
+    """What interpret mode cannot say of the packed rotary (``ops/rotary.py``):
+    in the optimized step the kernel's custom call stands three times (a
+    layer's forward, its recomputation, its backward), each under
+    ``attn_full/attn_qkv`` and not under ``attn_kernel`` (whose time the flash
+    roofline divides by), and between a q / k projection's matmul and the
+    flash call nothing re-lays q or k: no 4-D ``(B, T, H, d)`` array exists
+    anywhere in the step, and no ``copy`` / ``transpose`` / ``reshape`` of a
+    ``(B, T, H d)`` array stands under ``attn_full`` (a bitcast is free and is
+    printed as ``bitcast``)."""
+    import re
+
+    t, h, d = cfg.max_seq_len, cfg.n_heads, cfg.head_dim
+    calls = [line for line in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = .* custom-call\(", line)
+             and "rotary_packed" in line]
+    assert len(calls) == 3, len(calls)
+    assert all("/attn_full/attn_qkv/rotary_packed" in line and "attn_kernel" not in line for line in calls)
+    assert f"[{rows},{t},{h},{d}]" not in text
+    attn = [line.strip() for line in text.splitlines() if "/attn_full/" in line]
+    moved = [line for line in attn
+             if re.match(rf"(ROOT )?%\S+ = \S+\[{rows},{t},{h * d}\]\S* (copy|copy-start|transpose|reshape)\(", line)]
+    assert not moved, moved[:3]
 
 
 def _expert_loop_adds_in_place(text: str, tokens: int, staged_rows: int, d: int):
