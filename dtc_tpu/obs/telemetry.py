@@ -7,7 +7,7 @@ generate) talks to it through a small hook interface::
     tele.on_run_start(...)
     tele.on_step_start(step)
     with tele.clock.phase("data_wait"): ...      # and dispatch > rng, launch; block
-    tele.on_step_end(step, synced=...)
+    tele.on_step_end(step, elapsed_s=...)
     tele.on_eval(step, loss, duration_s)
     tele.on_run_end(...); tele.close()
 
@@ -20,13 +20,43 @@ Event stream schema (JSONL, one shard per process — see README
 - ``run_start``    — config fingerprint: strategy, mesh, batch, devices;
 - ``compile``      — first XLA backend-compile window (init + warmup),
                      labeled step 0;
+- ``startup``      — once, when the timed loop begins: ``t_enter`` (the
+                     ``perf_counter`` stamp of ``train()``'s first line),
+                     ``phases`` (``{name: [start after t_enter, seconds]}``
+                     of the ``train.startup.*`` phases: distributed, mesh,
+                     model, state, restore, step_build, data, obs,
+                     eval_setup, warmup_first, warmup_rest), ``named_s`` /
+                     ``total_s`` (entry to the first timed step's begin),
+                     and what ``jax.monitoring`` counted meanwhile:
+                     ``trace_s``, ``lower_s``, ``backend_compile_s`` (which
+                     holds ``cache_retrieval_s``), ``compiles``,
+                     ``cache_hits``, ``cache_misses`` (programs compiled
+                     anew: requests less hits), ``cache_writes`` (those of
+                     them the persistent cache kept: a step program here on
+                     a warm run says a key moved), ``compiled`` (``{program:
+                     [count, seconds]}`` of the programs compiled anew);
 - ``recompile``    — any later compile: something changed shape mid-run;
 - ``step``         — per-step breakdown: ``data_wait_s``, ``dispatch_s``
                      (of which ``rng_s`` the eager key fold and
                      ``launch_s`` the step call), ``block_s``, ``other_s``,
                      ``step_time_s``, ``between_s`` (the loop's time
                      between the step before's end and this one's begin),
-                     cumulative ``elapsed_s``;
+                     cumulative ``elapsed_s``; under ``obs.enabled`` also
+                     what the host was doing over the step's period
+                     (``between_s + step_time_s``): ``cpu_s``, ``gc_s``,
+                     ``gc_n`` (collections by generation), ``host_late_s``
+                     (the canary's largest lateness: ``obs/stepclock.py``);
+- ``slow_step``    — a step whose period passed 1.1 x the trailing median of
+                     the up to 64 before it: ``period_s``, ``median_s``,
+                     ``excess_s``, every phase's seconds and ``*_excess_s``
+                     over its own trailing median (data_wait, rng, launch,
+                     block, other, between), ``held_by``, ``cpu_s``,
+                     ``gc_s``, ``host_late_s``, ``owner`` (``host_frozen`` /
+                     ``gc`` / ``device_or_driver`` / ``host_phase``) and
+                     ``after`` (the boundary work the loop did since the
+                     step before: log_boundary, eval, checkpoint); the lead
+                     prints one line, the tracer gets a ``slow_step`` span
+                     of the excess on the ``train.phase`` track;
 - ``train_row``    — the CSV-schema row (step, elapsed_time, loss), also
                      bridged to ``log.csv`` by the CSV sink;
 - ``window``       — log-boundary throughput: avg step time, tokens/s, MFU;
@@ -43,7 +73,9 @@ Event stream schema (JSONL, one shard per process — see README
                      of the trailing median step time (``runtime: serve``
                      when the serving scheduler's watchdog flagged it);
 - ``run_summary``  — totals: tokens/s, MFU, peak HBM, compile/recompile
-                     counts, est. comm bytes per step;
+                     counts, est. comm bytes per step, the ``startup``
+                     event's totals (``startup``), ``slow_steps`` and
+                     ``slow_step_excess_s`` by owner;
 - ``counter``      — online goodput gauge sample (``name``: goodput_pct,
                      ``value``) — the Perfetto counter track (ISSUE 16);
                      the offline truth is the goodput ledger
@@ -87,7 +119,7 @@ from dtc_tpu.obs.profiling import StepWindowProfiler
 from dtc_tpu.obs.goodput import OnlineGoodput
 from dtc_tpu.obs.registry import CsvSink, JsonlSink, MetricsRegistry
 from dtc_tpu.obs.slo import SloMonitor
-from dtc_tpu.obs.stepclock import CompileWatcher, StepClock
+from dtc_tpu.obs.stepclock import CompileWatcher, SlowSteps, StepClock
 from dtc_tpu.obs.trace import FlightRecorder, Tracer
 
 
@@ -102,6 +134,8 @@ class Telemetry:
         profiler: StepWindowProfiler | None = None,
         append: bool = False,
         slo_cfg: Any = None,
+        clock: StepClock | None = None,
+        compiles: CompileWatcher | None = None,
     ):
         from dtc_tpu.config.schema import ObsConfig
 
@@ -109,8 +143,15 @@ class Telemetry:
         self.output_dir = output_dir
         self.lead = lead
         self.registry = MetricsRegistry(process_index=process_index)
-        self.clock = StepClock()
-        self.compiles = CompileWatcher()
+        # The trainer makes both at the first line of train(), long before
+        # a sink may open: the start-up's phases and compiles are kept in
+        # memory and written as the `startup` event once the loop begins.
+        self.clock = clock if clock is not None else StepClock()
+        self.compiles = compiles if compiles is not None else CompileWatcher()
+        self.slow = SlowSteps()
+        self._slow_excess: dict[str, float] = {}
+        self._since_last_step: list[str] = []   # boundary work, for `after`
+        self._startup: dict[str, Any] | None = None
         self.profiler = profiler or StepWindowProfiler(0, 0, "")
         self.obs_dir = ""
         # False until the first timed step completes: compile seconds
@@ -189,7 +230,8 @@ class Telemetry:
     # -- construction -----------------------------------------------------
     @classmethod
     def for_training(
-        cls, train_cfg, *, lead: bool, process_index: int, resumed: bool = False
+        cls, train_cfg, *, lead: bool, process_index: int, resumed: bool = False,
+        clock: StepClock | None = None, compiles: CompileWatcher | None = None,
     ) -> "Telemetry":
         """Build the trainer's telemetry from its config block.
 
@@ -217,6 +259,8 @@ class Telemetry:
             profiler=profiler,
             append=resumed,
             slo_cfg=getattr(train_cfg, "slo", None),
+            clock=clock,
+            compiles=compiles,
         )
 
     @classmethod
@@ -254,21 +298,26 @@ class Telemetry:
                 # One jax profiler session per process: defer devprof windows
                 # while the legacy configured window is mid-capture.
                 self.devprof.on_step(step, busy=self.profiler._active)
+        if self._startup is None:
+            # The timed loop begins: the start-up's one event, and from here
+            # the canary and the collector's hook (never a second time).
+            self._emit_startup(loop_began=True)
+            if self.cfg.enabled:
+                self.clock.watch_host()
         self.clock.begin(step)
 
-    def on_step_end(self, step: int, *, elapsed_s: float, synced: bool) -> dict:
+    def on_step_end(self, step: int, *, elapsed_s: float) -> dict:
         """Close the step's clock, fold in any compile the step triggered,
         emit the ``step`` event, and sample memory on cadence. Everything
         after the clock has closed is the ``obs`` phase; the rest of the
         loop body, up to the next ``on_step_start``, is ``tail``."""
         breakdown = self.clock.end()
         with self.clock.phase("obs"):
-            self._after_step(step, breakdown, elapsed_s, synced)
+            self._after_step(step, breakdown, elapsed_s)
         self.clock.tail()
         return breakdown
 
-    def _after_step(self, step: int, breakdown: dict, elapsed_s: float,
-                    synced: bool) -> None:
+    def _after_step(self, step: int, breakdown: dict, elapsed_s: float) -> None:
         self.registry.histogram("step_time_s").observe(breakdown["step_time_s"])
         compile_s, n = self.compiles.drain()
         extra: dict[str, Any] = {}
@@ -292,10 +341,13 @@ class Telemetry:
             "step",
             step=step,
             elapsed_s=round(elapsed_s, 6),
-            synced=synced,
             **breakdown,
             **extra,
         )
+        slow = self.slow.observe(breakdown)
+        if slow is not None:
+            self._on_slow_step(step, slow, breakdown)
+        self._since_last_step.clear()
         # Step/phase spans from the clock's own start stamps (no extra
         # clock read, no sync), moved onto the tracer's clock by the one
         # offset taken at construction.
@@ -314,15 +366,17 @@ class Telemetry:
                         ph, p0, p0 + d, cat="train",
                         tid="train.phase", step=step,
                     )
-            # Only a STEADY-state recompile gets its span here; the
-            # warmup-less first step's cold compile went through
-            # _note_startup_compile above, which already emitted the
-            # startup compile span — emitting both would double-count
-            # compile seconds in the attribution table.
-            if extra.get("recompile"):
+            if n:
+                # A step that compiled did so inside its launch: the span
+                # starts where that phase did. (What compiled BEFORE the
+                # loop has its spans from `_emit_startup`, each program's
+                # own stamps; the two never cover the same seconds.)
+                c0 = self.clock.starts.get("launch", self.clock.t0) + off
+                recompile = bool(extra.get("recompile"))
                 self.tracer.emit_span(
-                    "compile", t1 - compile_s, t1, cat="train",
-                    tid="train.compile", step=step, recompile=True,
+                    "compile", c0, c0 + compile_s, cat="train",
+                    tid="train.compile", step=step if recompile else 0,
+                    recompile=recompile,
                 )
         if self.goodput is not None:
             # Per-class attribution from numbers the clock already
@@ -365,7 +419,10 @@ class Telemetry:
     def record_aux_compile(self, step: int, what: str) -> None:
         """Drain compile seconds attributable to auxiliary host-side
         computations (the log-boundary loss stack, the eval step) so they
-        are NOT misflagged as train-step recompiles at the next step."""
+        are NOT misflagged as train-step recompiles at the next step. The
+        loop calls this after each piece of boundary work, so ``what`` is
+        also what a ``slow_step`` event's ``after`` names."""
+        self._since_last_step.append(what)
         compile_s, n = self.compiles.drain()
         if not n:
             return
@@ -385,21 +442,101 @@ class Telemetry:
 
     def _note_startup_compile(self, compile_s: float, n: int) -> None:
         """Accumulating, not last-writer-wins: warmup's compile and a
-        warmup-less first step's compile are both startup cost."""
+        warmup-less first step's compile are both startup cost. The spans
+        are not made here: `_emit_startup` places what compiled before the
+        loop by each program's own stamps, `_after_step` a first step's."""
         g = self.registry.gauge("compile_time_s")
         total = round((g.value or 0.0) + compile_s, 4)
         g.set(total)
         self.registry.emit(
             "compile", step=0, compile_time_s=round(compile_s, 4), count=n
         )
-        if self.tracer.enabled:
-            # Timeline placement is approximate (the compile seconds
-            # accumulated over init/warmup, ending no later than now) —
-            # the span's value is its DURATION on the startup track.
-            t1 = time.time()
+
+    def _emit_startup(self, *, loop_began: bool) -> None:
+        """The ``startup`` event, once: where ``train()``'s entry to the
+        first timed step went, by the clock's ``train.startup.*`` phases,
+        and what ``jax.monitoring`` counted meanwhile. Written when the loop
+        begins (or at ``close()`` for a run that never got there) because
+        the sinks open long after the clock starts. Nothing is emitted for
+        a runtime that names no start-up phase (serving)."""
+        clock, now = self.clock, time.perf_counter()
+        clock.freeze_startup(now)   # a no-op after the first begin()
+        phases = clock.startup_phases
+        self._startup = {}
+        if not phases:
+            return
+        t = self.compiles.totals
+        compiled: dict[str, list] = {}
+        programs = self.compiles.take_programs()
+        for _, sec, name, hit in programs:
+            if not hit:
+                c = compiled.setdefault(name, [0, 0.0])
+                c[0] += 1
+                c[1] = round(c[1] + sec, 4)
+        self._startup = {
+            "named_s": round(sum(sec for _, sec in phases.values()), 6),
+            "total_s": round(clock.startup_total_s, 6),
+            "trace_s": round(t["trace_s"], 4),
+            "lower_s": round(t["lower_s"], 4),
+            "backend_compile_s": round(t["backend_compile_s"], 4),
+            "cache_retrieval_s": round(t["cache_retrieval_s"], 4),
+            "compiles": t["compiles"],
+            "cache_hits": t["cache_hits"],
+            "cache_misses": t["compiled_anew"],
+            "cache_writes": t["cache_misses"],
+        }
+        self.registry.emit(
+            "startup", t_enter=round(clock.t_enter, 6),
+            loop_began=loop_began,
+            phases={k: [round(a, 6), round(sec, 6)] for k, (a, sec) in phases.items()},
+            compiled=compiled, **self._startup,
+        )
+        if not self.tracer.enabled:
+            return
+        # The phases on a track of their own, and on the compile track what
+        # each stretch compiled or loaded: from the first such program's
+        # start, as long as the stretch's programs took together.
+        base = clock.t_enter + self._clock_offset
+        for name, a, sec in clock.startup_segments:
             self.tracer.emit_span(
-                "compile", t1 - compile_s, t1, cat="train",
-                tid="train.compile", step=0, count=n,
+                f"startup.{name}", base + a, base + a + sec, cat="train",
+                tid="train.startup", step=0,
+            )
+            mine = [(te - clock.t_enter, d) for te, d, _, _ in programs
+                    if a < te - clock.t_enter <= a + sec]
+            if mine:
+                c0 = base + max(min(te - d for te, d in mine), a)
+                self.tracer.emit_span(
+                    "compile", c0, c0 + sum(d for _, d in mine), cat="train",
+                    tid="train.compile", step=0, count=len(mine), phase=name,
+                )
+
+    def _on_slow_step(self, step: int, slow: dict, breakdown: dict) -> None:
+        self.registry.counter("slow_steps").inc()
+        owner = slow["owner"]
+        self._slow_excess[owner] = self._slow_excess.get(owner, 0.0) + slow["excess_s"]
+        self.registry.emit(
+            "slow_step", step=step, after=list(self._since_last_step), **slow
+        )
+        held = slow["held_by"]
+        if self.lead:
+            print(
+                f"[dtc_tpu] slow step {step}: {slow['period_s']:.3g} s for "
+                f"{slow['median_s']:.3g} — {held} +{slow[held + '_excess_s']:.3g} s"
+                + (f", host late {slow['host_late_s']:.3g} s" if "host_late_s" in slow else "")
+                + (f", gc {slow['gc_s']:.3g} s" if slow.get("gc_s") else "")
+                + f": {owner}"
+            )
+        if self.tracer.enabled:
+            # The excess itself, from where the phase that held the step
+            # should have ended.
+            off, t0 = self._clock_offset, self.clock.t0
+            h0 = (t0 - breakdown["between_s"] if held == "between"
+                  else self.clock.starts.get(held, t0))
+            h0 += off + slow[f"{held}_s"] - slow[f"{held}_excess_s"]
+            self.tracer.emit_span(
+                "slow_step", h0, h0 + slow[f"{held}_excess_s"], cat="train",
+                tid="train.phase", step=step, held_by=held, owner=owner,
             )
 
     def on_window(self, step: int, *, avg_step_s: float, tokens_per_sec: float,
@@ -580,6 +717,12 @@ class Telemetry:
         self.registry.gauge("peak_hbm_bytes")
         body = dict(self.registry.snapshot())
         body.update(summary)
+        if self._startup:
+            body["startup"] = self._startup
+        body.setdefault("slow_steps", 0)
+        body["slow_step_excess_s"] = {
+            k: round(v, 6) for k, v in self._slow_excess.items()
+        }
         self.registry.emit("run_summary", **body)
         self.registry.flush()
         self._barrier()
@@ -623,8 +766,10 @@ class Telemetry:
     def close(self) -> None:
         if self._closed:
             return
+        if self._startup is None:
+            self._emit_startup(loop_began=False)   # a run that ended inside its start-up
         self._closed = True
-        self.clock.close()  # loop exit: the last pass's tail and group
+        self.clock.shutdown()  # the last pass's tail and group, the canary, the gc hook
         self.profiler.close()
         if self.devprof is not None:
             self.devprof.close()  # finalize a window the run ended inside

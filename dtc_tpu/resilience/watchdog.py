@@ -3,7 +3,9 @@
 Two layers, both off the hot path:
 
 - **flagging** (host-side, post-step): each completed step's duration is
-  compared against ``factor`` x the trailing median; outliers emit a
+  compared against ``factor`` x the trailing median
+  (``obs.stepclock.TrailingMedian``: the one outlier rule, which the
+  telemetry's always-on slow-step detector uses at 1.1 x); outliers emit a
   ``hung_step`` telemetry event and can arm a profiler window over the
   following steps so the trace shows WHAT was slow (``profile_on_flag``).
 - **hard timeout** (background thread, opt-in via ``hard_timeout_s > 0``):
@@ -20,8 +22,9 @@ import faulthandler
 import os
 import threading
 import time
-from collections import deque
 from typing import Any, Callable
+
+from dtc_tpu.obs.stepclock import TrailingMedian
 
 
 class StepWatchdog:
@@ -34,7 +37,7 @@ class StepWatchdog:
         clock: Callable[[], float] = time.monotonic,
     ):
         self.cfg = cfg
-        self._durations: deque[float] = deque(maxlen=64)
+        self._history = TrailingMedian(cfg.factor, cfg.min_samples)
         self._clock = clock
         self.timed_out = False
         self.flags = 0
@@ -58,33 +61,22 @@ class StepWatchdog:
         self._escalate = escalate
 
     # -- flagging ----------------------------------------------------------
-    def trailing_median(self) -> float | None:
-        if len(self._durations) < max(int(self.cfg.min_samples), 1):
-            return None
-        vals = sorted(self._durations)
-        return vals[len(vals) // 2]
-
     def observe(self, step: int, duration_s: float) -> dict | None:
         """Record a completed step; return flag details when it was a
         ``factor``-x outlier vs the trailing median (else None). The outlier
         itself is NOT added to the history — one hang must not license the
         next."""
         self.disarm()
-        med = self.trailing_median()
-        if (
-            med is not None
-            and med > 0
-            and duration_s > self.cfg.factor * med
-        ):
-            self.flags += 1
-            return {
-                "step": step,
-                "duration_s": round(duration_s, 4),
-                "median_s": round(med, 4),
-                "factor": round(duration_s / med, 2),
-            }
-        self._durations.append(duration_s)
-        return None
+        med = self._history.observe(duration_s)
+        if med is None:
+            return None
+        self.flags += 1
+        return {
+            "step": step,
+            "duration_s": round(duration_s, 4),
+            "median_s": round(med, 4),
+            "factor": round(duration_s / med, 2),
+        }
 
     # -- hard timeout ------------------------------------------------------
     def start(self) -> None:

@@ -52,7 +52,7 @@ from dtc_tpu.train.train_step import (
     resolve_collectives,
     resolve_precision,
 )
-from dtc_tpu.obs import Telemetry
+from dtc_tpu.obs import CompileWatcher, StepClock, Telemetry
 from dtc_tpu.utils.dist import is_lead_process, maybe_initialize_distributed
 from dtc_tpu.utils.metrics import comm_bytes_per_step, mfu
 
@@ -326,11 +326,26 @@ def train(
     host_iterator: Iterator[np.ndarray] | None = None,
     rules=DEFAULT_RULES,
 ) -> TrainResult:
-    if not train_cfg.debug_nans:
-        return _train(
-            train_cfg, model_cfg, opt_cfg,
-            host_iterator=host_iterator, rules=rules,
+    # The run's one clock and compile watcher, from the first line: every
+    # second up to the first timed step lies under a `train.startup.*` phase
+    # (obs/stepclock.py). The Telemetry built further down, once a sink may
+    # open, takes both over and writes them as the `startup` event.
+    clock = StepClock()
+    compiles = CompileWatcher().activate()
+    try:
+        return _train_in_mode(
+            train_cfg, model_cfg, opt_cfg, host_iterator=host_iterator,
+            rules=rules, clock=clock, compiles=compiles,
         )
+    finally:
+        # Telemetry.close() does both; this covers a raise before it exists.
+        compiles.deactivate()
+        clock.shutdown()
+
+
+def _train_in_mode(train_cfg, model_cfg, opt_cfg, **kw) -> TrainResult:
+    if not train_cfg.debug_nans:
+        return _train(train_cfg, model_cfg, opt_cfg, **kw)
     # SURVEY §5 sanitizer row: the TPU-native analog of the reference
     # stack's device-side assert tooling. XLA re-runs any jitted
     # computation whose output contains NaN un-jitted and raises
@@ -340,10 +355,7 @@ def train(
     prev = jax.config.jax_debug_nans
     jax.config.update("jax_debug_nans", True)
     try:
-        return _train(
-            train_cfg, model_cfg, opt_cfg,
-            host_iterator=host_iterator, rules=rules,
-        )
+        return _train(train_cfg, model_cfg, opt_cfg, **kw)
     finally:
         jax.config.update("jax_debug_nans", prev)
 
@@ -355,11 +367,15 @@ def _train(
     *,
     host_iterator: Iterator[np.ndarray] | None = None,
     rules=DEFAULT_RULES,
+    clock: StepClock,
+    compiles: CompileWatcher,
 ) -> TrainResult:
+    clock.startup("distributed")
     maybe_initialize_distributed(
         train_cfg.multihost, train_cfg.coordinator_timeout_s
     )
     num_devices = jax.device_count()
+    clock.startup("mesh")
     mesh = mesh_from_config(
         train_cfg.parallel, train_cfg.mesh, n_layers=model_cfg.n_layers
     )
@@ -461,6 +477,7 @@ def _train(
     # master-weight wrapper, so the pair can never half-apply.
     model_cfg = resolve_precision(opt_cfg, model_cfg)
 
+    clock.startup("model")
     model = build_model(model_cfg)
     # LoRA finetune mode (dtc_tpu/adapters/): the TrainState is the
     # adapter subtree, the base is a frozen step input. One flag here —
@@ -531,6 +548,8 @@ def _train(
                     "activations); every matmul keeps the serialized "
                     "XLA path"
                 )
+        # The init program's trace, its compile or load, and its run.
+        clock.startup("state")
         base_params = None
         if lora_on:
             state, base_params = init_adapter_state(
@@ -544,6 +563,7 @@ def _train(
         # catastrophic tier: the in-memory snapshots are the hot recovery
         # path, so ``elastic.cold_every`` (when set) slows the Orbax
         # cadence without touching the TrainConfig knob.
+        clock.startup("restore")
         checkpoint_every_eff = train_cfg.checkpoint_every
         if el_on and el_cfg.cold_every > 0 and train_cfg.checkpoint_every > 0:
             checkpoint_every_eff = el_cfg.cold_every
@@ -589,6 +609,7 @@ def _train(
                         "deliberately start fresh"
                     ) from e
 
+        clock.startup("step_build")
         # Anomaly guard: rollback needs a checkpoint manager AND a stream
         # the trainer can rebuild (a caller-provided host_iterator cannot
         # be re-positioned).
@@ -615,6 +636,7 @@ def _train(
         # against the restored state would advance it past the checkpointed
         # step). FineWeb SEEKS via the checkpointed stream position when the
         # sidecar exists (drain loop only as pre-sidecar fallback).
+        clock.startup("data")
         from dtc_tpu.data.holdout import (
             divert_holdout, diverted_indices, stream_index_for,
         )
@@ -776,14 +798,15 @@ def _train(
                 "checkpointing so the run resumes instead (guards committed "
                 "comparison artifacts against stray smoke runs)"
             )
-        # Telemetry AFTER the clobber guard (a refused run writes nothing)
-        # but BEFORE warmup, so the compile watcher sees the train step's
-        # XLA compile. All emission — JSONL events, the back-compat
+        # Telemetry AFTER the clobber guard (a refused run writes nothing):
+        # its sinks open here, its clock and compile watcher have run since
+        # train()'s first line. All emission — JSONL events, the back-compat
         # log.csv / eval_log.csv bridges, profiler windows — funnels
         # through this one object via the hook interface.
+        clock.startup("obs")
         tele = Telemetry.for_training(
             train_cfg, lead=lead, process_index=jax.process_index(),
-            resumed=start_step > 0,
+            resumed=start_step > 0, clock=clock, compiles=compiles,
         )
         # Device-profile context (ISSUE 8): capture metas carry the step's
         # model FLOPs, the chip peak, and the static collective-census
@@ -850,6 +873,7 @@ def _train(
             sync_every_step = bool(train_cfg.output_dir)
 
         # ------ periodic held-out eval ------
+        clock.startup("eval_setup")
         eval_fn = None
         if train_cfg.eval_every > 0:
             try:
@@ -1213,17 +1237,29 @@ def _train(
             warmup_steps = 0 if start_step > 0 else train_cfg.warmup_steps
             if lead and warmup_steps:
                 print("Warmup")
+            clock.startup("warmup_rest")  # the eager fold_in below compiles
             warm_key = jax.random.fold_in(key, 2**31 - 1)  # stream disjoint from steps
-            for i in range(warmup_steps):
+            if warmup_steps:
+                # The first batch is the feed starting up (`data`); the first
+                # step from its call to its loss fetched — trace, lower,
+                # compile or cache load, first execution — is `warmup_first`.
+                clock.startup("data")
                 x, y = next(data_it)
+                clock.startup("warmup_first")
                 # a pattern model's step also returns its counters
+                state, loss, *_ = train_step(state, Batch(x=x, y=y), jax.random.fold_in(warm_key, 0))
+                jax.device_get(loss)
+                clock.startup("warmup_rest")
+            for i in range(1, warmup_steps):
+                x, y = next(data_it)
                 state, loss, *_ = train_step(state, Batch(x=x, y=y), jax.random.fold_in(warm_key, i))
             delivered += warmup_steps
-            if warmup_steps:
+            if warmup_steps > 1:
                 # Sync via value fetch.
                 jax.device_get(loss)
 
             if start_step > 0:
+                clock.startup("warmup_first")
                 # Warmup is skipped on resume, so the first timed step would pay
                 # the full XLA compile and corrupt the first log window's
                 # timings. Compile now by running the step once on a throwaway
@@ -1242,6 +1278,7 @@ def _train(
                 )
                 jax.device_get(compile_loss)
 
+            clock.startup("warmup_rest")
             # Everything compiled so far (warmup / resume pre-compile) is
             # the run's startup compile — emitted as the step-0 `compile`
             # event. With warmup_steps=0 the first timed step pays it and
@@ -1316,9 +1353,7 @@ def _train(
                 now = time.perf_counter()
                 result.elapsed_times.append(now - start_time)
                 pending_rows.append((step, now - start_time))
-                breakdown = tele.on_step_end(
-                    step, elapsed_s=now - start_time, synced=bool(sync_every_step)
-                )
+                breakdown = tele.on_step_end(step, elapsed_s=now - start_time)
                 stalled_flag = False
                 if wd is not None:
                     flag = wd.observe(step, breakdown["step_time_s"])

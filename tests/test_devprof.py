@@ -384,7 +384,7 @@ class TestCaptureWindows:
             tele.devprof.request = lambda reason: calls.append(reason) or True
             for s in range(1, 5):
                 tele.on_step_start(s)
-                tele.on_step_end(s, elapsed_s=0.0, synced=True)
+                tele.on_step_end(s, elapsed_s=0.0)
         finally:
             tele.close()
         assert calls == ["slo_breach:step_time_p99_s"]

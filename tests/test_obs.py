@@ -106,11 +106,11 @@ def test_first_timed_step_compile_is_startup_not_recompile(tmp_path):
         tele.record_startup_compile()
         tele.on_step_start(1)
         jax.jit(lambda v: v * 2 + tmp_path.stat().st_mode)(jnp.ones(3)).block_until_ready()
-        tele.on_step_end(1, elapsed_s=0.1, synced=True)
+        tele.on_step_end(1, elapsed_s=0.1)
         # Steady state reached: the NEXT fresh compile is a real recompile.
         tele.on_step_start(2)
         jax.jit(lambda v: v * 3 - 1)(jnp.ones((2, 2))).block_until_ready()
-        tele.on_step_end(2, elapsed_s=0.2, synced=True)
+        tele.on_step_end(2, elapsed_s=0.2)
         tele.flush()
     finally:
         tele.close()
@@ -394,3 +394,254 @@ def test_main_two_step_run_emits_telemetry(tmp_path):
 
     rows = (out / "log.csv").read_text().strip().splitlines()
     assert rows[0] == "step,elapsed_time,loss" and len(rows) == 3
+
+
+# ---- the trainer's clock from the first line of train() (ISSUE 40) ---------
+
+STARTUP_STATE = ("distributed", "mesh", "model", "state", "restore")
+STARTUP_FIRST_STEP = ("step_build", "warmup_first")
+STARTUP_REST = ("data", "obs", "eval_setup", "warmup_rest")
+
+
+def _events_of(tmp_path, etype):
+    events = read_jsonl(str(tmp_path / "obs" / "events.r0.jsonl"))
+    return [e for e in events if e["etype"] == etype]
+
+
+def _slow_feed(train_cfg, model_cfg, slow_at: int, sleep_s: float):
+    """The trainer's own synthetic feed, asleep once: before its
+    ``slow_at``-th batch (warm-up batches count)."""
+    import time
+
+    from dtc_tpu.train.trainer import make_host_iterator
+
+    for i, batch in enumerate(make_host_iterator(train_cfg, model_cfg), 1):
+        if i == slow_at:
+            time.sleep(sleep_s)
+        yield batch
+
+
+def test_startup_event_names_the_whole_start_up(tiny_model_cfg, opt_cfg, tmp_path):
+    """One `startup` event when the timed loop begins: its phases cover at
+    least 95 % of train()'s entry to the first timed step, the three groups
+    the benchmark reads add up to what they name, and `run_summary` carries
+    the same totals."""
+    import jax
+
+    from dtc_tpu.train.trainer import train
+
+    cfg = make_train_cfg("dp", steps=2, log_every=2, output_dir=str(tmp_path),
+                         warmup_steps=3)
+    jax.clear_caches()   # what earlier tests of this process traced and compiled
+    train(cfg, tiny_model_cfg, opt_cfg)
+    (ev,) = _events_of(tmp_path, "startup")
+    assert ev["loop_began"] is True
+    phases = ev["phases"]
+    assert set(phases) == set(STARTUP_STATE + STARTUP_FIRST_STEP + STARTUP_REST)
+    starts = [phases[k][0] for k in STARTUP_STATE + ("step_build", "data", "obs", "eval_setup")]
+    assert starts == sorted(starts) and starts[0] < 0.01
+    groups = [sum(phases[k][1] for k in g)
+              for g in (STARTUP_STATE, STARTUP_FIRST_STEP, STARTUP_REST)]
+    assert sum(groups) == pytest.approx(ev["named_s"], abs=1e-4)
+    assert 0.95 * ev["total_s"] <= ev["named_s"] <= ev["total_s"] + 1e-6
+    # the first step is traced, lowered and compiled inside `warmup_first`
+    assert phases["warmup_first"][1] > phases["warmup_rest"][1]
+    assert ev["trace_s"] > 0 and ev["lower_s"] > 0 and ev["backend_compile_s"] > 0
+    # compiled anew, or loaded where an earlier test left a persistent cache on
+    assert ev["compiles"] > 0 and ev["cache_misses"] == ev["compiles"] - ev["cache_hits"]
+    assert sum(n for n, _ in ev["compiled"].values()) == ev["cache_misses"]
+    assert "jit(train_step)" in ev["compiled"] or ev["cache_hits"] > 0
+    (compile_ev,) = _events_of(tmp_path, "compile")
+    assert compile_ev["compile_time_s"] == pytest.approx(ev["backend_compile_s"], abs=1e-3)
+    summary = _events_of(tmp_path, "run_summary")[-1]
+    assert summary["startup"]["total_s"] == ev["total_s"]
+    assert summary["startup"]["cache_misses"] == ev["cache_misses"]
+    # the phases are spans on a track of their own, and what they compiled
+    # lies inside them (placed by the programs' own stamps)
+    spans = _events_of(tmp_path, "span")
+    stretches = [s for s in spans if s["tid"] == "train.startup"]
+    assert {s["name"] for s in stretches} == {f"startup.{k}" for k in phases}
+    compiled = [s for s in spans if s["name"] == "compile" and s["step"] == 0]
+    assert sum(s["dur_s"] for s in compiled) == pytest.approx(ev["backend_compile_s"], abs=1e-3)
+    for s in compiled:   # a name opened twice (`data`, `warmup_rest`) has two stretches
+        assert any(ph["name"] == f"startup.{s['phase']}"
+                   and ph["t0"] - 1e-3 <= s["t0"] <= ph["t0"] + ph["dur_s"]
+                   for ph in stretches), s
+
+
+def test_startup_event_reads_the_persistent_cache(tiny_model_cfg, opt_cfg, tmp_path):
+    """Two runs in one process with the compile cache in a temporary
+    directory and the caching threshold at 0: the first traces and compiles
+    everything anew and writes it, the second loads it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from dtc_tpu.train.trainer import train
+
+    keys = ("jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes", "jax_enable_compilation_cache")
+    before = {k: getattr(jax.config, k) for k in keys}
+    events = []
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        cc.reset_cache()
+        for run in ("first", "second"):
+            jax.clear_caches()   # in-process executables: the disk has to serve
+            out = tmp_path / run
+            cfg = make_train_cfg("dp", steps=1, log_every=1, output_dir=str(out),
+                                 warmup_steps=1)
+            train(cfg, tiny_model_cfg, opt_cfg)
+            (ev,) = _events_of(out, "startup")
+            events.append(ev)
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+        jax.clear_caches()
+    first, second = events
+    assert first["trace_s"] > 0
+    assert first["cache_writes"] > 0 and first["cache_misses"] >= first["cache_writes"]
+    assert second["cache_hits"] > 0 and second["cache_retrieval_s"] > 0
+    assert second["cache_misses"] < first["cache_misses"]
+    assert second["backend_compile_s"] >= second["cache_retrieval_s"]
+
+
+def test_sleeping_feed_is_one_slow_step_held_by_data_wait(tiny_model_cfg, opt_cfg, tmp_path, capsys):
+    from dtc_tpu.train.trainer import train
+
+    cfg = make_train_cfg("dp", steps=12, log_every=12, output_dir=str(tmp_path),
+                         warmup_steps=1, prefetch=0)
+    feed = _slow_feed(cfg, tiny_model_cfg, slow_at=1 + 9, sleep_s=0.4)
+    train(cfg, tiny_model_cfg, opt_cfg, host_iterator=feed)
+    # one event for the step the feed slept in (a loaded CPU may add others)
+    (ev,) = [e for e in _events_of(tmp_path, "slow_step") if e["step"] == 9]
+    assert ev["held_by"] == "data_wait" and ev["owner"] == "host_phase"
+    assert ev["data_wait_excess_s"] == pytest.approx(0.4, abs=0.05)
+    assert ev["excess_s"] == pytest.approx(ev["period_s"] - ev["median_s"], abs=1e-5)
+    assert ev["host_late_s"] < 0.1 and ev["gc_s"] < 0.1 and ev["after"] == []
+    step9 = next(e for e in _events_of(tmp_path, "step") if e["step"] == 9)
+    assert ev["cpu_s"] == step9["cpu_s"] and step9["data_wait_s"] >= 0.4
+    assert "slow step 9:" in capsys.readouterr().out
+    summary = _events_of(tmp_path, "run_summary")[-1]
+    assert summary["slow_steps"] >= 1
+    assert summary["slow_step_excess_s"]["host_phase"] >= 0.35
+    span = next(s for s in _events_of(tmp_path, "span")
+                if s["name"] == "slow_step" and s["step"] == 9)
+    assert span["tid"] == "train.phase" and span["dur_s"] == pytest.approx(0.4, abs=0.05)
+
+
+def test_slow_step_stays_out_of_the_next_median():
+    from dtc_tpu.obs.stepclock import SLOW_PHASES, SlowSteps
+
+    def step(block, between=0.001):
+        return {"data_wait_s": 0.001, "rng_s": 0.001, "launch_s": 0.001, "block_s": block,
+                "step_time_s": 0.003 + block, "between_s": between}
+
+    det = SlowSteps()
+    assert all(det.observe(step(0.100)) is None for _ in range(5))
+    ev = det.observe(step(2.100))
+    assert ev["held_by"] == "block" and ev["owner"] == "device_or_driver"
+    assert ev["excess_s"] == pytest.approx(2.0) and ev["block_excess_s"] == pytest.approx(2.0)
+    assert sum(ev[f"{p}_s"] for p in SLOW_PHASES) == pytest.approx(ev["period_s"])
+    assert max(det.period.values) < 0.2 and max(det.phases["block"].values) < 0.2
+    # the next slow step is judged by the same median, and the canary and the
+    # collector decide its owner
+    frozen = det.observe({**step(1.100), "host_late_s": 0.9, "gc_s": 0.0})
+    assert frozen["median_s"] == ev["median_s"] and frozen["owner"] == "host_frozen"
+    assert det.observe({**step(0.100, between=0.5), "gc_s": 0.3})["owner"] == "gc"
+    late = det.observe(step(0.100, between=0.5))
+    assert late["held_by"] == "between" and late["owner"] == "host_phase"
+    assert det.observe(step(0.108)) is None   # under 1.1 x: no slow step
+
+
+def test_forced_collection_shows_in_the_steps_gc_fields():
+    import gc
+
+    clock = StepClock()
+    clock.watch_host()
+    try:
+        clock.begin(1)
+        with clock.phase("dispatch"):
+            gc.collect()
+        out = clock.end()
+        clock.begin(2)
+        quiet = clock.end()
+    finally:
+        clock.shutdown()
+    assert out["gc_n"][2] >= 1 and out["gc_s"] > 0 and out["cpu_s"] > 0
+    assert quiet["gc_n"] == [0, 0, 0] and quiet["gc_s"] == 0.0
+    assert clock._on_gc not in gc.callbacks
+    assert "gc_s" not in StepClock().end()   # not watching: the fields are absent
+
+
+def test_canary_reports_the_lateness_it_was_given():
+    from dtc_tpu.obs.stepclock import Canary
+
+    now = [100.0]
+    lateness = [0.0, 0.25, 0.0, 3.0, 0.01]
+
+    def sleep(interval):
+        now[0] += interval + lateness.pop(0)
+        if not lateness:
+            canary._halt.set()
+
+    canary = Canary(0.02, clock=lambda: now[0], sleep=sleep)
+    canary.run()   # on this thread: the injected sleep ends it
+    assert canary.take(now[0]) == pytest.approx(3.0)
+    assert canary.take(now[0]) == 0.0   # taken: the next period starts clean
+    # a sleep still overdue when the step ends counts up to there, and only
+    # what is left of it goes to the next period
+    canary._due = now[0] + 0.02
+    assert canary.take(now[0] + 1.02) == pytest.approx(1.0)
+    canary._note(now[0] + 1.52)
+    assert canary.take(now[0] + 1.52) == pytest.approx(0.5)
+
+
+def test_canary_is_not_alive_after_close(tmp_path):
+    from dtc_tpu.obs import Telemetry
+
+    tele = Telemetry(output_dir=str(tmp_path))
+    try:
+        assert tele.clock._canary is None   # not before the first timed step
+        tele.on_step_start(1)
+        canary = tele.clock._canary
+        assert canary.is_alive() and canary.daemon
+        out = tele.on_step_end(1, elapsed_s=0.1)
+        assert {"cpu_s", "gc_s", "gc_n", "host_late_s"} <= set(out)
+        tele.on_step_start(2)
+        assert tele.clock._canary is canary   # started once
+    finally:
+        tele.close()
+    assert not canary.is_alive() and tele.clock._canary is None
+
+
+def test_watchdog_and_slow_step_detector_share_one_rule():
+    """One trailing-median rule, two factors: at the detector's factor the
+    watchdog flags the same steps of a series, at its own only the hang."""
+    from dtc_tpu.config.schema import WatchdogConfig
+    from dtc_tpu.obs.stepclock import SLOW_FACTOR, SlowSteps, TrailingMedian
+    from dtc_tpu.resilience import StepWatchdog
+
+    series = [0.55, 0.5, 0.5, 0.51, 0.5, 0.56, 0.5, 2.3, 0.5, 0.549, 0.552, 4.1, 0.5]
+    det = SlowSteps()
+    same = StepWatchdog(WatchdogConfig(enabled=True, factor=SLOW_FACTOR, min_samples=5))
+    hang = StepWatchdog(WatchdogConfig(enabled=True, factor=8.0, min_samples=5))
+    assert isinstance(same._history, TrailingMedian) and isinstance(det.period, TrailingMedian)
+    flagged = {"det": [], "same": [], "hang": []}
+    for i, v in enumerate(series):
+        b = {"data_wait_s": 0.0, "rng_s": 0.0, "launch_s": 0.0, "block_s": v,
+             "step_time_s": v, "between_s": 0.0}
+        ev = det.observe(b)
+        flag = same.observe(i, v)
+        if ev is not None:
+            flagged["det"].append(i)
+            assert ev["median_s"] == pytest.approx(flag["median_s"], abs=1e-4)
+        if flag is not None:
+            flagged["same"].append(i)
+        if hang.observe(i, v) is not None:
+            flagged["hang"].append(i)
+    assert flagged["det"] == flagged["same"] == [5, 7, 10, 11]
+    assert flagged["hang"] == [11]

@@ -171,8 +171,10 @@ def test_jsonl_spans_equal_the_clock_stamps(tiny_model_cfg, opt_cfg, tmp_path, m
     monkeypatch.setattr(Telemetry, "__init__", init)
     _, events = _train(tmp_path, tiny_model_cfg, opt_cfg, 4)
     off = offsets[-1]
+    # the start-up's spans (once a run, their own track) and a slow step's
+    # span of its excess are not lines of every step
     spans = [e for e in events if e["etype"] == "span" and e["cat"] == "train"
-             and e["name"] != "compile"]
+             and e["name"] not in ("compile", "slow_step") and e["tid"] != "train.startup"]
     assert {e["name"] for e in spans} == {"step", "data_wait", "dispatch", "block"}
     assert {e["tid"] for e in spans if e["name"] == "step"} == {"train"}
     assert {e["tid"] for e in spans if e["name"] != "step"} == {"train.phase"}

@@ -334,10 +334,10 @@ def test_warmupless_first_step_emits_one_compile_span(tmp_path):
     try:
         tele.on_step_start(1)
         jax.jit(lambda v: v * 2 + tmp_path.stat().st_mode)(jnp.ones(3)).block_until_ready()
-        tele.on_step_end(1, elapsed_s=0.1, synced=True)
+        tele.on_step_end(1, elapsed_s=0.1)
         tele.on_step_start(2)
         jax.jit(lambda v: v * 3 - 1)(jnp.ones((2, 2))).block_until_ready()
-        tele.on_step_end(2, elapsed_s=0.2, synced=True)
+        tele.on_step_end(2, elapsed_s=0.2)
         tele.flush()
     finally:
         tele.close()
@@ -352,7 +352,7 @@ def test_telemetry_dump_on_anomaly_and_hung_step(tmp_path):
     tele = Telemetry(output_dir=str(tmp_path))
     try:
         tele.on_step_start(1)
-        tele.on_step_end(1, elapsed_s=0.1, synced=True)
+        tele.on_step_end(1, elapsed_s=0.1)
         tele.on_anomaly(1, reason="non-finite loss", action="warn")
         p = os.path.join(str(tmp_path), "obs", "flight.r0.json")
         body = load_flight_dump(p)
