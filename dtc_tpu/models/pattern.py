@@ -893,7 +893,11 @@ def _latent_plan(cfg: ModelConfig) -> dict:
     tiles = mla_supports(t, cfg.n_heads, cfg.mla_qk_nope_dim, cfg.mla_qk_rope_dim,
                          cfg.mla_v_dim, DTYPE_BYTES[cfg.compute_dtype])
     if kernel == "flash" and tiles:
+        # the backward's tiles; the forward walks the causal triangle at its own
         plan["block_q"], plan["block_kv"] = tiles["block_q"], tiles["block_kv"]
+        fwd = tiles["fwd"]
+        plan["fwd"] = {k: fwd[k] for k in ("schedule", "block_q", "kv_chunk", "unit", "updates",
+                                            "chunks_skipped", "covered_share")}
         plan["vmem_limit_bytes"] = {leg: tiles[leg]["vmem_limit_bytes"] for leg in ("fwd", "dq", "dkv")}
     return plan
 

@@ -600,6 +600,44 @@ def _walk_triangle(i, block_q, chunk, diag, below, strip):
         strip(slice(r * diag, (r + 1) * diag), segs + [(cols(r * diag, diag), mask)])
 
 
+def _widen(x, width):
+    """A lane-dense (n, 128) statistic repeated across ``width`` lanes."""
+    rep = width // _LANES
+    return pltpu.repeat(x, rep, 1) if rep > 1 else x
+
+
+def _softmax_update(ss, vs, prev):
+    """One online-softmax update of one head: its fp32 score segments
+    ``ss`` (masked already) against their value tiles ``vs``, from ``prev``
+    = (m, l, acc) carried, or None. Returns (m, l, acc), m and l lane-dense
+    (n, 128), acc fp32 as wide as a value tile."""
+    m_cur = functools.reduce(
+        jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for s in ss]
+    )
+    if prev is None:
+        m_new = jnp.broadcast_to(m_cur, (m_cur.shape[0], _LANES))
+    else:
+        m_prev, l_prev, acc_prev = prev
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+    l_new = acc = None
+    for s, vt in zip(ss, vs):
+        p = jnp.exp(s - _widen(m_new, s.shape[1]))
+        l_seg = jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        l_new = l_seg if l_new is None else l_new + l_seg
+        acc = pv if acc is None else acc + pv
+    if prev is None:
+        l_new = jnp.broadcast_to(l_new, m_new.shape)
+    else:
+        l_new = alpha * l_prev + l_new
+        acc = _widen(alpha, acc.shape[1]) * acc_prev + acc
+    return m_new, l_new, acc
+
+
 def _fwd_kernel_tri(q_ref, k_ref, v_ref, o_ref, lse_ref, *stats,
                     block_q, chunk, diag, g, d, scale):
     whole = not stats  # the q block is the whole sequence: nothing carried
@@ -619,33 +657,11 @@ def _fwd_kernel_tri(q_ref, k_ref, v_ref, o_ref, lse_ref, *stats,
                     preferred_element_type=jnp.float32,
                 )
                 ss.append(s if mask is None else jnp.where(mask, s, NEG_INF))
-            m_cur = functools.reduce(
-                jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for s in ss]
-            )
-            if prev is None:
-                m_new = jnp.broadcast_to(m_cur, (m_cur.shape[0], _LANES))
-            else:
-                m_prev, l_prev, acc_prev = prev[gg]
-                m_new = jnp.maximum(m_prev, m_cur)
-                alpha = jnp.exp(m_prev - m_new)
-            l_new = acc = None
-            for s, (_, vt, _) in zip(ss, kv):
-                rep = s.shape[1] // _LANES
-                p = jnp.exp(s - (pltpu.repeat(m_new, rep, 1) if rep > 1 else m_new))
-                l_seg = jnp.sum(p, axis=-1, keepdims=True)
-                # p . [v_0 | v_1 | ...]: this head's lanes of the product
-                # are its own p . v, the others are dropped at the end.
-                pv = jax.lax.dot_general(
-                    p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                l_new = l_seg if l_new is None else l_new + l_seg
-                acc = pv if acc is None else acc + pv
-            if prev is None:
-                l_new = jnp.broadcast_to(l_new, m_new.shape)
-            else:
-                l_new, acc = alpha * l_prev + l_new, alpha * acc_prev + acc
-            new.append((m_new, l_new, acc))
+            # p . [v_0 | v_1 | ...]: this head's lanes of the product are
+            # its own p . v, the others are dropped at the end.
+            new.append(_softmax_update(
+                ss, [vt for _, vt, _ in kv], None if prev is None else prev[gg]
+            ))
         return new
 
     if not whole:
@@ -1401,12 +1417,17 @@ def flash_causal_attention(
 # are whole lane tiles, so a head's nope and value parts are aligned slices
 # of its block); kp's block is read once per KV block for all of them.
 #
-# The grid walks one (block_q, block_kv) tile a step, as the transposed
-# family does: forward (rows, head groups, q blocks, KV blocks), KV innermost,
-# the online softmax in VMEM scratch; backward split FA2-style into a dq pass
-# (the same grid) and a dk / dv pass whose innermost axis walks every head
-# group's q blocks for one KV block, so kp's gradient, the sum over all H
-# heads, accumulates in VMEM and is written once. Tiles above the diagonal
+# The forward walks the causal triangle inside the kernel, as the packed
+# family's does (``_walk_triangle``): grid (rows, head groups, q blocks); a q
+# block's own square of K / V arrives beside q as blocks and is run first in
+# row strips, nothing above the diagonal issued; the KV chunks below the
+# block are fetched from HBM by the kernel, two in flight, each one unmasked
+# update of all the block's rows. m and l are lane-dense, q scaled once a q
+# block. The backward walks one (block_q, block_kv) tile a grid step, as the
+# transposed family does, split FA2-style into a dq pass (rows, head groups,
+# q blocks, KV blocks) and a dk / dv pass whose innermost axis walks every
+# head group's q blocks for one KV block, so kp's gradient, the sum over all
+# H heads, accumulates in VMEM and is written once. Tiles above the diagonal
 # are predicated out, and their block indices are clamped to the last (first)
 # tile that runs, so a skipped step fetches nothing. dr 64 needs 64-lane
 # slices of the qp block, which Mosaic takes (the packed grid kernels slice
@@ -1447,41 +1468,88 @@ def _mla_heads(g, dn, dr, dv):
             for h in range(g)]
 
 
-def _mla_fwd_kernel(qn_ref, qp_ref, kn_ref, kp_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                    *, block_q, block_kv, g, dn, dr, dv, scale):
-    i, j = pl.program_id(2), pl.program_id(3)
+def _mla_fwd_kernel(qn_ref, qp_ref, kn_ref, kp_ref, v_ref, kn_hbm, v_hbm, o_ref, lse_ref,
+                    *carried, block_q, chunk, unit, g, dn, dr, dv, scale):
+    """The forward of q block i: its own square of K / V (the blocks beside
+    q's) in row strips first, then the chunks of nope keys and values below
+    it, fetched from HBM two in flight, each one unmasked update of all the
+    block's rows. The rope key is held whole for the row: Mosaic takes a
+    64-lane window of it neither as a DMA nor as a ref view, only as a
+    load."""
+    heads = _mla_heads(g, dn, dr, dv)
+    qn, qp = qn_ref[0] * scale, qp_ref[0] * scale        # scaled once per q block
+    q = [(qn[:, n], qp[:, r]) for n, r, _ in heads]
 
-    @pl.when(j == 0)
+    def update(rows, segs, prev):
+        """One online-softmax update of ``rows`` against the segments
+        ``(kn, kp, v, mask)`` (``kn`` / ``v`` give a head's lanes of the
+        segment, ``kp`` is its rope key), per head: (m, l, acc)."""
+        new = []
+        for gg, (n, _, c) in enumerate(heads):
+            qn_h, qp_h = q[gg][0][rows], q[gg][1][rows]
+            ss = [_mla_scores(qn_h, qp_h, kn(n), kp, mask) for kn, kp, _, mask in segs]
+            new.append(_softmax_update(ss, [v(c) for _, _, v, _ in segs],
+                                       None if prev is None else prev[gg]))
+        return new
+
+    def own(base, segs):
+        """The segments of a strip in the block's own square."""
+        return [(lambda n, cols=cols: kn_ref[0, cols, n],
+                 kp_ref[0, pl.ds(base + cols.start, cols.size), :],
+                 lambda c, cols=cols: v_ref[0, cols, c], mask) for cols, mask in segs]
+
+    def emit(rows, done):
+        for (_, _, c), (m, l, acc) in zip(heads, done):
+            o_ref[0, rows, c] = (acc / _widen(l, dv)).astype(o_ref.dtype)
+        for gg, (m, l, _) in enumerate(done):
+            lse_ref[0, 0, rows, gg:gg + 1] = (m + jnp.log(l))[:, :1]
+
+    if not carried:  # the q block is the whole sequence
+        _walk_triangle(None, block_q, chunk, unit, None,
+                       lambda rows, segs: emit(rows, update(rows, own(0, segs), None)))
+        return
+
+    m_scr, l_scr, acc_scr, kn_buf, v_buf, sem = carried
+    bi, gi, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n_below = i * (block_q // chunk)
+
+    def copies(j, slot):
+        cols = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
+        return [pltpu.make_async_copy(src.at[bi, cols, pl.ds(gi * w, w)], buf.at[slot], sem.at[x, slot])
+                for x, (src, buf, w) in enumerate(((kn_hbm, kn_buf, g * dn), (v_hbm, v_buf, g * dv)))]
+
+    @pl.when(n_below > 0)
     def _():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        for cp in copies(0, 0):
+            cp.start()
 
-    def accumulate(masked: bool):
-        mask = _mask(i, j, block_q, block_kv) if masked else None
-        kp = kp_ref[0]
-        for gg, (n, r, c) in enumerate(_mla_heads(g, dn, dr, dv)):
-            s = _mla_scores(qn_ref[0, :, n] * scale, qp_ref[0, :, r] * scale, kn_ref[0, :, n], kp, mask)
-            m_prev = m_scr[gg][:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = alpha * l_scr[gg][:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[:, c] = acc_scr[:, c] * alpha + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0, :, c], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_scr[gg] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[gg] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+    def strip(rows, segs):
+        done = update(rows, own(pl.multiple_of(i * block_q, block_q), segs), None)
+        for gg, (m, l, acc) in enumerate(done):
+            m_scr[gg, rows], l_scr[gg, rows], acc_scr[gg, rows] = m, l, acc
 
-    _causal_block_dispatch(i, j, block_q, block_kv, accumulate)
+    _walk_triangle(None, block_q, chunk, unit, None, strip)
 
-    @pl.when(j == pl.num_programs(3) - 1)
-    def _():
-        for gg, (_, _, c) in enumerate(_mla_heads(g, dn, dr, dv)):
-            l = l_scr[gg][:, :1]
-            o_ref[0, :, c] = (acc_scr[:, c] / l).astype(o_ref.dtype)
-            lse_ref[0, 0, :, gg:gg + 1] = m_scr[gg][:, :1] + jnp.log(l)
+    def below(j, carry):
+        slot = j % 2
+        for cp in copies(j, slot):
+            cp.wait()
+
+        @pl.when(j + 1 < n_below)
+        def _():
+            for cp in copies(j + 1, 1 - slot):
+                cp.start()
+
+        seg = (lambda n: kn_buf[slot, :, n],
+               kp_ref[0, pl.ds(pl.multiple_of(j * chunk, chunk), chunk), :],
+               lambda c: v_buf[slot, :, c], None)
+        prev = [(m_scr[gg], l_scr[gg], acc_scr[gg]) for gg in range(g)]
+        for gg, (m, l, acc) in enumerate(update(slice(None), [seg], prev)):
+            m_scr[gg], l_scr[gg], acc_scr[gg] = m, l, acc
+        return carry
+
+    jax.lax.fori_loop(0, n_below, below, 0)
+    emit(slice(None), [(m_scr[gg], l_scr[gg], acc_scr[gg]) for gg in range(g)])
 
 
 def _mla_p_ds(refs, lse, delta, mask, gg, heads, scale):
@@ -1623,27 +1691,42 @@ def _mla_args(plan, g, dn, dr, dv, scale, **extra):
 def _mla_fwd_call(qn, qp, kn, kp, v, plan, g, dn, dr, dv, scale):
     b, t, _ = qn.shape
     h = qn.shape[-1] // dn
-    bq, bkv = plan["block_q"], plan["block_kv"]
-    s = _mla_specs(t, g, dn, dr, dv, bq, bkv, kv_outer=False)
+    leg = plan["fwd"]
+    bq, ck = leg["block_q"], leg["kv_chunk"]
+
+    def q_of(bi, gi, i):
+        return bi, i, gi
+
+    carried = [] if bq == t else [
+        pltpu.VMEM((g, bq, _LANES), jnp.float32),   # running max, a head's lane-dense
+        pltpu.VMEM((g, bq, _LANES), jnp.float32),   # running sum
+        pltpu.VMEM((g, bq, dv), jnp.float32),       # p . v
+        pltpu.VMEM((2, ck, g * dn), kn.dtype),      # the chunks below, two in flight
+        pltpu.VMEM((2, ck, g * dv), v.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+    ]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        functools.partial(_mla_fwd_kernel, **_mla_args(plan, g, dn, dr, dv, scale)),
+        functools.partial(_mla_fwd_kernel, block_q=bq, chunk=ck, unit=leg["unit"][0],
+                          g=g, dn=dn, dr=dr, dv=dv, scale=scale),
         name="flash_mla_fwd",
-        grid=(b, h // g, t // bq, t // bkv),
-        in_specs=[s["qn"], s["qp"], s["kn"], s["kp"], s["v"]],
-        out_specs=[s["o"], s["stat"]],
+        grid=(b, h // g, t // bq),
+        in_specs=[pl.BlockSpec((1, bq, g * dn), q_of), pl.BlockSpec((1, bq, g * dr), q_of),
+                  # the q block's own square of nope keys, the rope key whole, values
+                  pl.BlockSpec((1, bq, g * dn), q_of),
+                  pl.BlockSpec((1, t, dr), lambda bi, gi, i: (bi, 0, 0)),
+                  pl.BlockSpec((1, bq, g * dv), q_of), hbm, hbm],
+        out_specs=[pl.BlockSpec((1, bq, g * dv), q_of),
+                   pl.BlockSpec((1, 1, bq, g), lambda bi, gi, i: (bi, gi, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((b, t, h * dv), v.dtype),
                    jax.ShapeDtypeStruct((b, h // g, t, g), jnp.float32)],
-        scratch_shapes=[
-            pltpu.VMEM((g, bq, _LANES), jnp.float32),   # running max, a head's lane-dense
-            pltpu.VMEM((g, bq, _LANES), jnp.float32),   # running sum
-            pltpu.VMEM((bq, g * dv), jnp.float32),      # p . v
-        ],
+        scratch_shapes=carried,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=plan["fwd"]["vmem_limit_bytes"],
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=leg["vmem_limit_bytes"],
         ),
         interpret=_interpret(),
-    )(qn, qp, kn, kp, v)
+    )(qn, qp, kn, kp, v, kn, v)
 
 
 def _mla_bwd_call(qn, qp, kn, kp, v, out, lse, do, plan, g, dn, dr, dv, scale):
@@ -1690,7 +1773,7 @@ def _mla_bwd_call(qn, qp, kn, kp, v, out, lse, do, plan, g, dn, dr, dv, scale):
     return dqn, dqp, dkn, dkp, dvv
 
 
-def _mla_static(qn, qp, kp, v, block_q, block_kv):
+def _mla_static(qn, qp, kp, v, block_q, block_kv, fwd_tiles):
     """(plan, g, dn, dr, dv, scale) of a call: the planner's tiles, or the
     caller's (tests at small shapes)."""
     b, t, _ = qn.shape
@@ -1698,25 +1781,27 @@ def _mla_static(qn, qp, kp, v, block_q, block_kv):
     h = qp.shape[-1] // dr
     dn, dv = qn.shape[-1] // h, v.shape[-1] // h
     g = mla_group(h, dn, dr, dv)
-    plan = vmem.mla_flash_plan(t, h, dn, dr, dv, qn.dtype.itemsize, block_q, block_kv)
+    plan = vmem.mla_flash_plan(t, h, dn, dr, dv, qn.dtype.itemsize, block_q, block_kv, fwd_tiles)
     if g is None or plan is None or not plan["fits"]:
         raise ValueError(f"latent flash attention unsupported for T={t}, H={h}, nope {dn}, "
-                         f"rope {dr}, v {dv}, blocks {block_q}/{block_kv}")
+                         f"rope {dr}, v {dv}, blocks {block_q}/{block_kv}, forward {fwd_tiles}")
     return plan, g, dn, dr, dv, float((dn + dr) ** -0.5)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def mla_flash_attention(qn, qp, kn, kp, v, block_q=0, block_kv=0):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def mla_flash_attention(qn, qp, kn, kp, v, block_q=0, block_kv=0, fwd_tiles=()):
     """Causal latent attention, packed: ``qn`` / ``kn`` (B, T, H dn), ``qp``
     (B, T, H dr) and the one rope key ``kp`` (B, T, dr), both rotated
     already, ``v`` (B, T, H dv); returns (B, T, H dv). Scores at dn + dr,
-    scaled by its inverse square root. ``block_*`` 0: the planner's tiles."""
-    plan, *static = _mla_static(qn, qp, kp, v, block_q, block_kv)
+    scaled by its inverse square root. ``block_*``: the backward's tiles,
+    ``fwd_tiles`` the forward's (q block, KV chunk, unit); 0 and (): the
+    planner's."""
+    plan, *static = _mla_static(qn, qp, kp, v, block_q, block_kv, fwd_tiles)
     return _mla_fwd_call(qn, qp, kn, kp, v, plan, *static)[0]
 
 
-def _mla_vjp_fwd(qn, qp, kn, kp, v, block_q, block_kv):
-    plan, *static = _mla_static(qn, qp, kp, v, block_q, block_kv)
+def _mla_vjp_fwd(qn, qp, kn, kp, v, block_q, block_kv, fwd_tiles):
+    plan, *static = _mla_static(qn, qp, kp, v, block_q, block_kv, fwd_tiles)
     out, lse = _mla_fwd_call(qn, qp, kn, kp, v, plan, *static)
     # the names the flash family's residuals go by (remat block_save_flash)
     out, lse = checkpoint_name(out, "flash_out"), checkpoint_name(lse, "flash_lse")
@@ -1726,9 +1811,9 @@ def _mla_vjp_fwd(qn, qp, kn, kp, v, block_q, block_kv):
     return out, (qn, qp, kn, kp, v, out, lse)
 
 
-def _mla_vjp_bwd(block_q, block_kv, res, do):
+def _mla_vjp_bwd(block_q, block_kv, fwd_tiles, res, do):
     qn, qp, kn, kp, v, out, lse = res
-    plan, *static = _mla_static(qn, qp, kp, v, block_q, block_kv)
+    plan, *static = _mla_static(qn, qp, kp, v, block_q, block_kv, fwd_tiles)
     return _mla_bwd_call(qn, qp, kn, kp, v, out, lse, do, plan, *static)
 
 

@@ -388,7 +388,7 @@ FLASH_DEFAULT_BLOCK = 512
 FLASH_VMEM_BUDGET_BYTES = 32 * 1024 * 1024
 
 
-def _flash_candidates(t: int) -> list[tuple[int, int, int]]:
+def _flash_candidates(t: int, widest: int = 512) -> list[tuple[int, int, int]]:
     """(q block, KV chunk, unit) from the largest q block down. The chip
     pays per softmax update, not per element (PERF.md, PR 27: at T = 1024
     one 1024-row block in strips took 0.97 ms a call forward + backward,
@@ -396,13 +396,14 @@ def _flash_candidates(t: int) -> list[tuple[int, int, int]]:
     as VMEM allows, the whole sequence first: then nothing is carried from
     one update to the next. The unit of skipping is 256 (128 where the
     block is under 512 rows): 128 and 512 both measured slower at T = 512
-    and 1024."""
+    and 1024. A KV chunk is ``widest`` columns where that divides the
+    block, else the block."""
     out = []
     for bq in (t, 1024, 512, 256, 128):
         if t % bq or bq > t or (out and bq >= out[-1][0]):
             continue
         unit = next((u for u in (256, 128) if bq % u == 0 and bq >= 2 * u), bq)
-        out.append((bq, min(bq, 512) if bq % 512 == 0 else bq, unit))
+        out.append((bq, min(bq, widest) if bq % widest == 0 else bq, unit))
     return out
 
 
@@ -514,33 +515,74 @@ def flash_grid_tile_fits(block_q: int, block_kv: int, itemsize: int = 4) -> bool
     return 2 * block_q * block_kv * 4 + blocks + scratch <= VMEM_BUDGET_BYTES
 
 
-#: (q block, KV block) tiles of the latent flash kernels, first that fits
-#: first: a 512 x 512 tile of two heads is 335 MFLOP of matmuls at q / k
-#: 192 and v 128, so a grid step's ~0.35 us of bookkeeping is a tenth of it.
+#: (q block, KV block) tiles of the latent backward kernels (dq, dk / dv),
+#: first that fits first: a 512 x 512 tile of two heads is 537 MFLOP of
+#: dq's matmuls at q / k 192 and v 128, so a grid step's ~0.35 us of
+#: bookkeeping is a small part of it.
 MLA_TILES = ((512, 512), (256, 256), (128, 128))
+
+#: Widest KV chunk the latent forward takes below a q block's diagonal: one
+#: softmax update of all the block's rows a chunk.
+MLA_FWD_CHUNK = 1024
+
+
+def _mla_leg(bytes_: int, transient: int, **schedule) -> dict[str, Any]:
+    return {**schedule, "bytes": bytes_, "modeled_transient_bytes": transient,
+            "fits": bytes_ + transient <= FLASH_VMEM_BUDGET_BYTES,
+            "vmem_limit_bytes": bytes_ + transient + VMEM_COMPILER_ALLOWANCE_BYTES}
+
+
+def _mla_fwd_leg(t, g, dn, dv, itemsize, bq, ck, unit) -> dict[str, Any]:
+    """The latent forward's schedule and bytes at (q block, KV chunk, unit),
+    as :func:`_flash_leg` counts the packed forward's. Double-buffered
+    blocks: q's nope and rope lanes, the block's own square of nope keys
+    and values, o, the statistics and the rope key whole, padded to a lane
+    tile; the nope keys and values of the chunks below the block, two in
+    flight, m / l lane-dense and p . v in float32 scratch; the transients
+    an fp32 score tile per update and head, and a head's scaled q."""
+    carried = bq != t
+    n = bq // unit
+    scores = (unit * unit * n * (n + 1) // 2 + (bq * ck if carried else 0)) * 4
+    blocks = (bq * (2 * g * (dn + dv) + LANE) + t * LANE) * itemsize + bq * LANE * 4
+    chunks = 2 * ck * g * (dn + dv) * itemsize if carried else 0
+    scratch = g * bq * (2 * LANE + dv) * 4 if carried else 0
+    return _mla_leg(2 * blocks + chunks + scratch, g * (scores + bq * (dn + LANE) * itemsize),
+                    schedule="triangle", **flash_schedule(t, bq, ck, unit))
 
 
 def mla_flash_plan(t: int, h: int, dn: int, dr: int, dv: int, itemsize: int = 2,
-                   block_q: int = 0, block_kv: int = 0) -> dict[str, Any] | None:
+                   block_q: int = 0, block_kv: int = 0,
+                   fwd: tuple = ()) -> dict[str, Any] | None:
     """The latent flash kernels' plan (``ops/flash_attention.mla_flash_attention``)
     for ``h`` heads of q / k ``dn + dr`` (``dr`` the one rope key's width,
-    shared by all heads) and v ``dv`` at ``T`` in ``itemsize`` bytes: the
-    tiles (the first of :data:`MLA_TILES` that divides ``T`` and fits, or
-    the caller's), and per pass (``fwd``, ``dq``, ``dkv``) the bytes of its
-    blocks as the BlockSpecs state them (a step's ``g = 128 // dr`` heads:
-    their nope, rope and value lanes of q / k / v / o / dO, the rope key's
-    ``(block, dr)`` padded to a lane tile, the statistics padded to one),
-    double-buffered, its float32 scratch, the fp32 score-sized temporaries
-    of its heads in flight, and the ``vmem_limit_bytes`` it states. None
-    where the widths do not tile (dn and dv whole lane tiles, dr dividing
-    one, the heads a multiple of ``g``)."""
+    shared by all heads) and v ``dv`` at ``T`` in ``itemsize`` bytes, a
+    step taking ``g = 128 // dr`` heads. Per pass its tiles, schedule
+    (:func:`flash_schedule`), bytes and the ``vmem_limit_bytes`` it states:
+    ``fwd`` walks the causal triangle inside the kernel, at the first of
+    :func:`_flash_candidates` (chunks of :data:`MLA_FWD_CHUNK`) that fits
+    :data:`FLASH_VMEM_BUDGET_BYTES`, or the caller's (q block, KV chunk,
+    unit); ``dq`` and ``dkv`` walk the grid a tile a step, at the first of
+    :data:`MLA_TILES` that divides ``T`` and fits, or the caller's
+    ``block_q`` x ``block_kv``, their bytes the BlockSpecs' (a step's nope,
+    rope and value lanes of q / k / v / o / dO, the rope key's ``(block,
+    dr)`` padded to a lane tile, the statistics padded to one),
+    double-buffered, their float32 scratch and the fp32 score-sized
+    temporaries of the heads in flight. ``block_q`` / ``block_kv`` on the
+    plan are the backward's. None where the widths do not tile (dn and dv
+    whole lane tiles, dr dividing one, the heads a multiple of ``g``)."""
     if dn % LANE or dv % LANE or dr <= 0 or LANE % dr or h % (LANE // dr):
         return None
     g = LANE // dr
-    tiles = [(block_q, block_kv)] if block_q else [
-        (bq, bkv) for bq, bkv in MLA_TILES if bq <= t and bkv <= t]
     plan: dict[str, Any] = {"kernel": "flash_mla", "t": t, "heads": h, "group": g,
                             "budget_bytes": FLASH_VMEM_BUDGET_BYTES}
+    for bq, ck, unit in [fwd] if fwd else _flash_candidates(t, MLA_FWD_CHUNK):
+        if t % bq or bq % ck or bq % unit or ck % LANE or unit % LANE:
+            continue
+        plan["fwd"] = _mla_fwd_leg(t, g, dn, dv, itemsize, bq, ck, unit)
+        if plan["fwd"]["fits"]:
+            break
+    tiles = [(block_q, block_kv)] if block_q else [
+        (bq, bkv) for bq, bkv in MLA_TILES if bq <= t and bkv <= t]
     for bq, bkv in tiles:
         if t % bq or t % bkv or bq % SUBLANE or bkv % LANE:
             continue
@@ -549,24 +591,22 @@ def mla_flash_plan(t: int, h: int, dn: int, dr: int, dv: int, itemsize: int = 2,
         vv, o = bkv * g * dv * itemsize, bq * g * dv * itemsize
         stat = bq * LANE * 4
         tile = bq * bkv * 4
-        legs = {
-            # blocks; scratch; temporaries (score and probability tiles a head)
-            "fwd": (q + k + vv + o + stat, 2 * g * bq * LANE * 4 + bq * g * dv * 4, 2 * g * tile),
-            # + dO, delta; dq's nope and rope lanes out
-            "dq": (2 * q + k + vv + o + 2 * stat, bq * g * (dn + LANE) * 4, 4 * g * tile),
-            # + dO, delta; dk's nope lanes, the rope key's and dv out
-            "dkv": (q + 2 * k + 2 * vv + o + 2 * stat, bkv * (g * (dn + dv) + LANE) * 4, 4 * g * tile),
-        }
-        fits = True
-        for name, (blocks, scratch, transient) in legs.items():
-            total = 2 * blocks + scratch
-            plan[name] = {"bytes": total, "modeled_transient_bytes": transient,
-                          "vmem_limit_bytes": total + transient + VMEM_COMPILER_ALLOWANCE_BYTES}
-            fits = fits and total + transient <= FLASH_VMEM_BUDGET_BYTES
-        plan.update(flash_schedule(t, bq, bkv), block_kv=bkv, fits=fits)
-        if fits:
-            return plan
-    return plan if "fits" in plan else None
+        grid = flash_schedule(t, bq, bkv)
+        # blocks double-buffered, scratch; temporaries: score-sized tiles a head
+        # dq: + dO, delta; dq's nope and rope lanes out
+        plan["dq"] = _mla_leg(2 * (2 * q + k + vv + o + 2 * stat) + bq * g * (dn + LANE) * 4,
+                              4 * g * tile, schedule="grid", **grid)
+        # dkv: + dO, delta; dk's nope lanes, the rope key's and dv out
+        plan["dkv"] = _mla_leg(2 * (q + 2 * k + 2 * vv + o + 2 * stat)
+                               + bkv * (g * (dn + dv) + LANE) * 4,
+                               4 * g * tile, schedule="grid", **grid)
+        plan.update(block_q=bq, block_kv=bkv)
+        if plan["dq"]["fits"] and plan["dkv"]["fits"]:
+            break
+    if "fwd" not in plan or "dq" not in plan:
+        return None
+    plan["fits"] = all(plan[leg]["fits"] for leg in ("fwd", "dq", "dkv"))
+    return plan
 
 
 # ---------------------------------------------------------------------------

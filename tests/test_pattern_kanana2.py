@@ -60,6 +60,17 @@ def _dense_latent(qn, qp, kn, kp, v, h):
     return latent_causal_attention(qn, qp, kn, kp, v, impl="dense", heads=h)
 
 
+def _dense_lse(qn, qp, kn, kp, h):
+    """Each head's logsumexp over its causal scores, (B, H, T), float32."""
+    b, t, _ = qn.shape
+    q = jnp.concatenate([qn.reshape(b, t, h, -1), qp.reshape(b, t, h, -1)], -1)
+    k = jnp.concatenate([kn.reshape(b, t, h, -1),
+                         jnp.broadcast_to(kp[:, :, None], (b, t, h, kp.shape[-1]))], -1)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s / q.shape[-1] ** 0.5, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1)
+
+
 def _latent_operands(b, t, h, dn, dr, dv, dtype=jnp.float32, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     shapes = [(b, t, h * dn), (b, t, h * dr), (b, t, h * dn), (b, t, dr), (b, t, h * dv)]
@@ -67,31 +78,49 @@ def _latent_operands(b, t, h, dn, dr, dv, dtype=jnp.float32, seed=0):
     return args, jax.random.normal(ks[5], (b, t, h * dv))
 
 
-@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 3e-2)])
-def test_latent_flash_pair_matches_dense_attention(dtype, tol):
-    """The kernels in interpret mode at q / k 192 (128 + 64) and v 128, four
-    heads (two grid steps of two), 256 positions in tiles of 128 (a tile on
-    the diagonal masked, one below it unmasked, one above it skipped):
-    the output and all five gradients, the rope key's summed over the heads,
-    against the dense path that tiles the rope key to every head."""
+@pytest.mark.parametrize("dtype,tol,t,fwd,dv", [
+    # forward q blocks of 128, one strip each, the second block one chunk below
+    pytest.param("float32", 1e-5, 256, (128, 128, 128), 128, id="float32-1e-05"),
+    pytest.param("bfloat16", 3e-2, 256, (128, 128, 128), 128, id="bfloat16-0.03"),
+    # one q block, the whole sequence: two strips on the diagonal, nothing carried
+    pytest.param("float32", 1e-5, 256, (256, 128, 128), 128, id="whole-block"),
+    # three q blocks of two strips, the last with four chunks below (both buffers twice)
+    pytest.param("float32", 1e-5, 768, (256, 128, 128), 128, id="chunks-below"),
+    # values of two lane tiles a head: the statistics widened across them
+    pytest.param("bfloat16", 3e-2, 512, (256, 128, 128), 256, id="chunks-below-v256"),
+])
+def test_latent_flash_pair_matches_dense_attention(dtype, tol, t, fwd, dv):
+    """The kernels in interpret mode at q / k 192 (128 + 64) and v ``dv``,
+    four heads (two grid steps of two): the forward at (q block, KV chunk,
+    unit) ``fwd`` — its own square in row strips, the chunks below fetched
+    by the kernel and carried over — and the backward in tiles of 128 (a
+    tile on the diagonal masked, one below it unmasked, one above it
+    skipped): the output, lse and all five gradients, the rope key's summed
+    over the heads, against the dense path that tiles the rope key to every
+    head."""
+    from dtc_tpu.ops import flash_attention
     from dtc_tpu.ops.flash_attention import mla_flash_attention
 
     h = 4
-    args, co = _latent_operands(1, 256, h, 128, 64, 128, jnp.dtype(dtype))
+    args, co = _latent_operands(1, t, h, 128, 64, dv, jnp.dtype(dtype))
 
     def loss(fn):
         return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * co)
 
-    flash = lambda *a: mla_flash_attention(*a, 128, 128)  # noqa: E731
+    flash = lambda *a: mla_flash_attention(*a, 128, 128, fwd)  # noqa: E731
     dense = lambda *a: _dense_latent(*a, h)  # noqa: E731
     with jax.default_matmul_precision("highest"):
         close(flash(*args), dense(*args), tol)
+        qn, qp, kn, kp, v = args
+        _, lse = flash_attention._mla_fwd_call(
+            *args, *flash_attention._mla_static(qn, qp, kp, v, 128, 128, fwd))
+        close(lse.transpose(0, 1, 3, 2).reshape(1, h, t), _dense_lse(qn, qp, kn, kp, h), tol)
         got = jax.grad(loss(flash), argnums=range(5))(*args)
         want = jax.grad(loss(dense), argnums=range(5))(*args)
     for name, g, w in zip(("q_nope", "q_pe", "k_nope", "k_pe", "v"), got, want):
         assert g.shape == w.shape and g.dtype == w.dtype, name
         close(g, w, 3 * tol)
-    assert got[3].shape == (1, 256, 64)   # one rope key, its gradient the sum over the heads
+    assert got[3].shape == (1, t, 64)   # one rope key, its gradient the sum over the heads
 
 
 def test_latent_flash_pair_refuses_widths_it_cannot_tile():
@@ -278,8 +307,9 @@ def test_head_dim_is_the_width_the_scores_are_taken_at(kanana2_cfg):
 def test_plans_name_what_the_kanana2_cell_runs(kanana2_cfg, monkeypatch):
     """``layer_plan`` names the latent kernel pair, both widths, the tiles,
     the pairing and that the rope key is read once; the toy runs the dense
-    path with the key broadcast. ``flash_plan`` reports both widths."""
-    from dtc_tpu.ops import attention
+    path with the key broadcast. ``flash_plan`` reports both widths and
+    every pass's schedule and bytes, each inside the budget it states."""
+    from dtc_tpu.ops import attention, vmem
 
     toy = pattern.layer_plan(kanana2_cfg)["mla"]
     assert (toy["kernel"], toy["rope_key"], toy["rotary_pairing"]) == ("dense", "broadcast", "interleaved")
@@ -290,10 +320,24 @@ def test_plans_name_what_the_kanana2_cell_runs(kanana2_cfg, monkeypatch):
     mla = plan["mla"]
     assert (mla["kernel"], mla["rope_key"], mla["qk_dim"], mla["v_dim"], mla["kv_rank"]) == (
         "flash_mla", "once", 192, 128, 512)
+    # dq and dk / dv walk the grid a 512 x 512 tile a step
     assert (mla["block_q"], mla["block_kv"]) == (512, 512)
-    assert all(16 * 2**20 < v < 32 * 2**20 for v in mla["vmem_limit_bytes"].values())
+    grid = vmem.flash_schedule(8192, 512, 512)
+    assert (grid["updates"], grid["chunks_skipped"]) == (136, 120)
+    # the forward walks the triangle: one grid step a q block of 1024 (eight a row and
+    # head pair, none skipped), 28 chunks of 1024 below them and 32 strips of 256
+    fwd = mla["fwd"]
+    assert (fwd["schedule"], fwd["block_q"], fwd["kv_chunk"], fwd["unit"]) == (
+        "triangle", 1024, 1024, [256, 256])
+    assert fwd["updates"] == 28 + 32 < grid["updates"]
+    assert fwd["covered_share"] == 0.515625
     event = attention.flash_plan_event(cfg)
     assert (event["qk_dim"], event["v_dim"], event["kernel"]) == (192, 128, "flash_mla")
+    assert event["fwd"]["updates"] == 60 and event["fits"]
+    for leg in ("fwd", "dq", "dkv"):
+        assert event[leg]["bytes"] + event[leg]["modeled_transient_bytes"] <= event["budget_bytes"]
+        assert 16 * 2**20 < mla["vmem_limit_bytes"][leg] <= (
+            event["budget_bytes"] + vmem.VMEM_COMPILER_ALLOWANCE_BYTES)
     assert attention.resolve_impl(cfg.attention, cfg.max_seq_len, cfg.head_dim,
                                   cfg.attention_block_q, cfg.attention_block_kv) == "flash"
     moe = pattern.moe_plan(cfg, 2 * 8192)
